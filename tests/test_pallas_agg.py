@@ -7,7 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tests.test_e2e import assert_rows_match
 from trino_tpu.ops.pallas_agg import grouped_sums_pallas, grouped_sums_xla
 from trino_tpu.runtime.runner import LocalQueryRunner
 
@@ -35,22 +34,36 @@ def test_kernel_multi_block():
 
 
 def test_query_with_pallas_agg_matches_default():
+    """`pallas_agg` REACHES the kernel from SQL (streaming partial
+    aggregation; until PR 21 the per-batch reducer dropped the flag and
+    this test passed without it) and agrees with the default path to f32
+    accumulation precision."""
+    import math
+
+    from trino_tpu.telemetry.metrics import aggregation_path_counter
+
     sql = (
         "select o_orderstatus, o_orderpriority, count(*), "
         "sum(cast(o_totalprice as double)), avg(cast(o_totalprice as double)) "
         "from orders group by o_orderstatus, o_orderpriority"
     )
     base = LocalQueryRunner(catalog="tpch", schema="tiny")
-    expected = base.execute(sql).rows
+    expected = sorted(base.execute(sql).rows)
 
     fast = LocalQueryRunner(catalog="tpch", schema="tiny")
     fast.execute("set session pallas_agg = true")
-    actual = fast.execute(sql).rows
-    assert_rows_match(actual, expected, ordered=False, atol=0.5)
+    actual = sorted(fast.execute(sql).rows)
+    assert aggregation_path_counter().value(("pallas",)) > 0
+    assert len(actual) == len(expected)
+    for ra, re in zip(actual, expected):
+        assert ra[:3] == re[:3]  # keys and counts exact
+        assert all(
+            math.isclose(a, e, rel_tol=1e-5) for a, e in zip(ra[3:], re[3:])
+        ), (ra, re)
 
 
-def test_matmul_direct_sums_exact():
-    """The one-hot GEMM aggregation path (TPU default) is exact for int,
+def test_onehot_direct_sums_exact():
+    """The one-hot masked-reduction aggregation path (TPU default) is exact for int,
     short-decimal, long-decimal, and double sums — forced on here since
     tests run on CPU where the segmented path is the default."""
     from decimal import Decimal
@@ -69,12 +82,12 @@ def test_matmul_direct_sums_exact():
         catalog="tpch", schema="tiny", target_splits=4
     ).execute(q).rows
 
-    orig = agg.AggregationOperator._matmul_direct_sums
+    orig = agg.AggregationOperator._onehot_direct_sums
     orig_cache = agg._STEP_CACHE
     called = {"n": 0}
 
     def forced(self, batch, live, gid, prod):
-        self.force_matmul = True
+        self.force_onehot = True
         out = orig(self, batch, live, gid, prod)
         if out is not None:
             called["n"] += 1
@@ -83,13 +96,13 @@ def test_matmul_direct_sums_exact():
     # fresh step cache: the jitted steps bake the (forced) matmul path into
     # their traces, so they must neither reuse earlier unforced traces nor
     # leak forced ones back into the shared process-level cache
-    agg.AggregationOperator._matmul_direct_sums = forced
+    agg.AggregationOperator._onehot_direct_sums = forced
     agg._STEP_CACHE = {}
     try:
         r = LocalQueryRunner(catalog="tpch", schema="tiny", target_splits=4)
         rows = r.execute(q).rows
-        assert called["n"] > 0, "matmul path did not engage"
+        assert called["n"] > 0, "one-hot path did not engage"
         assert rows == expected
     finally:
-        agg.AggregationOperator._matmul_direct_sums = orig
+        agg.AggregationOperator._onehot_direct_sums = orig
         agg._STEP_CACHE = orig_cache

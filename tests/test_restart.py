@@ -35,7 +35,6 @@ from trino_tpu.runtime.prewarm import (
     PrewarmExecutor,
     WorkloadManifest,
     attach_prewarm,
-    disable_persistent_compile_cache,
     enable_persistent_compile_cache,
     load_manifest,
     save_manifest,
@@ -47,14 +46,20 @@ SQL = "select count(*) from region"
 
 
 @pytest.fixture(autouse=True)
-def _clean():
+def _clean(monkeypatch):
+    # these tests place the cache through `compile-cache.dir`; an ambient
+    # JAX_COMPILATION_CACHE_DIR would (rightly) win over it
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     reset_config()
     BREAKERS.reset()
     yield
     reset_config()
     BREAKERS.reset()
-    # a tmpdir cache must never outlive its directory into later tests
-    disable_persistent_compile_cache()
+    # a tmpdir cache must never outlive its directory into later tests:
+    # back to the suite's default placement
+    from trino_tpu.parallel.spmd import configure_persistent_cache
+
+    configure_persistent_cache()
 
 
 # -- persistent compile cache --------------------------------------------------
@@ -83,7 +88,7 @@ def test_enable_persistent_cache_local_dir(tmp_path):
 
     jax.jit(lambda x: x * 3 + 1)(jnp.arange(7))
     assert any(cache.iterdir()), "expected persisted XLA cache entries"
-    disable_persistent_compile_cache()
+    assert spmd.configure_persistent_cache(enabled=False) is None
     assert spmd.PERSISTENT_CACHE_DIR is None
 
 

@@ -1,0 +1,169 @@
+"""AOT compiles for a DESCRIBED TPU v5e (no chip attached): the pieces of
+the main path that branch on the backend, at the widths the engine really
+uses.  The chip's compiler is installed here and refuses exactly what it
+would refuse on the machine with the chip (a Mosaic layout it cannot infer,
+a 64-bit block, a program that does not fit) — interpret mode shows none of
+that.  A compile that passes is not a chip run; chip_smoke.py is.
+
+All of these live in this ONE file: the worker that describes the topology
+keeps libtpu (and its lock) until it exits, so a second file on another
+xdist worker would skip every test.  The topology is described inside a
+module-scoped fixture — never at import, never in conftest.py.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of these tests
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+# (rows, value planes K, groups G): a scan page / a learned-capacity batch
+# at the property's group-domain ceiling
+@pytest.mark.parametrize(
+    "n,k,g", [(1 << 21, 6, 8), (1 << 20, 11, 512)], ids=["2M_k6_g8", "1M_k11_g512"]
+)
+def test_pallas_grouped_sums_compiles(one_chip, n, k, g):
+    from trino_tpu.ops.pallas_agg import grouped_sums_pallas
+
+    S = _shapes(one_chip)
+    compiled = grouped_sums_pallas.lower(
+        S((n,), jnp.int32), S((n,), jnp.bool_), S((n, k), jnp.float32),
+        n_groups=g,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n", [1 << 20, 1 << 21], ids=["1M", "2M"])
+def test_onehot_plane_sums_compiles(one_chip, n):
+    """The default grouped-sum path off-CPU: K = 12 planes is what Q1
+    produces (decimal sums as 32-bit chunk planes, plus counts), G = 8 its
+    padded flag x status domain.  The f64 one-hot einsum this replaced
+    materialized its product (3.19 GB of temp at ONEHOT_ROW_LIMIT, and
+    inexact on the chip); the masked int64 reductions fuse — guard that."""
+    from trino_tpu.ops.aggregation import AggregationOperator, _onehot_plane_sums
+
+    assert n <= AggregationOperator.ONEHOT_ROW_LIMIT
+    S = _shapes(one_chip)
+    g = 8
+    planes = [S((n,), jnp.int64)] * 10 + [S((n,), jnp.float64)] * 2
+    compiled = jax.jit(
+        lambda gid, live, *planes: _onehot_plane_sums(gid, live, list(planes), g)
+    ).lower(S((n,), jnp.int64), S((n,), jnp.bool_), *planes).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (256 << 20), f"one-hot temp grew to {temp} bytes at {n} rows"
+
+
+def test_q1_fragment_compiles(one_chip):
+    """`__graft_entry__.entry()`: int64 argsort + segment_sum at a scan
+    page's capacity."""
+    import __graft_entry__ as g
+
+    S = _shapes(one_chip)
+    n = 1 << 20
+    i64 = ("qty", "price", "disc", "tax")
+    cols = {c: S((n,), jnp.int64) for c in i64}
+    cols.update({c: S((n,), jnp.int32) for c in ("flag", "status", "shipdate")})
+    fn, _ = g.entry()
+    jax.jit(fn).lower(cols).compile()
+
+
+@pytest.mark.parametrize(
+    "cap_b,probe", [(1 << 16, 1 << 20), (1 << 20, 1 << 21)],
+    ids=["build64K_probe1M", "build1M_probe2M"],
+)
+def test_locate_sorted_compiles(one_chip, cap_b, probe):
+    """The join probe: two vectorized binary searches of int64 canon planes
+    (XLA gathers; there is no Pallas probe kernel)."""
+    from trino_tpu.ops.join import _locate_sorted
+
+    S = _shapes(one_chip)
+    jax.jit(
+        lambda b, nm, p, pn: _locate_sorted([b], nm, [p], pn, cap_b=cap_b)
+    ).lower(
+        S((cap_b,), jnp.int64), S((), jnp.int64),
+        S((probe,), jnp.int64), S((probe,), jnp.bool_),
+    ).compile()
+
+
+# -- the cross-chip path: one program over the four chips of a v5e:2x2 ---------
+
+
+def _stacked_batch(wm, cap):
+    """A [W, cap] stacked scan batch of shapes: bigint key, nullable short
+    decimal, date — the column kinds Q3's repartition moves."""
+    from trino_tpu import types as T
+    from trino_tpu.columnar import Batch, Column
+
+    S = _shapes(wm.sharding())
+    w = wm.n
+    return Batch(
+        [
+            Column(S((w, cap), jnp.int64), T.BIGINT, None),
+            Column(
+                S((w, cap), jnp.int64), T.DecimalType(12, 2),
+                S((w, cap), jnp.bool_),
+            ),
+            Column(S((w, cap), jnp.int32), T.DATE, None),
+        ],
+        S((w, cap), jnp.bool_),
+    )
+
+
+def test_four_chip_repartition_compiles(topo):
+    """Hash repartition = bucketize + `all_to_all` under shard_map, on a
+    WorkerMesh of the four described chips (what `chip_smoke.py --chips 4`
+    runs for real).  Rows per worker are kept small: the stable argsort
+    inside dominates compile time (76 s at 2^20 rows) and is covered, at
+    real size, by test_q1_fragment_compiles."""
+    from trino_tpu.parallel.exchange import _exchange_kernel
+    from trino_tpu.parallel.spmd import WorkerMesh, spmd_collective_step
+
+    wm = WorkerMesh(devices=list(topo.devices))
+    assert wm.n == 4
+    fn = spmd_collective_step(wm, _exchange_kernel([0], wm.n, 1 << 10))
+    compiled = fn.lower(_stacked_batch(wm, 1 << 12)).compile()
+    assert "all-to-all" in compiled.as_text()
+
+
+def test_four_chip_broadcast_compiles(topo):
+    from trino_tpu.parallel.exchange import _broadcast_kernel
+    from trino_tpu.parallel.spmd import WorkerMesh, spmd_collective_step
+
+    wm = WorkerMesh(devices=list(topo.devices))
+    fn = spmd_collective_step(wm, _broadcast_kernel)
+    compiled = fn.lower(_stacked_batch(wm, 1 << 16)).compile()
+    assert "all-gather" in compiled.as_text()

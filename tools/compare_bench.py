@@ -905,6 +905,14 @@ def main(argv=None) -> int:
         "same zero-counter expectations",
     )
     args = ap.parse_args(argv)
+    if not os.path.exists(args.extra):
+        # no capture is checked in: the side file exists only after a
+        # bench run (bench.py / tools/*_bench.py) wrote one
+        print(
+            f"compare_bench: skipped: nothing recorded ({args.extra} does "
+            "not exist — run bench.py --mesh/--serve to record one)"
+        )
+        return 0
     try:
         with open(args.extra, "r", encoding="utf-8") as fh:
             extra = json.load(fh)

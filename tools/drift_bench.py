@@ -2,7 +2,10 @@
 """Archive warm mesh profiles and record the ROADMAP item-2 drift
 attribution into BENCH_EXTRA.json's `drift` section.
 
-What it does (in a sanitized 8-virtual-device child, like bench.py):
+What it does (in ONE fresh child, on the backend and devices JAX gives it —
+this parent never touches JAX, so the child may take the chip; for the CPU
+rehearsal run it under JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=8):
 
   1. warms Q6 and archives TWO consecutive warm runs — the **null-diff
      self check**: `profile_diff` over two warm archives of the same
@@ -47,7 +50,7 @@ runs = @RUNS@
 archive_dir = @ARCHIVE@ or tempfile.mkdtemp(prefix="trino_tpu_drift_")
 
 local = LocalQueryRunner(schema=schema, target_splits=8)
-dist = DistributedQueryRunner(n_workers=8, schema=schema)
+dist = DistributedQueryRunner(n_workers=len(jax.devices()), schema=schema)
 store = attach_profile_store(
     dist, ProfileStore(archive_dir=archive_dir, synchronous=True)
 )
@@ -98,6 +101,11 @@ def load(ref):
 print(json.dumps({
     "schema": schema,
     "workers": dist.wm.n,
+    "device": {
+        "platform": jax.devices()[0].platform,
+        "kind": jax.devices()[0].device_kind,
+        "count": len(jax.devices()),
+    },
     "archive_dir": archive_dir,
     "q6_warm_a_s": round(q6_warm_a_s, 4),
     "q6_warm_b_s": round(q6_warm_b_s, 4),
@@ -115,9 +123,6 @@ print(json.dumps({
 
 
 def run_child(schema: str, runs: int, archive_dir: str, timeout: float) -> dict:
-    from _cleanenv import cpu_env
-
-    env = cpu_env(os.environ, n_virtual_devices=8)
     code = (
         _CHILD_CODE
         .replace("@SCHEMA@", repr(schema))
@@ -126,7 +131,7 @@ def run_child(schema: str, runs: int, archive_dir: str, timeout: float) -> dict:
     )
     r = subprocess.run(
         [sys.executable, "-c", code],
-        env=env, capture_output=True, text=True, timeout=timeout, cwd=ROOT,
+        capture_output=True, text=True, timeout=timeout, cwd=ROOT,
     )
     lines = [l for l in (r.stdout or "").splitlines() if l.startswith("{")]
     if r.returncode != 0 or not lines:
@@ -180,6 +185,7 @@ def build_drift_section(measured: dict, baseline_sec: dict,
     cur_counters = art.get("counters", {}) or {}
     return {
         "schema": measured["schema"],
+        "device": measured["device"],
         "query": "q3",
         "baseline": {
             "ref": baseline_ref,

@@ -65,9 +65,9 @@ def main(argv=None) -> int:
     ap.add_argument("-o", "--out", default=None, help="output file (default: stdout)")
     args = ap.parse_args(argv)
 
-    # mirror the test/bench environment: a CPU box serves an 8-virtual-device
-    # mesh; a real accelerator deployment leaves JAX_PLATFORMS alone
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the backend is whatever JAX gives this process (on the chip machine:
+    # the chip — prewarming another backend's programs warms nothing); the
+    # virtual-device flag only takes effect when that backend is the CPU
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

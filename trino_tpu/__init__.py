@@ -25,8 +25,13 @@ Layer map (mirrors SURVEY.md §1):
 import jax
 
 # SQL semantics require 64-bit integers (BIGINT keys, decimal-as-i64-cents) and
-# 64-bit floats (DOUBLE). The hot paths stay integer/f32; f64 appears only in
-# final-aggregation arithmetic so the TPU cost is negligible.
+# 64-bit floats (DOUBLE).  The TPU has neither natively: i64 runs as i32 pairs
+# (exact) and f64 is EMULATED (not IEEE: on a v5e an f64 one-hot einsum summed
+# Q1's decimal chunks wrong in the 5th digit — PR 21).  So every exact sum stays
+# in integer arithmetic end to end (ops/aggregation one-hot reductions, segment
+# sums, i128 limb planes); f64 carries only DOUBLE/REAL columns and the final
+# division of avg()/stddev-style results, where float semantics are the SQL
+# semantics.  Do not route an exactness argument through f64 on the device.
 jax.config.update("jax_enable_x64", True)
 
 __version__ = "0.1.0"

@@ -72,6 +72,12 @@ def main(argv=None) -> int:
     ap.add_argument("--execute", "-e", help="run one statement and exit")
     args = ap.parse_args(argv)
 
+    if not args.server:
+        # in-process engine: compiled programs persist where the one
+        # placement rule says (spmd.configure_persistent_cache)
+        from trino_tpu.runtime.prewarm import enable_persistent_compile_cache
+
+        enable_persistent_compile_cache()
     backend = (
         _RemoteBackend(args.server)
         if args.server

@@ -286,9 +286,12 @@ class CompileCacheConfig(ConfigSection):
 
     dir: str = knob(
         "", "compile-cache.dir",
-        "on-disk XLA compilation cache location (empty = disabled); "
-        "resolved through the filesystem SPI, so file:// and plain paths "
-        "work and object-store schemes fail loudly at configuration time",
+        "on-disk XLA compilation cache location (empty = the fixed "
+        "in-checkout default, .jax_cache/); resolved through the filesystem "
+        "SPI, so file:// and plain paths work and object-store schemes "
+        "fail loudly at configuration time.  JAX_COMPILATION_CACHE_DIR in "
+        "the environment overrides both: the program then sets no "
+        "directory in code (spmd.configure_persistent_cache)",
     )
     enabled: bool = knob(
         True, "compile-cache.enabled",
@@ -552,20 +555,19 @@ def install_config(cfg: ClusterConfig) -> None:
         from trino_tpu.runtime.lifecycle import set_memory_pool_limit
 
         set_memory_pool_limit(cfg.memory.pool_limit_bytes)
-    if cfg.compile_cache.enabled and cfg.compile_cache.dir:
+    # A config that names no dir leaves placement alone unless a cache this
+    # process already attached must be re-placed or detached (the master
+    # switch is a switch, not a one-way latch) — a pure-config process that
+    # never touched jax must not import it here.
+    import sys as _sys
+
+    spmd = _sys.modules.get("trino_tpu.parallel.spmd")
+    if (cfg.compile_cache.enabled and cfg.compile_cache.dir) or (
+        spmd is not None and spmd.PERSISTENT_CACHE_DIR
+    ):
         from trino_tpu.runtime.prewarm import enable_persistent_compile_cache
 
         enable_persistent_compile_cache(cfg)
-    else:
-        # a reload that turns the cache OFF (enabled=false, or dir unset)
-        # must actually detach it — the master switch is a switch, not a
-        # one-way latch.  Only when a cache is live: a pure-config process
-        # that never touched jax must not import it here.
-        import sys as _sys
-
-        spmd = _sys.modules.get("trino_tpu.parallel.spmd")
-        if spmd is not None and spmd.PERSISTENT_CACHE_DIR:
-            spmd.configure_persistent_cache(None)
 
 
 def reset_config() -> None:

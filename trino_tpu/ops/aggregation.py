@@ -303,6 +303,28 @@ def _reduce128(d, gid, nseg: int, kind: str, valid):
     raise NotImplementedError(f"long decimal {kind}")
 
 
+def _onehot_plane_sums(gid, live, planes, prod: int):
+    """Per-plane [prod] per-group sums of [cap] planes against the [cap,
+    prod] one-hot of the live rows' group ids: `sum_n where(onehot[n, g],
+    plane[n], 0)`, one masked reduction per plane (XLA fuses them into one
+    pass over the rows; nothing [cap, prod, K]-shaped is materialized).
+
+    Integer planes reduce in int64 and are EXACT on every backend — never
+    route them through f64: the TPU emulates it, and an f64 one-hot einsum
+    returned Q1's sum(l_extendedprice) wrong in the 5th digit on a v5e (PR
+    21).  Double planes reduce in f64, with the backend's float semantics.
+    Module-level so tests/test_tpu_compile.py can AOT-compile exactly what
+    `_onehot_direct_sums` runs."""
+    onehot = jnp.logical_and(
+        gid[:, None] == jnp.arange(prod, dtype=gid.dtype)[None, :],
+        live[:, None],
+    )
+    return [
+        jnp.sum(jnp.where(onehot, p[:, None], jnp.zeros((), p.dtype)), axis=0)
+        for p in planes
+    ]
+
+
 def _note_fastpath(path: str) -> None:
     """Record the trace-time decimal-sum path choice (proven |
     runtime_check | limb).  Called while a kernel TRACES — the choice is
@@ -312,6 +334,15 @@ def _note_fastpath(path: str) -> None:
     from trino_tpu.telemetry.metrics import decimal_fastpath_counter
 
     decimal_fastpath_counter().labels(path).inc()
+
+
+def _note_agg_path(path: str) -> None:
+    """Record the trace-time grouped-aggregation kernel choice (pallas |
+    onehot | segmented | positional | sort).  Like `_note_fastpath`, bumped
+    while a step TRACES: the choice is static per compiled program."""
+    from trino_tpu.telemetry.metrics import aggregation_path_counter
+
+    aggregation_path_counter().labels(path).inc()
 
 
 def _sum128(
@@ -776,20 +807,23 @@ class AggregationOperator:
             cols.append(
                 Column(code.astype(c.data.dtype), c.type, valid, c.dictionary)
             )
-        pallas_sums = None
-        if self.use_pallas and self.mode == "single":
-            pallas_sums = self._pallas_direct_sums(batch, live, gid, prod)
-        if pallas_sums is not None:
-            cols.extend(pallas_sums)
-            return Batch(cols, out_live)
-        matmul_states = self._matmul_direct_sums(batch, live, gid, prod)
-        if matmul_states is not None:
-            for spec, state_cols in zip(self.aggregates, matmul_states):
+        states = None
+        if self.use_pallas:
+            states = self._pallas_direct_sums(batch, live, gid, prod)
+        if states is not None:
+            _note_agg_path("pallas")
+        else:
+            states = self._onehot_direct_sums(batch, live, gid, prod)
+            if states is not None:
+                _note_agg_path("onehot")
+        if states is not None:
+            for spec, state_cols in zip(self.aggregates, states):
                 if self.mode == "partial":
                     cols.extend(state_cols)
                 else:
                     cols.append(_finalize(spec, state_cols))
             return Batch(cols, out_live)
+        _note_agg_path("segmented")
         perm = jnp.arange(cap, dtype=jnp.int64)
         for spec in self.aggregates:
             state_cols = self._reduce_one(batch, spec, perm, live, gid, nseg, prod)
@@ -799,39 +833,42 @@ class AggregationOperator:
                 cols.append(_finalize(spec, state_cols))
         return Batch(cols, out_live)
 
-    #: one-hot matmul path bounds: groups (one-hot width) and rows (chunk
-    #: sums must stay exact in f64: 2**32 chunks * 2**21 rows = 2**53)
-    MATMUL_GROUP_LIMIT = 32
-    MATMUL_ROW_LIMIT = 1 << 21
+    #: one-hot path bounds: groups (one-hot width) and rows (32-bit chunk
+    #: sums must stay inside i64: 2**32 chunks * 2**30 rows = 2**62).  The
+    #: row bound was 2**21 while the sums rode f64 (2**53 mantissa); a
+    #: mesh worker's stacked scan batch (SF1 lineitem on one chip: 2**23
+    #: rows) then fell to the segmented scatter-adds
+    ONEHOT_GROUP_LIMIT = 32
+    ONEHOT_ROW_LIMIT = 1 << 30
 
-    def _matmul_direct_sums(self, batch: Batch, live, gid, prod: int):
-        """EXACT one-hot matmul aggregation (default on the direct path):
-        every sum/count reduces in ONE dot — [cap, G] one-hot against a
-        [cap, K] plane matrix — instead of K segmented scatter-adds.
+    def _onehot_direct_sums(self, batch: Batch, live, gid, prod: int):
+        """EXACT one-hot aggregation (default on the direct path off-CPU):
+        every sum/count is a masked reduction of a [cap] plane against the
+        [cap, G] one-hot of the group ids, fused by XLA into one pass over
+        the rows — instead of K segmented scatter-adds, which the TPU has
+        no hardware for (Q1 SF1 warm on a v5e: 4.49 s segmented).
 
-        This is the MXU-native formulation (TPU: systolic-array matmul; CPU:
-        a single GEMM) and it is exact: integer inputs split into 32-bit
-        chunk planes, each chunk sum < 2**32 * 2**21 = 2**53 fits the f64
-        mantissa, and the chunks recombine into i64/i128 with carries.
-        Returns per-spec primitive STATE columns (same layout as
-        _reduce_one) or None when ineligible.
+        It is exact: integer inputs split into 32-bit chunk planes summed
+        in int64, and the chunks recombine into i64/i128 with carries; only
+        DOUBLE/REAL sums accumulate in floating point.  Returns per-spec
+        primitive STATE columns (same layout as _reduce_one) or None when
+        ineligible.
 
         Reference role: the grouped-sum loop of operator/aggregation/
         DecimalSumAggregation + GroupedAccumulator, reshaped for hardware
-        that prefers one big matmul over row-at-a-time accumulation."""
+        that prefers wide vector reductions over row-at-a-time
+        accumulation."""
         cap = batch.capacity
-        if prod > self.MATMUL_GROUP_LIMIT or cap > self.MATMUL_ROW_LIMIT:
+        if prod > self.ONEHOT_GROUP_LIMIT or cap > self.ONEHOT_ROW_LIMIT:
             return None
         if self.mode not in ("single", "partial"):
             return None
         if not self.aggregates:
             return None  # pure dedupe (e.g. DISTINCT pre-aggregation)
-        # the one-hot GEMM is the accelerator formulation; CPU's scalar
+        # the one-hot reduction is the accelerator formulation; CPU's scalar
         # pipelines prefer the segmented scatter-adds
-        import jax as _j
-
-        if _j.default_backend() == "cpu" and not getattr(
-            self, "force_matmul", False
+        if jax.default_backend() == "cpu" and not getattr(
+            self, "force_onehot", False
         ):
             return None
         for spec in self.aggregates:
@@ -847,7 +884,7 @@ class AggregationOperator:
                     return None
 
         m32 = jnp.int64(0xFFFFFFFF)
-        planes = []  # f64 [cap] arrays
+        planes = []  # [cap] arrays: int64 (counts, chunks) or f64 (doubles)
         plan = []  # per spec: list of (prim_kind, chunk_layout, plane_idx..)
 
         def _valid_plane(col):
@@ -860,16 +897,15 @@ class AggregationOperator:
             prims = []
             if spec.name == "count_star":
                 prims.append(("count", "count", (len(planes),)))
-                planes.append(live.astype(jnp.float64))
+                planes.append(live.astype(jnp.int64))
             elif spec.name == "count":
                 col = batch.columns[spec.arg]
                 v = _valid_plane(col)
                 prims.append(("count", "count", (len(planes),)))
-                planes.append(v.astype(jnp.float64))
+                planes.append(v.astype(jnp.int64))
             else:  # sum / avg -> (sum, count) primitive states
                 col = batch.columns[spec.arg]
                 v = _valid_plane(col)
-                vf = v.astype(jnp.float64)
                 t = self.input_types[spec.arg]
                 st = _state_types(spec, self.input_types)[0]
                 if t.name in ("double", "real"):
@@ -882,10 +918,10 @@ class AggregationOperator:
                     i0 = len(planes)
                     planes.extend(
                         [
-                            (l & m32).astype(jnp.float64),
-                            ((l >> 32) & m32).astype(jnp.float64),
-                            (h & m32).astype(jnp.float64),
-                            (h >> 32).astype(jnp.float64),
+                            l & m32,
+                            (l >> 32) & m32,
+                            h & m32,
+                            h >> 32,
                         ]
                     )
                     prims.append(("sum", "i128", (i0, i0 + 1, i0 + 2, i0 + 3)))
@@ -894,8 +930,8 @@ class AggregationOperator:
                     i0 = len(planes)
                     planes.extend(
                         [
-                            (d & m32).astype(jnp.float64),
-                            (d >> 32).astype(jnp.float64),  # signed top chunk
+                            d & m32,
+                            d >> 32,  # signed top chunk
                         ]
                     )
                     kind = (
@@ -905,15 +941,10 @@ class AggregationOperator:
                     )
                     prims.append(("sum", kind + "_2", (i0, i0 + 1)))
                 prims.append(("count", "count", (len(planes),)))
-                planes.append(vf)
+                planes.append(v.astype(jnp.int64))
             plan.append((spec, prims))
 
-        onehot = jnp.logical_and(
-            gid[:, None] == jnp.arange(prod, dtype=gid.dtype)[None, :],
-            live[:, None],
-        ).astype(jnp.float64)
-        V = jnp.stack(planes, axis=1)  # [cap, K]
-        S = jnp.einsum("ng,nk->gk", onehot, V)  # ONE matmul: [G, K]
+        S = _onehot_plane_sums(gid, live, planes, prod)  # K x [G]
 
         from trino_tpu.types import int128 as i128
 
@@ -923,30 +954,20 @@ class AggregationOperator:
             sts = _state_types(spec, self.input_types)
             for (kind, layout, idx), st in zip(prims, sts):
                 if layout == "count":
-                    state_cols.append(
-                        Column(S[:, idx[0]].astype(jnp.int64), T.BIGINT)
-                    )
+                    state_cols.append(Column(S[idx[0]], T.BIGINT))
                 elif layout == "f64":
-                    state_cols.append(Column(S[:, idx[0]], st))
+                    state_cols.append(Column(S[idx[0]], st))
                 elif layout == "i64_2":
-                    s0 = S[:, idx[0]].astype(jnp.int64)
-                    s1 = S[:, idx[1]].astype(jnp.int64)
-                    state_cols.append(Column((s1 << 32) + s0, st))
-                elif layout == "i128_2":
-                    hi, lo = i128.recombine2(
-                        S[:, idx[0]].astype(jnp.int64),
-                        S[:, idx[1]].astype(jnp.int64),
+                    state_cols.append(
+                        Column((S[idx[1]] << 32) + S[idx[0]], st)
                     )
+                elif layout == "i128_2":
+                    hi, lo = i128.recombine2(S[idx[0]], S[idx[1]])
                     state_cols.append(
                         Column(jnp.stack([hi, lo], axis=-1), st)
                     )
                 else:  # i128 (4 chunk planes)
-                    hi, lo = i128.recombine4(
-                        S[:, idx[0]].astype(jnp.int64),
-                        S[:, idx[1]].astype(jnp.int64),
-                        S[:, idx[2]].astype(jnp.int64),
-                        S[:, idx[3]].astype(jnp.int64),
-                    )
+                    hi, lo = i128.recombine4(*(S[i] for i in idx))
                     state_cols.append(
                         Column(jnp.stack([hi, lo], axis=-1), st)
                     )
@@ -955,8 +976,13 @@ class AggregationOperator:
 
     def _pallas_direct_sums(self, batch: Batch, live, gid, prod: int):
         """MXU one-hot-matmul fast path (ops/pallas_agg.py) when every
-        aggregate is a float sum/avg or a count; returns finalized columns
-        or None when ineligible."""
+        aggregate is a float sum/avg or a count; returns per-spec primitive
+        STATE columns (same layout as `_onehot_direct_sums`) or None when
+        ineligible.  Off the `tpu` platform the kernel runs in interpret
+        mode (CPU tests); on it, a kernel Mosaic refuses raises — there is
+        no giving way to the XLA path."""
+        if self.mode not in ("single", "partial"):
+            return None
         for spec in self.aggregates:
             if spec.name in ("count_star", "count"):
                 continue
@@ -975,64 +1001,45 @@ class AggregationOperator:
 
         # value matrix: one column per needed quantity
         mats = []
-        plan = []  # (spec, kind, col indices into mats)
+        plan = []  # (spec, count column, value column or None)
         ones = None
         for spec in self.aggregates:
             if spec.name == "count_star":
                 if ones is None:
                     ones = len(mats)
                     mats.append(jnp.ones(cap, jnp.float32))
-                plan.append((spec, "count", (ones,)))
+                plan.append((spec, ones, None))
                 continue
             c = batch.columns[spec.arg]
             v = c.valid_mask() if c.valid is not None else None
-            data = c.data.astype(jnp.float32)
-            if v is not None:
-                data = jnp.where(v, data, 0.0)
             cnt_col = len(mats)
             mats.append(
                 (v if v is not None else jnp.ones(cap, bool)).astype(jnp.float32)
             )
             if spec.name == "count":
-                plan.append((spec, "count", (cnt_col,)))
+                plan.append((spec, cnt_col, None))
                 continue
-            val_col = len(mats)
+            data = c.data.astype(jnp.float32)
+            if v is not None:
+                data = jnp.where(v, data, 0.0)
+            plan.append((spec, cnt_col, len(mats)))
             mats.append(data)
-            plan.append((spec, spec.name, (val_col, cnt_col)))
-        values = jnp.stack(mats, axis=1)
-        interpret = jax.default_backend() != "tpu"
         sums = grouped_sums_pallas(
             gid.astype(jnp.int32),
             live,
-            values,
+            jnp.stack(mats, axis=1),
             n_groups=prod,
-            interpret=interpret,
+            interpret=jax.default_backend() != "tpu",
         )  # [prod, len(mats)]
         out = []
-        for spec, kind, idx in plan:
-            if kind == "count":
+        for spec, cnt_col, val_col in plan:
+            count = Column(sums[:, cnt_col].astype(jnp.int64), T.BIGINT)
+            if val_col is None:
+                out.append([count])
+            else:
+                st = _state_types(spec, self.input_types)[0]
                 out.append(
-                    Column(sums[:, idx[0]].astype(jnp.int64), T.BIGINT)
-                )
-            elif kind == "sum":
-                n = sums[:, idx[1]]
-                out.append(
-                    Column(
-                        sums[:, idx[0]].astype(jnp.float64),
-                        spec.out_type,
-                        n > 0,
-                    )
-                )
-            else:  # avg
-                n = sums[:, idx[1]]
-                out.append(
-                    Column(
-                        (sums[:, idx[0]] / jnp.maximum(n, 1.0)).astype(
-                            jnp.float64
-                        ),
-                        spec.out_type,
-                        n > 0,
-                    )
+                    [Column(sums[:, val_col].astype(jnp.float64), st), count]
                 )
         return out
 
@@ -1178,6 +1185,7 @@ class AggregationOperator:
                 valid = code < (sizes_list[i] - 1)
             data = (code + mins[i]).astype(col.data.dtype)
             cols.append(Column(data, col.type, valid, col.dictionary))
+        _note_agg_path("positional")
         perm = jnp.arange(cap, dtype=jnp.int64)
         gid_c = jnp.minimum(gid, out_cap)
         for spec in self.aggregates:
@@ -1235,6 +1243,7 @@ class AggregationOperator:
             direct = self._direct_group_info(batch)
         if direct is not None:
             return self._direct_reduce(batch, *direct)
+        _note_agg_path("sort")
         perm = multi_key_sort_perm(batch, [SortKey(ch) for ch in gch])
         gid, ngroups, new_group = group_ids_from_sorted(batch, perm, gch)
         live = jnp.take(batch.mask(), perm, mode="clip")
@@ -1996,6 +2005,7 @@ class AggregationOperator:
             self.aggregates,
             self.input_types,
             mode=per_mode,
+            use_pallas=self.use_pallas and per_mode == "partial",
             pre_step=self._pre if per_mode == "partial" else None,
             pre_key=self._pre_key if per_mode == "partial" else None,
             pre_jit=self._pre_jit if per_mode == "partial" else None,

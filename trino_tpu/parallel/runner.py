@@ -66,10 +66,6 @@ from trino_tpu.ops.join import (
     _locate_sorted,
     _sort_build_device,
 )
-from trino_tpu.ops.pallas_probe import (
-    locate_sorted_pallas,
-    probe_kernel_eligible,
-)
 from trino_tpu.ops.sort import OrderByOperator, TopNOperator
 from trino_tpu.parallel import exchange as ex
 from trino_tpu.parallel.spmd import (
@@ -1740,9 +1736,6 @@ class StageExecutor:
         jkey = (
             node.kind, tuple(pk), tuple(bk), cap_b,
             _sig(probe.symbols), _sig(build.symbols), residual_key,
-            # the probe-kernel knob changes the compiled program text, so
-            # it must discriminate the trace-cache key
-            bool(self.properties.get("pallas_probe")),
         )
         # capacity-history discriminator: two queries can share the same
         # join signature (and compiled programs) while filtering the probe
@@ -1845,26 +1838,14 @@ class StageExecutor:
             )
             return jnp.sum(emit, dtype=jnp.int64)
 
-        use_pallas = bool(self.properties.get("pallas_probe"))
-
         def locate(pb: Batch, bb: Batch):
             # per-shard PagesHash analog: sort THIS shard's build once,
             # then binary-search the probe keys against it
             sb, canon, n_match = _sort_build_device(bb, bk)
             pc, pn = _canon_probe_device(pb, pk, canon)
-            if use_pallas and probe_kernel_eligible(canon, pc):
-                # Pallas gather-probe (ops/pallas_probe.py): same
-                # lower/upper-bound search compiled as one kernel with
-                # the sorted build resident across probe blocks;
-                # interpreter mode off-TPU keeps CPU meshes exact
-                start, count = locate_sorted_pallas(
-                    canon[0], n_match, pc[0], pn, cap_b=cap_b,
-                    interpret=jax.default_backend() != "tpu",
-                )
-            else:
-                start, count = _locate_sorted(
-                    canon, n_match, pc, pn, cap_b=cap_b
-                )
+            start, count = _locate_sorted(
+                canon, n_match, pc, pn, cap_b=cap_b
+            )
             return sb, start, count
 
         def expand(pb: Batch, sb: Batch, start, count, total, out_cap: int):

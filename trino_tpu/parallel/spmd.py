@@ -9,6 +9,7 @@ property (SqlTaskExecution), realized as SPMD.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import OrderedDict
@@ -109,49 +110,58 @@ TRACE_CACHE = TraceCache()
 #: re-traces every key but reloads the XLA executable from disk
 PERSISTENT_CACHE_DIR: Optional[str] = None
 
+#: where compiled programs persist when nothing else says: ONE fixed path
+#: inside the checkout (.gitignore'd).  The path is part of JAX's cache key
+#: lookup, so a directory named by a temp name, pid or time never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 
 def configure_persistent_cache(
-    cache_dir: Optional[str],
+    cache_dir: str = "",
+    enabled: bool = True,
     min_compile_time_s: float = 0.0,
     min_entry_size_bytes: int = -1,
-) -> bool:
-    """Point JAX's native on-disk compilation cache at `cache_dir` (None
-    disables).  Returns False when this jax build has no persistent-cache
-    knob — callers degrade to a no-op (policy, filesystem-SPI resolution,
-    and warnings live in runtime/prewarm.enable_persistent_compile_cache).
+) -> Optional[str]:
+    """THE one rule for where compiled programs persist; every entry point
+    (chip_smoke.py, bench.py, the CLI/server start-up, tests/conftest.py,
+    `compile-cache.*` config installs) goes through here and nothing else
+    touches `jax_compilation_cache_dir`:
 
-    The threshold knobs are best-effort across jax versions: the dir knob
-    alone still caches with that build's defaults."""
+      1. `JAX_COMPILATION_CACHE_DIR` set in the environment -> JAX reads it
+         itself; no directory is set in code, whatever the config says.
+      2. otherwise `cache_dir` (a deployment's explicit `compile-cache.dir`)
+         when given, else DEFAULT_CACHE_DIR.  `enabled=False` detaches.
+
+    Returns the directory in effect (None when detached).  Policy,
+    filesystem-SPI resolution and warnings for configured dirs live in
+    runtime/prewarm.enable_persistent_compile_cache."""
     global PERSISTENT_CACHE_DIR
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (AttributeError, ValueError):
-        return False
-    # jax initializes its cache AT MOST ONCE, at the first compile — a dir
-    # configured after that (a server installing config post-import, or a
-    # dir change) would be silently ignored without a reset.  Best-effort:
-    # the module is private, and the flag alone still works when the dir
-    # lands before the first compile.
-    try:
-        from jax._src.compilation_cache import reset_cache
+    from jax.experimental.compilation_cache import compilation_cache
 
-        reset_cache()
-    except Exception:
-        pass
-    PERSISTENT_CACHE_DIR = cache_dir
-    if cache_dir is None:
-        return True
-    for knob, value in (
-        ("jax_persistent_cache_min_compile_time_secs",
-         float(min_compile_time_s)),
-        ("jax_persistent_cache_min_entry_size_bytes",
-         int(min_entry_size_bytes)),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):
-            pass
-    return True
+    # persist everything: jax's defaults skip sub-second compiles, which is
+    # most of a query's programs
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", float(min_compile_time_s)
+    )
+    jax.config.update(
+        "jax_persistent_cache_min_entry_size_bytes", int(min_entry_size_bytes)
+    )
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if from_env:
+        PERSISTENT_CACHE_DIR = from_env
+        return from_env
+    target = (cache_dir or DEFAULT_CACHE_DIR) if enabled else None
+    if target != jax.config.jax_compilation_cache_dir:
+        jax.config.update("jax_compilation_cache_dir", target)
+        # jax initializes its cache AT MOST ONCE, at the first compile — a
+        # dir configured after that (a server installing config
+        # post-import, or a dir change) is ignored without a reset
+        compilation_cache.reset_cache()
+    PERSISTENT_CACHE_DIR = target
+    return target
 
 
 def mesh_key(wm: "WorkerMesh") -> tuple:
@@ -163,23 +173,6 @@ def bucket_cap(n: int, floor: int = 64) -> int:
     """Pow2 shape bucket for batch capacities: a small set of distinct
     shapes so (fragment, bucket)-keyed traces are reused across batches."""
     return next_pow2(max(1, n), floor=floor)
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across API versions (top-level export landed after
-    0.4.x — fall back to jax.experimental.shard_map — and the check_rep ->
-    check_vma rename)."""
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature")
 
 
 class WorkerMesh:
@@ -342,8 +335,12 @@ def spmd_step(wm: WorkerMesh, step: Callable, out_replicated: bool = False):
         out = step(*squeezed)
         return jax.tree.map(lambda x: x[None], out)
 
-    inner = shard_map_compat(
-        local, wm.mesh, P("workers"), P() if out_replicated else P("workers")
+    inner = jax.shard_map(
+        local,
+        mesh=wm.mesh,
+        in_specs=P("workers"),
+        out_specs=P() if out_replicated else P("workers"),
+        check_vma=False,
     )
     return jax.jit(inner)
 
@@ -357,8 +354,12 @@ def spmd_collective_step(wm: WorkerMesh, step: Callable, out_replicated: bool = 
         TRACE_CACHE.retraces += 1
         return step(*args)
 
-    inner = shard_map_compat(
-        traced, wm.mesh, P("workers"), P() if out_replicated else P("workers")
+    inner = jax.shard_map(
+        traced,
+        mesh=wm.mesh,
+        in_specs=P("workers"),
+        out_specs=P() if out_replicated else P("workers"),
+        check_vma=False,
     )
     return jax.jit(inner)
 

@@ -8,9 +8,8 @@ analog has two halves, because its cold cost has two layers:
     vs 12.7 s warm) persists across restarts via JAX's native on-disk
     compilation cache — `enable_persistent_compile_cache` wires the
     CompileCache config section (trino_tpu/config) through the filesystem
-    SPI into `jax_compilation_cache_dir`, with a graceful no-op when the
-    backend doesn't support it.  A restarted worker re-traces but reloads
-    executables from disk.
+    SPI into the one placement rule, `spmd.configure_persistent_cache`.
+    A restarted worker re-traces but reloads executables from disk.
   * the **trace** (`spmd.TRACE_CACHE` is process-local and dies with the
     process) is re-done by the `PrewarmExecutor`: it persists a workload
     manifest — the SQL replay set, the learned speculative-join capacities
@@ -54,49 +53,39 @@ MAX_CAPACITY_ROUNDS = 4
 
 def enable_persistent_compile_cache(cfg=None, warn=None) -> Optional[str]:
     """Apply the CompileCache config section to JAX's native on-disk
-    compilation cache; returns the local directory in effect, or None when
-    disabled or gracefully degraded (remote filesystem scheme without an
-    implementation, a jax build without the knob, or an unwritable dir —
-    a missing cache is slower, never wrong, so configuration problems warn
-    instead of failing server bring-up)."""
+    compilation cache through the one placement rule
+    (`spmd.configure_persistent_cache`: the `JAX_COMPILATION_CACHE_DIR`
+    environment variable wins, then an explicit `compile-cache.dir`, then
+    the fixed in-checkout default).  Returns the local directory in effect,
+    or None when disabled or gracefully degraded (remote filesystem scheme
+    without an implementation, or an unwritable dir — a missing cache is
+    slower, never wrong, so configuration problems warn instead of failing
+    server bring-up)."""
     from trino_tpu.config import get_config
+    from trino_tpu.parallel.spmd import configure_persistent_cache
 
     cc = (cfg or get_config()).compile_cache
     emit = warn or log.warning
-    if not cc.enabled or not cc.dir:
-        return None
-    from trino_tpu.filesystem import filesystem_for, strip_scheme
+    path = ""
+    if cc.enabled and cc.dir:
+        from trino_tpu.filesystem import filesystem_for, strip_scheme
 
-    try:
-        fs = filesystem_for(cc.dir)
-    except NotImplementedError as e:
-        emit(f"persistent compile cache disabled: {e}")
-        return None
-    path = strip_scheme(cc.dir)
-    try:
-        fs.mkdirs(path)
-    except OSError as e:
-        emit(f"persistent compile cache disabled: cannot create {path}: {e}")
-        return None
-    from trino_tpu.parallel.spmd import configure_persistent_cache
-
-    if not configure_persistent_cache(
-        path, cc.min_compile_time_s, cc.min_entry_size_bytes
-    ):
-        emit(
-            "persistent compile cache disabled: this jax build has no "
-            "jax_compilation_cache_dir knob"
-        )
-        return None
-    return path
-
-
-def disable_persistent_compile_cache() -> None:
-    """Detach the on-disk cache (tests; a tmpdir cache must not outlive
-    its directory)."""
-    from trino_tpu.parallel.spmd import configure_persistent_cache
-
-    configure_persistent_cache(None)
+        try:
+            fs = filesystem_for(cc.dir)
+        except NotImplementedError as e:
+            emit(f"persistent compile cache disabled: {e}")
+            return None
+        path = strip_scheme(cc.dir)
+        try:
+            fs.mkdirs(path)
+        except OSError as e:
+            emit(
+                f"persistent compile cache disabled: cannot create {path}: {e}"
+            )
+            return None
+    return configure_persistent_cache(
+        path, cc.enabled, cc.min_compile_time_s, cc.min_entry_size_bytes
+    )
 
 
 # -- workload manifest ---------------------------------------------------------

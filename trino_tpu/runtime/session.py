@@ -227,14 +227,6 @@ SESSION_PROPERTIES: dict[str, PropertyMetadata] = {
             bool,
             False,
         ),
-        PropertyMetadata(
-            "pallas_probe",
-            "use the Pallas blocked binary-search gather-probe kernel for "
-            "the join inner loop (single-plane integer keys; falls back to "
-            "the XLA probe for limb-coded keys)",
-            bool,
-            False,
-        ),
     ]
 }
 

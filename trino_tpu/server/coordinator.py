@@ -765,6 +765,11 @@ class CoordinatorServer:
           `prewarm.manifest-path` when the runner has none, and replay the
           persisted workload manifest in the background so restart cost is
           paid before the first query, not by it."""
+        # compiled programs persist where the one placement rule says
+        # (spmd.configure_persistent_cache, fed by the installed config)
+        from trino_tpu.runtime.prewarm import enable_persistent_compile_cache
+
+        enable_persistent_compile_cache()
         det = getattr(self.runner, "failure_detector", None)
         if det is not None and callable(getattr(det, "start", None)) \
                 and callable(getattr(det, "stop", None)):

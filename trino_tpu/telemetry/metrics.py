@@ -412,6 +412,13 @@ COLLECTIVE_VOCABULARY = (
 DECIMAL_FASTPATHS = ("proven", "runtime_check", "limb")
 
 
+#: grouped-aggregation kernel path vocabulary (ops/aggregation): which
+#: formulation a traced step compiled — the Pallas MXU kernel, the exact
+#: int64 one-hot masked reduction, segmented scatter-adds over dense codes, the
+#: range-positional domain, or the sort-based numbering
+AGGREGATION_PATHS = ("pallas", "onehot", "segmented", "positional", "sort")
+
+
 #: join capacity-sizing outcome vocabulary (verify/capacity.py +
 #: parallel/runner._sized_expansion): proven = a capacity certificate
 #: licensed a fixed-capacity expand (no sizing gather, no overflow flag),
@@ -730,6 +737,16 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
     )
     for p in DECIMAL_FASTPATHS:
         fastpath.touch(p)
+    aggpath = reg.counter(
+        _PREFIX + "aggregation_path_total",
+        "grouped-aggregation kernel path selections at TRACE time "
+        "(ops/aggregation): pallas = Mosaic one-hot MXU kernel, onehot = "
+        "exact int64 one-hot masked reduction, segmented = scatter-adds over dense codes, "
+        "positional = range-positional domain, sort = sort-based numbering",
+        labelnames=("path",),
+    )
+    for p in AGGREGATION_PATHS:
+        aggpath.touch(p)
     joincap = reg.counter(
         _PREFIX + "join_capacity_total",
         "join expand-capacity decisions (parallel/runner._sized_expansion): "
@@ -838,6 +855,13 @@ def decimal_fastpath_counter() -> Counter:
     with runtime_check deltas == 0 proves the workload runs entirely on
     statically-licensed sums."""
     return REGISTRY.counter(_PREFIX + "decimal_fastpath_total")
+
+
+def aggregation_path_counter() -> Counter:
+    """Trace-time grouped-aggregation path selections, labeled
+    path=pallas|onehot|segmented|positional|sort (static per compiled
+    program, so warm replays add nothing)."""
+    return REGISTRY.counter(_PREFIX + "aggregation_path_total")
 
 
 def join_capacity_counter() -> Counter:
