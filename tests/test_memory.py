@@ -63,6 +63,23 @@ def test_batch_bytes():
     assert batch_bytes(b) == 8 * 8 + 8 + 8 * 4 + 8
 
 
+def test_batch_bytes_reads_shapes_and_pulls_nothing():
+    """Accounting a device batch must not move it: a row mask counts by its
+    `.size`, never through `np.asarray` (a blocking device->host read per
+    accounted batch, found by PR 26's `host_active_ms`)."""
+
+    class DeviceOnly:
+        size = 8
+        dtype = np.dtype(bool)
+
+        def __array__(self, *a, **k):
+            raise AssertionError("batch_bytes pulled a device value")
+
+    data = DeviceOnly()
+    data.dtype = np.dtype(np.int64)
+    assert batch_bytes(Batch([Column(data, T.BIGINT)], DeviceOnly())) == 72
+
+
 def test_batch_bytes_includes_dictionary_footprint():
     """Dictionary-coded columns account their dictionary (i32 lookup table
     + validity byte per entry + value bytes), not just the code column —

@@ -289,8 +289,14 @@ def test_mesh_trace_nests_query_fragment_launch(dist):
             "fragment-"
         )
         attrs = json.loads(l["attributes"])
-        assert attrs["phase"] in MESH_PHASES
-        assert "fragment" in attrs
+        assert attrs["step"]  # the launch door names every program
+        # a launch booked by StageExecutor._call carries its phase; the
+        # coordinator fragment's local operators and the exchange's counts
+        # pass launch outside it
+        if "phase" in attrs:
+            assert attrs["phase"] in MESH_PHASES
+            assert "fragment" in attrs
+    assert any("phase" in json.loads(l["attributes"]) for l in launches)
 
 
 def test_mesh_events_mirrored_to_registry(dist):
@@ -1027,3 +1033,373 @@ def test_concurrent_statements_isolate_spans_and_ledgers(dist):
             assert sum(1 for sp in spans if sp["name"] == "query") == 1
     finally:
         dist.profile_store = None
+
+
+# -- the launch / host-pull boundary (telemetry/programs.py jit_program,
+# columnar/batch.py host_pull): named programs, `launch` and `host_pull`
+# spans on both runners, always-on counts on the QueryContext -----------------
+
+
+def _vocabulary(section: str) -> set:
+    """The names listed under `section` in trino_tpu.telemetry's docstring
+    (the one list of span names, launch steps and host_pull reasons)."""
+    import trino_tpu.telemetry as telemetry
+
+    body = telemetry.__doc__.split(section, 1)[1].split(":\n", 1)[1]
+    body = body.split("\n\n", 1)[0]
+    body = re.sub(r"\([^)]*\)", " ", body)  # explanations in parentheses
+    return set(re.findall(r"\b[a-z][a-z0-9_]*(?:-N)?\b", body)) - {
+        "local", "mesh", "and", "the", "when", "a", "by", "followed",
+        "appended", "program", "holds", "collective", "kinds", "deferred",
+        "fused",
+    }
+
+
+def _step_in_vocabulary(step: str, steps: set) -> bool:
+    """`step` is a listed name; or a mesh name: listed kinds joined by `_`
+    (`chain_scan_pred_project`, `fused_exchange_agg_final`), `_x` last when
+    the program holds a collective."""
+    if step in steps:
+        return True
+    if step.endswith("_x"):
+        step = step[:-2]
+
+    def splits(rest: str) -> bool:
+        if not rest:
+            return True
+        return any(
+            rest == k or (rest.startswith(k + "_") and splits(rest[len(k) + 1:]))
+            for k in steps
+        )
+
+    return splits(step)
+
+
+def _run_with_context(r, sql):
+    """Execute `sql`; returns (its QueryContext, its flat spans or [])."""
+    got = []
+    r._query_context_cb = got.append
+    n = len(r.traces)
+    r.execute(sql)
+    spans = r.traces[-1][1] if len(r.traces) > n else []
+    return got[0], spans
+
+
+def _attrs(span):
+    return json.loads(span["attributes"]) if span["attributes"] else {}
+
+
+def _tpch(n):
+    from trino_tpu.connectors.tpch.queries import QUERIES
+
+    return QUERIES[n]
+
+
+def test_vocabulary_parses():
+    steps = _vocabulary("launch steps")
+    assert {"filter_project", "agg_reduce", "join_expand_unique",
+            "chain", "fused_exchange", "row_count"} <= steps
+    assert not steps & {"local", "traced", "step"}
+    assert {"result", "capacity", "overflow_flag", "spill"} <= _vocabulary(
+        "host_pull why"
+    )
+    assert {"execute", "build", "result", "launch", "host_pull",
+            "fragment-N"} <= _vocabulary("span names")
+    assert _step_in_vocabulary("chain_scan_pred_dyn_filter", steps)
+    assert _step_in_vocabulary("fused_exchange_agg_final_x", steps)
+    assert not _step_in_vocabulary("chain_local", steps)
+
+
+def test_local_execute_has_build_result_launch_and_pull(runner):
+    ctx, flat = _run_with_context(runner, _tpch(6))
+    by_id = {s["span_id"]: s for s in flat}
+    execute = [s for s in flat if s["name"] == "execute"]
+    assert len(execute) == 1
+    kids = [s["name"] for s in flat if s["parent_id"] == execute[0]["span_id"]]
+    assert kids == ["build", "result"]
+    steps = _vocabulary("launch steps")
+    launches = [s for s in flat if s["name"] == "launch"]
+    assert launches
+    for l in launches:
+        assert _attrs(l)["step"] in steps, _attrs(l)
+    pulls = [s for s in flat if s["name"] == "host_pull"]
+    whys = _vocabulary("host_pull why")
+    assert all(_attrs(p)["why"] in whys for p in pulls)
+    results = [p for p in pulls if _attrs(p)["why"] == "result"]
+    assert results and all(_attrs(p)["bytes"] > 0 for p in results)
+    # the pull of the rows sits in `result` and names the launch before it
+    last = results[-1]
+    assert by_id[last["parent_id"]]["name"] == "result"
+    assert _attrs(last)["after"] in steps
+    assert {s["name"] for s in flat} <= _vocabulary("span names")
+
+
+@pytest.mark.parametrize("which, query", [
+    ("runner", 6), ("runner", 3), ("dist", 3),
+])
+def test_launches_and_pulls_lie_inside_execute(request, which, query):
+    """Every launch and host_pull of a statement is a descendant of its
+    `execute` (`schedule`) span and lies inside it in time."""
+    r = request.getfixturevalue(which)
+    _, flat = _run_with_context(r, _tpch(query))
+    by_id = {s["span_id"]: s for s in flat}
+    outer = [s for s in flat if s["name"] in ("execute", "schedule")]
+    assert len(outer) == 1
+    o = outer[0]
+    inner = [s for s in flat if s["name"] in ("launch", "host_pull")]
+    assert inner
+    for s in inner:
+        cur = s
+        while cur["parent_id"] and cur["span_id"] != o["span_id"]:
+            cur = by_id[cur["parent_id"]]
+        assert cur["span_id"] == o["span_id"], s
+        assert s["start_ms"] >= o["start_ms"] - 1e-3
+        assert (
+            s["start_ms"] + s["duration_ms"]
+            <= o["start_ms"] + o["duration_ms"] + 2e-3
+        )
+    # host_pull spans never nest: host_active_ms subtracts their sum
+    for s in inner:
+        if s["name"] == "host_pull":
+            assert by_id[s["parent_id"]]["name"] != "host_pull"
+
+
+def test_mesh_launches_carry_step_and_are_counted_once(dist):
+    ctx, flat = _run_with_context(dist, _tpch(3))
+    by_id = {s["span_id"]: s for s in flat}
+    steps = _vocabulary("launch steps")
+    launches = [s for s in flat if s["name"] == "launch"]
+    booked = [l for l in launches if "phase" in _attrs(l)]
+    assert booked, "StageExecutor._call books its phase on the door's span"
+    for l in launches:
+        assert _step_in_vocabulary(_attrs(l)["step"], steps), _attrs(l)
+    for l in booked:
+        assert by_id[l["parent_id"]]["name"].startswith("fragment-")
+    # one span per launch: _call adds to the door's span, it records none
+    assert ctx.launches == len(launches)
+    assert ctx.host_pulls == len([s for s in flat if s["name"] == "host_pull"])
+    assert any(_attrs(s)["why"] == "result"
+               for s in flat if s["name"] == "host_pull")
+    assert "result" in [s["name"] for s in flat]
+
+
+@pytest.mark.parametrize("which", ["runner", "dist"])
+def test_counts_are_the_same_with_query_trace_off(request, which):
+    r = request.getfixturevalue(which)
+    sql = _tpch(6)
+    r.execute(sql)  # warm: the same programs both times
+    on, flat = _run_with_context(r, sql)
+    r.execute("set session query_trace = false")
+    try:
+        off, none = _run_with_context(r, sql)
+    finally:
+        r.execute("set session query_trace = true")
+    assert none == [] and off.tracer is NULL_TRACER
+    assert on.launches == len([s for s in flat if s["name"] == "launch"]) > 0
+    assert (off.launches, off.host_pulls, off.d2h_bytes) == (
+        on.launches, on.host_pulls, on.d2h_bytes
+    )
+    assert off.host_pulls >= 1 and off.d2h_bytes > 0 and off.host_pull_s > 0
+
+
+def test_query_trace_off_allocates_no_span_and_no_annotation(runner, monkeypatch):
+    from trino_tpu.telemetry import spans as spans_mod
+
+    made = []
+
+    class Counting(spans_mod.TraceAnnotation):
+        def __init__(self, *a, **k):
+            made.append(a)
+            super().__init__(*a, **k)
+
+    real_init = spans_mod.Span.__init__
+
+    def counting_init(self, *a, **k):
+        made.append(a)
+        real_init(self, *a, **k)
+
+    sql = "select count(*) from region"
+    runner.execute(sql)
+    monkeypatch.setattr(spans_mod, "TraceAnnotation", Counting)
+    monkeypatch.setattr(spans_mod.Span, "__init__", counting_init)
+    runner.execute(sql)
+    assert made, "with query_trace on, spans and annotations are made"
+    runner.execute("set session query_trace = false")
+    try:
+        del made[:]
+        runner.execute(sql)
+        assert made == []
+    finally:
+        runner.execute("set session query_trace = true")
+    # and NULL_TRACER's own span() is the shared no-op context
+    assert NULL_TRACER.span("x") is NULL_TRACER.span("y")
+    assert made == []
+
+
+def test_statistics_carry_the_boundary_counts(runner):
+    from trino_tpu.runtime.events import CollectingEventListener
+
+    listener = CollectingEventListener()
+    runner.events.add(listener)
+    try:
+        ctx, _ = _run_with_context(runner, _tpch(6))
+    finally:
+        runner.events.listeners.remove(listener)
+    st = listener.completed[-1].statistics
+    assert st.launches == ctx.launches > 0
+    assert st.host_pulls == ctx.host_pulls >= 1
+    assert st.d2h_bytes == ctx.d2h_bytes > 0
+    assert st.host_pull_s > 0
+
+
+def test_spans_are_annotations_on_the_profiler_clock(monkeypatch):
+    """span() enters TraceAnnotation("tt:<name>") for the span's lifetime;
+    record() (launches) enters none."""
+    from trino_tpu.telemetry import spans as spans_mod
+
+    events = []
+
+    class Fake:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("enter", self.name))
+
+        def __exit__(self, *a):
+            events.append(("exit", self.name))
+
+    monkeypatch.setattr(spans_mod, "TraceAnnotation", Fake)
+    tr = SpanTracer(query_id="q")
+    with tr.span("query"):
+        with tr.span("host_pull", why="result"):
+            pass
+        tr.record("launch", tr.t0, tr.t0 + 0.001, {"step": "sort"})
+    assert events == [
+        ("enter", "tt:query"), ("enter", "tt:host_pull"),
+        ("exit", "tt:host_pull"), ("exit", "tt:query"),
+    ]
+
+
+def test_agg_path_is_replayed_per_execution(runner):
+    """The kernel path a step chose while tracing rides every later launch
+    of that program (`path=`), and the aggregation-path counter counts
+    executions, not traces."""
+    from trino_tpu.telemetry.metrics import (
+        AGGREGATION_PATHS,
+        aggregation_path_counter,
+    )
+
+    sql = (
+        "select l_returnflag, l_linestatus, sum(l_quantity) from lineitem "
+        "group by l_returnflag, l_linestatus"
+    )
+    runner.execute(sql)  # traces (or finds the programs cached)
+    c = aggregation_path_counter()
+    total = lambda: sum(c.value((p,)) for p in AGGREGATION_PATHS)  # noqa: E731
+    before = total()
+    _, flat = _run_with_context(runner, sql)
+    with_path = [
+        _attrs(s) for s in flat
+        if s["name"] == "launch" and "path" in _attrs(s)
+    ]
+    assert with_path, "a warm grouped aggregation replays its path"
+    for a in with_path:
+        assert set(a["path"].split("+")) <= set(AGGREGATION_PATHS)
+    assert total() - before >= len(with_path)
+
+
+def test_pull_off_the_statement_thread_is_counted_without_a_span():
+    import contextvars
+    import threading
+
+    import jax.numpy as jnp
+
+    from trino_tpu.columnar.batch import host_pull
+    from trino_tpu.runtime import lifecycle
+    from trino_tpu.telemetry.programs import jit_program
+
+    ctx = lifecycle.QueryContext("q-thread")
+    ctx.tracer = SpanTracer(query_id="q-thread")
+    double = jit_program(lambda x: x * 2, "filter_project")
+    token = lifecycle.set_current(ctx)
+    try:
+        with ctx.tracer.span("query"):
+            snapshot = contextvars.copy_context()
+
+            def off_thread():
+                snapshot.run(
+                    lambda: host_pull(double(jnp.arange(4)), "spill")
+                )
+
+            t = threading.Thread(target=off_thread, name="t", daemon=True)
+            t.start()
+            t.join(timeout=60)
+            assert not t.is_alive()
+            host_pull(double(jnp.arange(4)), "result")
+    finally:
+        lifecycle.reset_current(token)
+    assert (ctx.launches, ctx.host_pulls, ctx.d2h_bytes) == (2, 2, 64)
+    names = [s["name"] for s in ctx.tracer.flat_spans()]
+    assert names == ["query", "launch", "host_pull"]
+
+
+def test_every_compiled_program_is_named_from_the_vocabulary(runner, dist):
+    """After Q1, Q3, Q6, Q18 on both runners, everything the step caches
+    and TRACE_CACHE hold is a named Program: no `local`, `traced`, `step`."""
+    from trino_tpu.ops import aggregation, filter_project, join, sort, unnest, window
+    from trino_tpu.parallel.spmd import TRACE_CACHE
+    from trino_tpu.runtime import local_planner
+    from trino_tpu.telemetry.programs import Program
+
+    for n in (1, 3, 6, 18):
+        runner.execute(_tpch(n))
+    for n in (1, 3, 6):
+        dist.execute(_tpch(n))
+    steps = _vocabulary("launch steps")
+    cached = list(TRACE_CACHE._fns.values())
+    for cache in (
+        aggregation._STEP_CACHE, filter_project._STEP_CACHE,
+        join._STEP_CACHE, sort._STEP_CACHE, unnest._STEP_CACHE,
+        window._WINDOW_STEP_CACHE, local_planner._MINMAX_STEP_CACHE,
+    ):
+        cached += [v for k, v in cache.items()
+                   if not (isinstance(k, tuple) and k and k[0] == "raw")]
+    programs = [f for f in cached if isinstance(f, Program)]
+    assert len(programs) > 20
+    # what is cached un-jitted is a host-eager filter/project step only
+    assert all(isinstance(f, Program) or f.__name__ == "step" for f in cached)
+    for p in programs:
+        assert _step_in_vocabulary(p.step, steps), p.step
+        assert p.step not in ("local", "traced", "step")
+        # and jax sees the name: the XLA module is jit_<step>
+        assert p.jitted.__name__ == p.step
+
+
+def test_lint_knows_the_two_doors(tmp_path):
+    sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
+    try:
+        import lint_tpu
+    finally:
+        sys.path.pop(0)
+    bad = tmp_path / "trino_tpu" / "ops" / "bad.py"
+    bad.parent.mkdir(parents=True)
+    bad.write_text(
+        "import functools\nimport jax\nimport jax.numpy as jnp\n"
+        "from trino_tpu.columnar.batch import host_pull\n"
+        "f = jax.jit(lambda x: x)\n"
+        "@jax.jit\ndef g(x):\n    return x\n"
+        "@functools.partial(jax.jit, static_argnames=('n',))\n"
+        "def h(x, n):\n    return x\n"
+        "a = jax.device_get(f(1))\n"
+        "n = int(host_pull(jnp.sum(f(1)), 'capacity'))\n"
+        "m = int(jnp.sum(f(1)))\n"
+    )
+    found = [(f.rule, f.line) for f in lint_tpu.lint_file(str(bad))]
+    assert found == [
+        ("raw-jit", 5), ("raw-jit", 6), ("raw-jit", 9),
+        ("host-transfer", 12), ("host-sync-cast", 14),
+    ]
+    assert lint_tpu._rules_for_path(
+        "trino_tpu/runtime/local_planner.py"
+    ) == {"raw-jit", "host-transfer"}

@@ -154,7 +154,9 @@ def test_four_chip_repartition_compiles(topo):
 
     wm = WorkerMesh(devices=list(topo.devices))
     assert wm.n == 4
-    fn = spmd_collective_step(wm, _exchange_kernel([0], wm.n, 1 << 10))
+    fn = spmd_collective_step(
+        wm, _exchange_kernel([0], wm.n, 1 << 10), "fused_exchange_x"
+    )
     compiled = fn.lower(_stacked_batch(wm, 1 << 12)).compile()
     assert "all-to-all" in compiled.as_text()
 
@@ -164,6 +166,6 @@ def test_four_chip_broadcast_compiles(topo):
     from trino_tpu.parallel.spmd import WorkerMesh, spmd_collective_step
 
     wm = WorkerMesh(devices=list(topo.devices))
-    fn = spmd_collective_step(wm, _broadcast_kernel)
+    fn = spmd_collective_step(wm, _broadcast_kernel, "broadcast_x")
     compiled = fn.lower(_stacked_batch(wm, 1 << 16)).compile()
     assert "all-gather" in compiled.as_text()

@@ -13,9 +13,16 @@ pays.  This linter walks `trino_tpu/ops/`, `trino_tpu/parallel/`, and
   host-sync-item    | `x.item()` — always a blocking device->host sync
   host-sync-cast    | `float()/int()/bool()` applied to a jnp expression
   host-sync-asarray | `np.asarray(...)` / `np.array(...)` of a jnp value
-  host-transfer     | `jax.device_get` / `device_get_async` /
-                    | `block_until_ready` calls (allowed only at declared
-                    | host boundaries)
+  host-transfer     | `jax.device_get` / `block_until_ready` calls: the
+                    | one device->host door is `host_pull(tree, why)`
+                    | (columnar/batch.py), which counts the pull and
+                    | records its span; `int()` / `np.asarray()` of a
+                    | `host_pull(...)` result is host arithmetic
+  raw-jit           | `jax.jit` (call, decorator or partial) under ops/,
+                    | parallel/ and runtime/local_planner.py: the one
+                    | launch door is `jit_program(fn, step, ...)`
+                    | (telemetry/programs.py), which names the program
+                    | and counts its launches
   untyped-symbol    | `Symbol(name)` built without a type — untyped
                     | PlanNode construction poisons downstream typing
   raw-perf-counter  | `time.perf_counter()` phase timing in device code —
@@ -90,14 +97,18 @@ DEFAULT_PATHS = (
     # HTTP tier: linted ONLY for raw-http-timeout (see _rules_for_path) —
     # host transfers are legal there, hardcoded socket timeouts are not
     "trino_tpu/server",
+    # the local planner: linted ONLY for the launch and host-pull doors
+    "trino_tpu/runtime/local_planner.py",
 )
 
 RULES = {
     "host-sync-item": ".item() blocks on a device->host transfer",
     "host-sync-cast": "python scalar cast of a jnp value syncs the device",
     "host-sync-asarray": "np.asarray/np.array of a jnp value syncs the device",
-    "host-transfer": "explicit device->host transfer outside a declared "
-                     "host boundary",
+    "host-transfer": "explicit device->host transfer outside the "
+                     "host_pull door",
+    "raw-jit": "jax.jit outside the jit_program door: the program has no "
+               "name and its launches are not counted",
     "untyped-symbol": "Symbol constructed without a type",
     "raw-perf-counter": "raw time.perf_counter() phase timing outside "
                         "telemetry/ and query_stats.py",
@@ -150,6 +161,8 @@ def _rules_for_path(path: str) -> frozenset:
     http = any(h in p for h in _HTTP_PATHS)
     if "trino_tpu/server/" in p:
         return frozenset({"raw-http-timeout"})
+    if p.endswith("runtime/local_planner.py"):
+        return frozenset({"raw-jit", "host-transfer"})
     rules = frozenset(RULES) if http else _DEVICE_RULES
     if not any(k in p for k in _KNOB_FREE_PATHS):
         rules = rules - {"module-level-knob"}
@@ -185,11 +198,31 @@ def _allowances(source: str) -> dict:
 def _contains_jnp(node: ast.AST) -> bool:
     """Heuristic for 'this expression produces a device value': the subtree
     references `jnp` (every device op in this codebase routes through the
-    jax.numpy namespace)."""
-    for n in ast.walk(node):
-        if isinstance(n, ast.Name) and n.id == "jnp":
-            return True
-    return False
+    jax.numpy namespace).  What `host_pull(...)` returns is a host value:
+    the door's arguments are not looked into."""
+    if isinstance(node, ast.Call) and _call_name(node) in (
+        "host_pull", "_host_pull"
+    ):
+        return False
+    if isinstance(node, ast.Name) and node.id == "jnp":
+        return True
+    return any(_contains_jnp(c) for c in ast.iter_child_nodes(node))
+
+
+def _call_name(node: ast.Call):
+    fn = node.func
+    if isinstance(fn, ast.Attribute):
+        return fn.attr
+    return fn.id if isinstance(fn, ast.Name) else None
+
+
+def _is_jax_jit(node: ast.AST) -> bool:
+    return (
+        isinstance(node, ast.Attribute)
+        and node.attr == "jit"
+        and isinstance(node.value, ast.Name)
+        and node.value.id == "jax"
+    )
 
 
 #: narrow integer dtype names: an astype to one of these can silently wrap
@@ -294,7 +327,17 @@ class _Linter(ast.NodeVisitor):
         self._names.pop()
         self._scopes.pop()
 
+    def _flag_raw_jit(self, node: ast.AST) -> None:
+        self._flag(
+            "raw-jit", node,
+            "`jax.jit` outside the launch door; build the program with "
+            "`jit_program(fn, step, ...)` (telemetry/programs.py)",
+        )
+
     def _visit_fn_scope(self, node) -> None:
+        for dec in node.decorator_list:
+            if _is_jax_jit(dec):
+                self._flag_raw_jit(dec)
         self._fn_depth += 1
         self._valid_aware.append(
             any(
@@ -422,22 +465,18 @@ class _Linter(ast.NodeVisitor):
                 "`np.%s(...)` of a jnp value copies it to the host; stay in "
                 "jnp or declare a host boundary" % fn.attr,
             )
-        # jax.device_get(...) / device_get_async(...) / x.block_until_ready()
-        transfer = None
-        if isinstance(fn, ast.Attribute) and fn.attr in (
-            "device_get", "block_until_ready"
-        ):
-            transfer = fn.attr
-        elif isinstance(fn, ast.Name) and fn.id in (
-            "device_get_async", "device_get"
-        ):
-            transfer = fn.id
-        if transfer is not None:
+        # jax.device_get(...) / x.block_until_ready()
+        if _call_name(node) in ("device_get", "block_until_ready"):
             self._flag(
                 "host-transfer", node,
-                f"`{transfer}` moves device data to the host; allowed only "
-                "at declared boundaries (# lint: allow(host-transfer))",
+                f"`{_call_name(node)}` moves device data to the host "
+                "uncounted; go through `host_pull(tree, why)` "
+                "(columnar/batch.py)",
             )
+        # jax.jit(...) / functools.partial(jax.jit, ...); a bare `@jax.jit`
+        # decorator is caught in _visit_fn_scope
+        if _is_jax_jit(fn) or any(_is_jax_jit(a) for a in node.args):
+            self._flag_raw_jit(node)
         # time.perf_counter() / perf_counter() — phase timing belongs to the
         # telemetry clock (trino_tpu.telemetry.now), which spans and
         # MeshProfile phases share; raw readings drift out of the trace

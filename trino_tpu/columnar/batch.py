@@ -16,6 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar.column import Column
+from trino_tpu.runtime.lifecycle import current_query
+from trino_tpu.telemetry.programs import jit_program, recording_tracer
+from trino_tpu.telemetry.spans import now
 
 
 class Batch:
@@ -111,18 +114,17 @@ class Batch:
         return self
 
     def num_rows_host(self) -> int:
+        """Live rows, read to the host: it sizes the next program's static
+        capacity (one `row_count` launch, one `capacity` pull)."""
         if self.row_mask is None:
             return self.capacity
-        return int(np.asarray(jnp.sum(self.row_mask)))
+        if isinstance(self.row_mask, np.ndarray):  # a host batch
+            return int(self.row_mask.sum())
+        return int(host_pull(_ROW_COUNT(self.row_mask), "capacity"))
 
     def to_pylist(self) -> list[list]:
-        """Rows of python values (live rows only, in order).
-
-        All column transfers are STARTED before any is awaited
-        (copy_to_host_async): device_get alone awaits leaves one at a time,
-        paying a full device->host round trip per column instead of
-        overlapping them."""
-        host = device_get_async(self)
+        """Rows of python values (live rows only, in order)."""
+        host = host_pull(self, "result")
         rm = None if host.row_mask is None else np.asarray(host.row_mask)
         cols = [c.to_pylist(rm) for c in host.columns]
         return [list(r) for r in zip(*cols)] if cols else []
@@ -131,16 +133,54 @@ class Batch:
         return f"Batch(cap={self.capacity}, width={self.width})"
 
 
-def device_get_async(tree):
-    """device_get with all leaf transfers launched up front — one round-trip
-    latency for the whole pytree instead of one per leaf."""
+def host_pull(tree, why: str):
+    """The engine's one device->host door: `jax.device_get(tree)` with every
+    leaf's transfer STARTED before any is awaited (one round trip for the
+    whole pytree, not one per leaf), accounted for.
+
+    Books on the executing statement's `QueryContext` one pull, the
+    seconds this thread was blocked and the bytes of the device leaves;
+    with `query_trace` on it also opens a `host_pull` span for the wait,
+    carrying `why` (what the host needs the value for — the closed
+    vocabulary is in `trino_tpu.telemetry`'s docstring), `bytes` and
+    `after` (the `step` of the statement's newest launch: what the device
+    was most likely still running).  The tracer is not thread-safe: a pull
+    made off the statement's own thread is counted but records no span."""
+    nbytes = 0
     for leaf in jax.tree_util.tree_leaves(tree):
         if hasattr(leaf, "copy_to_host_async"):
+            nbytes += leaf.nbytes
             try:
                 leaf.copy_to_host_async()
             except Exception:
                 pass  # backend without async copies: plain get below
-    return jax.device_get(tree)
+    ctx = current_query()
+    if ctx is None:
+        return jax.device_get(tree)
+    tracer = recording_tracer(ctx)
+    t0 = now()
+    if tracer is None:
+        out = jax.device_get(tree)
+    else:
+        with tracer.span(
+            "host_pull", why=why, bytes=nbytes, after=ctx.last_step
+        ):
+            out = jax.device_get(tree)
+    ctx.host_pulls += 1
+    ctx.host_pull_s += now() - t0
+    ctx.d2h_bytes += nbytes
+    return out
+
+
+#: jitted stable compaction (Batch.compact_device), ONE program object for
+#: every operator that packs live rows to a static capacity bucket
+COMPACT = jit_program(
+    Batch.compact_device, "compact", static_argnames=("out_capacity",)
+)
+
+_ROW_COUNT = jit_program(
+    lambda mask: jnp.sum(mask, dtype=jnp.int64), "row_count"
+)
 
 
 def _batch_flatten(b: Batch):

@@ -27,7 +27,7 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import concat_batches
+from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.ops.common import (
     SortKey,
     group_ids_from_sorted,
@@ -61,6 +61,7 @@ class AggSpec:
 
 
 from trino_tpu.planner.functions import HOLISTIC_AGGS
+from trino_tpu.telemetry.programs import jit_program, note_path
 
 #: collect subset of the holistic aggregates (padded-array group state)
 COLLECT_AGGS = ("array_agg", "map_agg", "listagg")
@@ -327,22 +328,22 @@ def _onehot_plane_sums(gid, live, planes, prod: int):
 
 def _note_fastpath(path: str) -> None:
     """Record the trace-time decimal-sum path choice (proven |
-    runtime_check | limb).  Called while a kernel TRACES — the choice is
-    static per compiled program, so warm replays add nothing and a warm
-    run's zero runtime_check delta is a real guarantee (gated by
-    tools/compare_bench.py over the bench.py --mesh Q1 section)."""
+    runtime_check | limb).  Called while a kernel TRACES: the count is per
+    traced program, not per execution — the choice is static per compiled
+    program, so warm replays add nothing and a warm run's zero
+    runtime_check delta is a real guarantee."""
     from trino_tpu.telemetry.metrics import decimal_fastpath_counter
 
     decimal_fastpath_counter().labels(path).inc()
 
 
 def _note_agg_path(path: str) -> None:
-    """Record the trace-time grouped-aggregation kernel choice (pallas |
-    onehot | segmented | positional | sort).  Like `_note_fastpath`, bumped
-    while a step TRACES: the choice is static per compiled program."""
-    from trino_tpu.telemetry.metrics import aggregation_path_counter
-
-    aggregation_path_counter().labels(path).inc()
+    """Record the grouped-aggregation kernel choice (pallas | onehot |
+    segmented | positional | sort).  Called where a step chooses, which
+    under jit is while it TRACES: the launch door remembers the choice on
+    the program and replays it on every launch (`path=` on the `launch`
+    span; `trino_tpu_aggregation_path_total` counts executions)."""
+    note_path(path)
 
 
 def _sum128(
@@ -650,7 +651,7 @@ class MarkDistinctOperator:
         self._acc: list[Batch] = []
         key = ("mark_distinct", tuple(self.key_channels))
         if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = jax.jit(self._mark_step)
+            _STEP_CACHE[key] = jit_program(self._mark_step, "agg_mark")
         self._step = _STEP_CACHE[key]
 
     def _mark_step(self, batch: Batch) -> Batch:
@@ -733,7 +734,9 @@ class AggregationOperator:
         )
         cached = _STEP_CACHE.get(key)
         if cached is None:
-            cached = jax.jit(self._reduce_step, static_argnames=("out_cap",))
+            cached = jit_program(
+                self._reduce_step, "agg_reduce", static_argnames=("out_cap",)
+            )
             _STEP_CACHE[key] = cached
         self._step = cached
 
@@ -1089,7 +1092,7 @@ class AggregationOperator:
                     maxs.append(jnp.max(jnp.where(v, d, -big)))
                 return jnp.stack(mins), jnp.stack(maxs)
 
-            step = jax.jit(stats)
+            step = jit_program(stats, "agg_key_stats")
             _STEP_CACHE[key] = step
         return step(batch)
 
@@ -1103,8 +1106,7 @@ class AggregationOperator:
         if not self._positional_static_eligible(batch):
             return None
         mins_d, maxs_d = self._key_stats(batch)
-        mins = np.asarray(jax.device_get(mins_d))  # lint: allow(host-transfer)
-        maxs = np.asarray(jax.device_get(maxs_d))  # lint: allow(host-transfer)
+        mins, maxs = host_pull((mins_d, maxs_d), "group_stats")
         prod = 1
         sizes = []
         for i, ch in enumerate(self.group_channels):
@@ -1132,7 +1134,9 @@ class AggregationOperator:
         )
         step = _STEP_CACHE.get(key)
         if step is None:
-            step = jax.jit(self._range_step, static_argnames=("out_cap",))
+            step = jit_program(
+                self._range_step, "agg_range", static_argnames=("out_cap",)
+            )
             _STEP_CACHE[key] = step
         out = step(
             batch,
@@ -1145,9 +1149,7 @@ class AggregationOperator:
         ng = out.num_rows_host()
         cc = next_pow2(max(ng, 1), floor=16)
         if cc * 2 <= nseg:
-            out = jax.jit(Batch.compact_device, static_argnames=("out_capacity",))(
-                out, out_capacity=cc
-            )
+            out = COMPACT(out, out_capacity=cc)
         return out
 
     def _range_step(self, batch: Batch, mins, sizes, out_cap: int) -> Batch:
@@ -1203,9 +1205,7 @@ class AggregationOperator:
         n = big.num_rows_host()
         cap = next_pow2(max(n, 1), floor=1)
         if cap < big.capacity:
-            big = jax.jit(Batch.compact_device, static_argnames=("out_capacity",))(
-                big, out_capacity=cap
-            )
+            big = COMPACT(big, out_capacity=cap)
         else:
             cap = next_pow2(big.capacity, floor=1)
             big = _pad_device(big, cap)
@@ -1377,7 +1377,7 @@ class AggregationOperator:
             )
         # within-group rank over kept rows
         pos_in_group, counts = _group_ranks(varg, gid_c, cap, nseg)
-        kmax = int(np.asarray(jnp.max(counts[:out_cap])))  # the one host sync  # lint: allow(host-sync-asarray, host-sync-cast)
+        kmax = int(host_pull(jnp.max(counts[:out_cap]), "capacity"))  # the one host sync
         k = next_pow2(max(kmax, 1), floor=1)
         scatter_g = jnp.where(varg, gid_c, nseg)  # drop non-kept rows
         scatter_p = jnp.clip(pos_in_group, 0, k - 1)
@@ -1431,7 +1431,7 @@ class AggregationOperator:
         perm2 = multi_key_sort_perm(batch, keys)
         if gch:
             gid2, _, _ = group_ids_from_sorted(batch, perm2, gch)
-            gid_h = np.asarray(jax.device_get(gid2))  # lint: allow(host-transfer)
+            gid_h = host_pull(gid2, "dictionary")
         else:
             gid_h = np.zeros(batch.capacity, dtype=np.int64)
         live = jnp.take(batch.mask(), perm2, mode="clip")
@@ -1440,8 +1440,7 @@ class AggregationOperator:
                 live, jnp.take(col.valid, perm2, mode="clip")
             )
         codes = jnp.take(col.data, perm2, mode="clip")
-        live_h = np.asarray(jax.device_get(live))  # lint: allow(host-transfer)
-        codes_h = np.asarray(jax.device_get(codes))  # lint: allow(host-transfer)
+        live_h, codes_h = host_pull((live, codes), "dictionary")
         sep = str(sep)
         values = col.dictionary.values
         joined = [""] * out_cap

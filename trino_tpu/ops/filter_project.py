@@ -12,11 +12,10 @@ from __future__ import annotations
 from functools import partial
 from typing import Optional, Sequence
 
-import jax
-
 from trino_tpu.columnar import Batch
 from trino_tpu.expr import ExprCompiler
 from trino_tpu.expr.ir import Call, Expr
+from trino_tpu.telemetry.programs import jit_program
 
 #: functions that must evaluate eagerly (host-side per-row rendering):
 #: projections containing one run the step unjitted
@@ -58,7 +57,10 @@ class FilterProjectOperator:
             )
             # expressions with host-eager functions (per-row string renders
             # that can't trace) run the same step without jit
-            cached = step if any(map(_needs_eager, exprs)) else jax.jit(step)
+            cached = (
+                step if any(map(_needs_eager, exprs))
+                else jit_program(step, "filter_project")
+            )
             _STEP_CACHE[key] = cached
         self._step = cached
 

@@ -31,8 +31,9 @@ import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import concat_batches
+from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.ops.common import next_pow2
+from trino_tpu.telemetry.programs import jit_program
 
 
 def _dense_build(batches: list[Batch], types: Sequence[T.Type]) -> tuple[Batch, int]:
@@ -43,9 +44,7 @@ def _dense_build(batches: list[Batch], types: Sequence[T.Type]) -> tuple[Batch, 
     big = batches[0] if len(batches) == 1 else concat_batches(batches)
     n = big.num_rows_host()
     cap = next_pow2(max(n, 1), floor=1)
-    return jax.jit(Batch.compact_device, static_argnames=("out_capacity",))(
-        big, out_capacity=cap
-    ), n
+    return COMPACT(big, out_capacity=cap), n
 
 
 def _canon_build_keys(build: Batch, key_channels: Sequence[int]):
@@ -150,7 +149,7 @@ def _prepare_sorted_build(build: Batch, key_channels: Sequence[int]):
     canon, nomatch = _canon_build_keys(build, key_channels)
     perm = None
     table = None
-    n_match = int(jnp.sum(jnp.logical_not(nomatch)))  # lint: allow(host-sync-cast)
+    n_match = int(host_pull(jnp.sum(jnp.logical_not(nomatch)), "capacity"))
     if all(jnp.issubdtype(d.dtype, jnp.integer) for d in canon):
         imax = jnp.iinfo(jnp.int64).max
         mins, widths = [], []
@@ -158,8 +157,10 @@ def _prepare_sorted_build(build: Batch, key_channels: Sequence[int]):
         for d in canon:
             d64 = d.astype(jnp.int64)
             # nomatch rows must not widen the packed range
-            mn = int(jnp.min(jnp.where(nomatch, imax, d64)))  # lint: allow(host-sync-cast)
-            mx = int(jnp.max(jnp.where(nomatch, -imax, d64)))  # lint: allow(host-sync-cast)
+            mn, mx = (int(x) for x in host_pull((
+                jnp.min(jnp.where(nomatch, imax, d64)),
+                jnp.max(jnp.where(nomatch, -imax, d64)),
+            ), "group_stats"))
             mins.append(mn)
             widths.append(mx - mn + 1)
             total *= mx - mn + 1
@@ -308,11 +309,13 @@ class _SortedBuildJoinBase:
         self._recode: dict = {}  # key index -> {id(probe_dict): (dict, table)}
         self._locate = _jit_cached(
             ("locate", len(self.build_keys)),
-            lambda: jax.jit(_locate_sorted, static_argnames=("cap_b",)),
+            lambda: jit_program(
+                _locate_sorted, "join_locate_sorted", static_argnames=("cap_b",)
+            ),
         )
         self._locate_t = _jit_cached(
             ("locate_table", len(self.build_keys)),
-            lambda: jax.jit(_locate_table),
+            lambda: jit_program(_locate_table, "join_locate_table"),
         )
 
     def release_build(self) -> None:
@@ -428,13 +431,17 @@ class HashJoinOperator(_SortedBuildJoinBase):
                 tuple(t.name for t in self.build_types), residual_key,
             )
         self._expand = _jit_cached(
-            cache_key, lambda: jax.jit(
-                self._expand_step, static_argnames=("out_cap", "cap_b")
+            cache_key, lambda: jit_program(
+                self._expand_step, "join_expand",
+                static_argnames=("out_cap", "cap_b"),
             )
         )
         self._expand_unique = _jit_cached(
             None if cache_key is None else ("uniq",) + cache_key[1:],
-            lambda: jax.jit(self._expand_unique_step, static_argnames=("cap_b",)),
+            lambda: jit_program(
+                self._expand_unique_step, "join_expand_unique",
+                static_argnames=("cap_b",),
+            ),
         )
 
     def set_build(self, batches: list[Batch]) -> None:
@@ -568,8 +575,8 @@ class HashJoinOperator(_SortedBuildJoinBase):
         cap_b = self.build.capacity
         start, count = self._locate_batch(probe)
         maxc, total_inner, probe_live = (
-            int(x) for x in jax.device_get(  # lint: allow(host-transfer)
-                (jnp.max(count), jnp.sum(count), probe.count())
+            int(x) for x in host_pull(
+                (jnp.max(count), jnp.sum(count), probe.count()), "capacity"
             )
         )
         if maxc <= 1:
@@ -583,14 +590,15 @@ class HashJoinOperator(_SortedBuildJoinBase):
             if cc * 2 <= out.capacity:
                 # selective join: hand downstream a dense batch, not a
                 # mostly-dead full-capacity one
-                out = jax.jit(
-                    Batch.compact_device, static_argnames=("out_capacity",)
-                )(out, out_capacity=cc)
+                out = COMPACT(out, out_capacity=cc)
             return out
         if self.kind == "inner":
             total = total_inner
         else:
-            total = int(jnp.sum(jnp.where(probe.mask(), jnp.maximum(count, 1), 0)))  # lint: allow(host-sync-cast)
+            total = int(host_pull(
+                jnp.sum(jnp.where(probe.mask(), jnp.maximum(count, 1), 0)),
+                "capacity",
+            ))
         out_cap = next_pow2(max(total, 1), floor=1024)
         out, new_matched = self._expand(
             probe, self.build, start, count, self._build_matched,
@@ -634,7 +642,10 @@ class NestedLoopJoinOperator:
         self._nb = 0
         self._step = _jit_cached(
             ("nested", tuple(t.name for t in self.build_types)),
-            lambda: jax.jit(self._expand, static_argnames=("out_cap", "nb")),
+            lambda: jit_program(
+                self._expand, "join_nested_expand",
+                static_argnames=("out_cap", "nb"),
+            ),
         )
 
     def set_build(self, batches: list[Batch]) -> None:
@@ -716,7 +727,9 @@ class SemiJoinOperator(_SortedBuildJoinBase):
         self._filter_has_null = False
         self._mark = _jit_cached(
             ("mark", null_aware, source_key_channel, filtering_key_channel),
-            lambda: jax.jit(self._mark_step, static_argnames=("has_null",)),
+            lambda: jit_program(
+                self._mark_step, "join_semi_mark", static_argnames=("has_null",)
+            ),
         )
         res_key = (
             None
@@ -726,8 +739,8 @@ class SemiJoinOperator(_SortedBuildJoinBase):
         )
         self._mark_res = _jit_cached(
             res_key,
-            lambda: jax.jit(
-                self._mark_residual_step,
+            lambda: jit_program(
+                self._mark_residual_step, "join_semi_mark_residual",
                 static_argnames=("cap_b", "out_cap", "has_null"),
             ),
         )
@@ -808,7 +821,7 @@ class SemiJoinOperator(_SortedBuildJoinBase):
             if self.residual is None:
                 yield self._mark(probe, count, has_null=self._filter_has_null)
             else:
-                total = int(jnp.sum(count))  # lint: allow(host-sync-cast)
+                total = int(host_pull(jnp.sum(count), "capacity"))
                 out_cap = next_pow2(max(total, 1), floor=1024)
                 yield self._mark_res(
                     probe, self.build, start, count,

@@ -35,6 +35,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from trino_tpu.telemetry.programs import jit_program
+
 _BLOCK = 2048  # rows per grid step (VMEM: Kp*2048*4B + Gp*2048*4B one-hot)
 _LANES = 128
 _SUBLANES = 8
@@ -74,7 +76,6 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-@functools.partial(jax.jit, static_argnames=("n_groups", "interpret"))
 def grouped_sums_pallas(
     gids, mask, values, n_groups: int, interpret: bool = False
 ):
@@ -109,6 +110,12 @@ def grouped_sums_pallas(
         interpret=interpret,
     )(gid_row, planes)
     return out_t[:k, :n_groups].T
+
+
+grouped_sums_pallas = jit_program(
+    grouped_sums_pallas, "agg_pallas_kernel",
+    static_argnames=("n_groups", "interpret"),
+)
 
 
 def grouped_sums_xla(gids, mask, values, n_groups: int):

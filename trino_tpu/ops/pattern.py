@@ -18,13 +18,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import device_get_async, concat_batches
+from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.columnar.dictionary import StringDictionary
 from trino_tpu.expr import ExprCompiler
 from trino_tpu.expr.compiler import Val, _and_valid
@@ -293,9 +292,7 @@ class PatternRecognitionOperator:
         if n == 0:
             return
         cap = next_pow2(n, floor=1)
-        big = jax.jit(Batch.compact_device, static_argnames=("out_capacity",))(
-            big, out_capacity=cap
-        )
+        big = COMPACT(big, out_capacity=cap)
         node = self.node
         keys = [SortKey(self._channel(s.name)) for s in node.partition_by] + [
             SortKey(self._channel(s.name), ascending=asc, nulls_first=nf)
@@ -305,7 +302,7 @@ class PatternRecognitionOperator:
             perm = multi_key_sort_perm(big, keys)
             live = jnp.take(big.mask(), perm, mode="clip")
             big = big.gather(perm, valid=live)
-        host = device_get_async(big)  # lint: allow(host-transfer)
+        host = host_pull(big, "host_operator")
         live_h = np.asarray(host.mask())[:n]
         # partition ids from sorted partition-key runs: a new partition
         # starts wherever ANY key's (value, validity) changes — collision
@@ -358,7 +355,7 @@ class PatternRecognitionOperator:
             if cond is None:
                 continue
             mask = compiler.filter_mask(rewrite_nav(cond))
-            ok[vi] = np.asarray(device_get_async(mask))[:n]  # lint: allow(host-transfer)
+            ok[vi] = host_pull(mask, "host_operator")[:n]
         ok &= live_h[None, :]
         var_ix = {v: i for i, v in enumerate(self.vars)}
         # host NFA walk per partition

@@ -13,16 +13,15 @@ configurations; the SQL spec leaves sampling implementation-defined.)
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar import Batch
 from trino_tpu.ops.common import splitmix64
+from trino_tpu.telemetry.programs import jit_program
 
 
-@jax.jit
-def _sample_step(batch: Batch, offset, ratio) -> Batch:
+def _sample(batch: Batch, offset, ratio) -> Batch:
     """Keep rows where splitmix64(salted position) < ratio.  Salt/offset/
     ratio are TRACED arguments so every sampled query shares ONE compiled
     kernel (the _STEP_CACHE convention, via jit's own signature cache)."""
@@ -32,6 +31,9 @@ def _sample_step(batch: Batch, offset, ratio) -> Batch:
     # top 53 bits -> uniform [0, 1)
     unif = (u >> jnp.uint64(11)).astype(jnp.float64) / float(1 << 53)
     return batch.filter(unif < ratio)
+
+
+_sample_step = jit_program(_sample, "sample")
 
 
 class SampleOperator:

@@ -15,9 +15,10 @@ import jax
 import jax.numpy as jnp
 
 from trino_tpu.columnar import Batch
-from trino_tpu.columnar.batch import concat_batches
+from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.ops.common import SortKey, multi_key_sort_perm, next_pow2
 from trino_tpu.ops.aggregation import _pad_device
+from trino_tpu.telemetry.programs import jit_program
 
 
 #: shared jitted steps across per-query instances (see filter_project)
@@ -41,7 +42,7 @@ class OrderByOperator:
         self._acc: list[Batch] = []
         key = ("orderby", tuple(keys))
         if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = jax.jit(self._sort_step)
+            _STEP_CACHE[key] = jit_program(self._sort_step, "sort")
         self._step = _STEP_CACHE[key]
 
     def _sort_step(self, batch: Batch) -> Batch:
@@ -64,19 +65,11 @@ class OrderByOperator:
         the finish-time merge is a full host lexsort, so a per-run device
         sort would be thrown-away work; the single-run case re-sorts on
         device at finish.  Returns the host run, or an int disk-run id."""
-        from trino_tpu.columnar.batch import device_get_async
-
         big = self._acc[0] if len(self._acc) == 1 else concat_batches(self._acc)
         self._acc.clear()
         n = big.num_rows_host()
         cap = next_pow2(max(n, 1), floor=1)
-        ckey = ("spill_compact",)
-        if ckey not in _STEP_CACHE:
-            _STEP_CACHE[ckey] = jax.jit(
-                Batch.compact_device, static_argnames=("out_capacity",)
-            )
-        compact = _STEP_CACHE[ckey](big, out_capacity=cap)
-        host = device_get_async(compact)  # lint: allow(host-transfer)
+        host = host_pull(COMPACT(big, out_capacity=cap), "sort_compact")
         spiller = self._get_spiller()
         if spiller is None:
             return host
@@ -166,7 +159,9 @@ class TopNOperator:
         self._state: Optional[Batch] = None
         key = ("topn", tuple(keys), n)
         if key not in _STEP_CACHE:
-            _STEP_CACHE[key] = jax.jit(self._merge_step, static_argnames=("out_cap",))
+            _STEP_CACHE[key] = jit_program(
+                self._merge_step, "sort_merge", static_argnames=("out_cap",)
+            )
         self._step = _STEP_CACHE[key]
 
     def _merge_step(self, batch: Batch, out_cap: int) -> Batch:

@@ -14,13 +14,13 @@ shapes: one jitted gather per batch.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
 from trino_tpu.expr import ExprCompiler
 from trino_tpu.expr.ir import Expr
+from trino_tpu.telemetry.programs import jit_program
 
 _STEP_CACHE: dict = {}
 
@@ -41,7 +41,7 @@ class UnnestOperator:
         self.raw_step = self._make_step()
         cached = _STEP_CACHE.get(key)
         if cached is None:
-            cached = jax.jit(self.raw_step)
+            cached = jit_program(self.raw_step, "unnest")
             _STEP_CACHE[key] = cached
         self._step = cached
 

@@ -32,6 +32,7 @@ from trino_tpu.ops.common import (
     multi_key_sort_perm,
     next_pow2,
 )
+from trino_tpu.telemetry.programs import jit_program
 
 
 @dataclass(frozen=True)
@@ -96,7 +97,7 @@ class WindowOperator:
         )
         cached = _WINDOW_STEP_CACHE.get(key)
         if cached is None:
-            cached = jax.jit(self._window_step)
+            cached = jit_program(self._window_step, "window")
             _WINDOW_STEP_CACHE[key] = cached
         self._step = cached
 

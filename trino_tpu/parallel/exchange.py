@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar import Batch, Column
+from trino_tpu.columnar.batch import host_pull
 from trino_tpu.ops.common import next_pow2
 from trino_tpu.parallel.spmd import WorkerMesh
 
@@ -154,7 +155,7 @@ def exchange_slot_cap(
         lambda: _counts_kernel(key_channels, wm.n),
         collective=True,
     )
-    counts = np.asarray(counts_fn(stacked))  # [W, W]
+    counts = host_pull(counts_fn(stacked), "capacity")  # [W, W]
     if TRACE_CACHE.retraces > r0:
         from trino_tpu.runtime.lifecycle import check_current
 
@@ -216,6 +217,8 @@ def fused_repartition(
         ("fused_exchange", tuple(key_channels), slot_cap) + tuple(key),
         build,
         collective=True,
+        # a fused consumer's kind (`agg_final`, ...) is part of the name
+        name="_".join(("fused_exchange", *key[:1], "x")),
     )
     return fn(stacked)
 

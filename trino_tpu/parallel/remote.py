@@ -1037,10 +1037,9 @@ class _StageScheduler:
 
     def _merge(self, per_producer: list, node: RemoteSourceNode) -> PhysicalPlan:
         """Ordered merge of per-worker sorted shards (MergeOperator role)."""
-        import jax
         import numpy as np
 
-        from trino_tpu.columnar.batch import concat_batches
+        from trino_tpu.columnar.batch import concat_batches, host_pull
         from trino_tpu.ops.common import SortKey
         from trino_tpu.ops.merge import merge_sorted_shards
 
@@ -1048,7 +1047,7 @@ class _StageScheduler:
         for bs in per_producer:
             if not bs:
                 continue
-            host = jax.device_get(concat_batches(bs))  # lint: allow(host-transfer)
+            host = host_pull(concat_batches(bs), "remote_page")
             mask = np.asarray(host.mask())
             idx = np.nonzero(mask)[0]
             shards.append(_take_host(host, idx))
@@ -1064,11 +1063,9 @@ class _StageScheduler:
 
 class _LocalResult:
     def __init__(self, plan: PhysicalPlan):
-        import jax
+        from trino_tpu.columnar.batch import host_pull
 
-        from trino_tpu.columnar.batch import concat_batches
-
-        batches = [jax.device_get(b) for b in plan.stream]  # lint: allow(host-transfer)
+        batches = [host_pull(b, "remote_page") for b in plan.stream]
         self.plan = PhysicalPlan(iter(batches), plan.symbols)
 
 

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import device_get_async, concat_batches
+from trino_tpu.columnar.batch import concat_batches, host_pull
 from trino_tpu.connectors.api import CatalogManager
 from trino_tpu.expr import ExprCompiler
 from trino_tpu.expr.ir import InputRef, and_
@@ -370,9 +370,10 @@ class DistributedQueryRunner(LocalQueryRunner):
         with tr.span("schedule"):
             host = executor.run(sub)
             rows = []
-            for batch in host.stream:
-                check_current()  # cancel/deadline between result batches
-                rows.extend(tuple(r) for r in batch.to_pylist())
+            with tr.span("result"):
+                for batch in host.stream:
+                    check_current()  # cancel/deadline between batches
+                    rows.extend(tuple(r) for r in batch.to_pylist())
         if stats is not None:
             stats.mesh_profile = profile
         return MaterializedResult(
@@ -474,11 +475,10 @@ class StageExecutor:
             realigned=realigned,
         )
 
-    def _host_pull(self, *vals):  # lint: allow(host-transfer)
-        """Declared host boundary for the runner's tiny device->host reads
-        (speculative overflow flags, speculative-off capacity syncs): every
-        value crosses in ONE transfer."""
-        out = [np.asarray(x) for x in device_get_async(tuple(vals))]
+    def _host_pull(self, *vals, why: str = "capacity"):
+        """The runner's tiny device->host reads (speculative overflow
+        flags, capacity syncs): every value crosses in ONE transfer."""
+        out = host_pull(tuple(vals), why)
         return out if len(out) > 1 else out[0]
 
     def _call(self, fn, *args, phase: str = "compute", fid: Optional[int] = None):
@@ -492,6 +492,9 @@ class StageExecutor:
         prof = self.profile
         owner = self._current_fid if fid is None else fid
         r0 = TRACE_CACHE.retraces
+        tr = prof.tracer
+        if tr.enabled:
+            tr.last_launch = None
         t0 = now()
         out = fn(*args)
         if prof.blocking:
@@ -514,12 +517,16 @@ class StageExecutor:
         else:
             booked = phase
         prof.add_phase(owner, booked, dt)
-        tr = prof.tracer
-        if tr.enabled:
-            # child span per SPMD launch, carrying the phase attribution
-            sp = tr.record(
-                "launch", t0, t0 + dt, {"phase": booked, "fragment": owner}
-            )
+        sp = tr.last_launch if tr.enabled else None
+        if sp is not None:
+            # the launch door (telemetry/programs.py) recorded this
+            # launch's span, with its `step`; book the phase attribution
+            # on it rather than record the same launch twice.  (Handed an
+            # exchange function that launches several programs, this is
+            # the newest: the exchange itself.)
+            sp.attrs.update(phase=booked, fragment=owner)
+            if prof.blocking:
+                sp.end_s = t0 + dt  # the wait on the device was inside
             # compile stalls nest as children of the launch span, so
             # EXPLAIN ANALYZE VERBOSE and Perfetto separate compile from
             # compute instead of one undifferentiated launch block
@@ -572,7 +579,9 @@ class StageExecutor:
             out = self._fragment_result(sub.fragment.id)
             if isinstance(out, _Dist):  # defensive: root should be SINGLE
                 self._current_fid = sub.fragment.id
-                host = unstack_batch(device_get_async(self._gather_compact(out.stacked)))  # lint: allow(host-transfer)
+                host = unstack_batch(
+                    host_pull(self._gather_compact(out.stacked), "result")
+                )
                 self.profile.bump("result_gather")
                 self.profile.add_collective(
                     self._root_fid, batch_bytes(host), "gather",
@@ -732,7 +741,7 @@ class StageExecutor:
 
         stacked = res.stacked  # deferred chain runs as its own phase
         with self.profile.phase(fid, "transfer"):
-            host = device_get_async(stacked)  # lint: allow(host-transfer)
+            host = host_pull(stacked, "stage_output")
         self.profile.bump("spool_write")
         spooled_fragments_counter().inc()
         self.profile.fragment(fid).bytes_to_host += batch_bytes(host)
@@ -869,7 +878,7 @@ class StageExecutor:
         )
         reduced = self._call(fn, stacked)
         with self.profile.phase(self._current_fid, "transfer"):
-            summ = np.asarray(device_get_async(reduced))  # lint: allow(host-transfer)
+            summ = host_pull(reduced, "dynamic_filter")
         self.profile.bump("dynamic_filter_sync")
         self.profile.add_collective(
             self._current_fid, int(summ.nbytes), "reduce", "dynamic_filter"
@@ -937,14 +946,16 @@ class StageExecutor:
         if isinstance(child, PhysicalPlan):
             return child
         fid = self._current_fid
+        root = fid == self._root_fid
+        why = "result" if root else "stage_output"
         if node.exchange_kind == "merge":
-            batch = self._merge_gather(child, node)
+            batch = self._merge_gather(child, node, why)
         else:
             stacked = child.stacked  # deferred chain runs as its own phase
             stacked = self._gather_compact(stacked)
             with self.profile.phase(fid, "transfer"):
-                batch = unstack_batch(device_get_async(stacked))  # lint: allow(host-transfer)
-        purpose = "result_gather" if fid == self._root_fid else "host_gather"
+                batch = unstack_batch(host_pull(stacked, why))
+        purpose = "result_gather" if root else "host_gather"
         self.profile.bump(purpose)
         self.profile.fragment(fid).bytes_to_host += batch_bytes(batch)
         self.profile.add_collective(
@@ -952,16 +963,15 @@ class StageExecutor:
         )
         return PhysicalPlan(iter([batch]), child.symbols)
 
-    def _merge_gather(self, child: _Dist, node: RemoteSourceNode) -> Batch:
+    def _merge_gather(self, child: _Dist, node: RemoteSourceNode,
+                      why: str) -> Batch:
         """Merge exchange: per-worker sorted shards -> one ordered host batch
         (MergeOperator/MergeSortedPages role)."""
         from trino_tpu.ops.merge import merge_sorted_shards
 
         # compaction is STABLE (cumsum-scatter keeps live-row order), so
         # the per-worker sorted runs stay sorted for the host merge
-        host = device_get_async(  # lint: allow(host-transfer)
-            self._gather_compact(child.stacked)
-        )
+        host = host_pull(self._gather_compact(child.stacked), why)
         keys = [
             SortKey(child.channel(s.name), asc, nf)
             for s, asc, nf in node.orderings
@@ -1223,7 +1233,9 @@ class StageExecutor:
                     ("dyn_counts", tuple(k for k, _, _ in pend), dkey),
                     build_counts,
                 )
-                counts = np.asarray(device_get_async(self._call(fn, out._stacked)))  # lint: allow(host-transfer)
+                counts = host_pull(
+                    self._call(fn, out._stacked), "dynamic_filter"
+                )
                 self.dynamic_filter_stats[node.handle.table] = (
                     int(counts[:, 0].sum()), int(counts[:, 1].sum())
                 )
@@ -1589,7 +1601,7 @@ class StageExecutor:
         final_op = self._final_op(specs, partial_op, states)
         fid = self._current_fid
         with self.profile.phase(fid, "transfer"):
-            gathered = unstack_batch(device_get_async(states))  # lint: allow(host-transfer)
+            gathered = unstack_batch(host_pull(states, "stage_output"))
         self.profile.bump("state_gather")
         self.profile.fragment(fid).bytes_to_host += batch_bytes(gathered)
         from trino_tpu.ops.aggregation import _pad_device
@@ -2195,7 +2207,7 @@ class StageExecutor:
                 )
                 with self.profile.phase(fid, "transfer"):
                     over_h, total_h, live_h = self._host_pull(
-                        over, total, live
+                        over, total, live, why="overflow_flag"
                     )
                 self.profile.bump("join_overflow_check")
                 self.profile.add_collective(
@@ -2274,13 +2286,10 @@ class StageExecutor:
             fcol = stacked.columns[fk]
             if fcol.valid is None:
                 return False
-            return bool(
-                np.any(
-                    (lambda _m, _v: np.asarray(_m) & ~np.asarray(_v))(
-                        *device_get_async((stacked.mask(), fcol.valid))  # lint: allow(host-transfer)
-                    )
-                )
+            live, valid = host_pull(
+                (stacked.mask(), fcol.valid), "group_stats"
             )
+            return bool(np.any(live & ~valid))
 
         if node.filter is not None:
             # residual-filtered semi join, PARTITIONED on the key: both

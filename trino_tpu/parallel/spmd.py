@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from trino_tpu.columnar import Batch, Column
 from trino_tpu.ops.common import next_pow2
 from trino_tpu.telemetry.compile_events import OBSERVATORY
+from trino_tpu.telemetry.programs import jit_program
 
 
 class TraceCache:
@@ -320,8 +321,10 @@ def unstack_batch(stacked: Batch) -> Batch:
     return Batch(cols, mask)
 
 
-def spmd_step(wm: WorkerMesh, step: Callable, out_replicated: bool = False):
-    """Lift a per-worker pure Batch step into a jitted SPMD program.
+def spmd_step(wm: WorkerMesh, step: Callable, name: str,
+              out_replicated: bool = False):
+    """Lift a per-worker pure Batch step into a jitted SPMD program named
+    `name` (the launch door, telemetry/programs.py).
 
     `step` sees a worker-local Batch (no leading axis) and returns one; the
     wrapper maps it over the mesh with shard_map, squeezing the local [1, cap]
@@ -342,10 +345,11 @@ def spmd_step(wm: WorkerMesh, step: Callable, out_replicated: bool = False):
         out_specs=P() if out_replicated else P("workers"),
         check_vma=False,
     )
-    return jax.jit(inner)
+    return jit_program(inner, name)
 
 
-def spmd_collective_step(wm: WorkerMesh, step: Callable, out_replicated: bool = False):
+def spmd_collective_step(wm: WorkerMesh, step: Callable, name: str,
+                         out_replicated: bool = False):
     """Like spmd_step but `step` may use collectives over axis name
     'workers' (all_to_all / all_gather / psum); the local shard view keeps
     its leading axis of 1 so collective outputs shape naturally."""
@@ -361,7 +365,21 @@ def spmd_collective_step(wm: WorkerMesh, step: Callable, out_replicated: bool = 
         out_specs=P() if out_replicated else P("workers"),
         check_vma=False,
     )
-    return jax.jit(inner)
+    return jit_program(inner, name)
+
+
+def step_name(key: tuple, collective: bool = False) -> str:
+    """The program name of a `cached_spmd_step` key: its kind (`key[0]`);
+    a deferred `chain` appends the kinds of the steps it fused, in order;
+    a program with collectives ends in `_x`.  No fingerprints, literals or
+    fragment ids: those are in the `launch` span's attributes."""
+    def kind(k):
+        return kind(k[0]) if isinstance(k, tuple) else str(k)
+
+    name = kind(key)
+    if name == "chain":
+        name = "_".join(dict.fromkeys(["chain", *map(kind, key[1:])]))
+    return name + "_x" if collective else name
 
 
 def cached_spmd_step(
@@ -370,12 +388,17 @@ def cached_spmd_step(
     build_step: Callable,
     out_replicated: bool = False,
     collective: bool = False,
+    name: Optional[str] = None,
 ):
     """TRACE_CACHE-backed spmd_step: `build_step()` constructs the per-worker
     step closure only on a cache miss.  `key` must fingerprint the step's
-    semantics (expression text, static caps, mesh) — see TraceCache."""
+    semantics (expression text, static caps, mesh) — see TraceCache.  The
+    program is named `name`, by default `step_name(key, collective)`."""
     lift = spmd_collective_step if collective else spmd_step
     return TRACE_CACHE.get(
         ("spmd", collective, out_replicated, mesh_key(wm)) + tuple(key),
-        lambda: lift(wm, build_step(), out_replicated=out_replicated),
+        lambda: lift(
+            wm, build_step(), name or step_name(key, collective),
+            out_replicated=out_replicated,
+        ),
     )

@@ -78,6 +78,13 @@ class QueryStatistics:
     #: archived profile-artifact key (telemetry/profile_store; empty when
     #: no store is attached)
     profile_key: str = ""
+    #: the launch/pull boundary's always-on counts (lifecycle.QueryContext):
+    #: device programs dispatched, blocking device->host reads, the seconds
+    #: the statement's threads were blocked in them, bytes read back
+    launches: int = 0
+    host_pulls: int = 0
+    host_pull_s: float = 0.0
+    d2h_bytes: int = 0
 
 
 @dataclass

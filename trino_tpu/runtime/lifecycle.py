@@ -255,6 +255,18 @@ class QueryContext:
         #: contextvar, the runner joins outcomes + stamps hindsight before
         #: archiving (same lane-safety contract as the tracer)
         self.decisions = None
+        # -- the launch/pull boundary's always-on counts (bumped by
+        # telemetry/programs.Program and columnar/batch.host_pull, copied
+        # into QueryStatistics when the statement finishes)
+        #: device programs dispatched for this statement
+        self.launches = 0
+        #: blocking device->host reads, the seconds the reading thread
+        #: was blocked in them, and the bytes they brought back
+        self.host_pulls = 0
+        self.host_pull_s = 0.0
+        self.d2h_bytes = 0
+        #: `step` of the newest launch (`after=` of the next host_pull)
+        self.last_step = ""
 
     # -- state machine --------------------------------------------------------
 

@@ -14,7 +14,7 @@ from typing import Iterable, Iterator, Optional
 
 from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import device_get_async
+from trino_tpu.columnar.batch import COMPACT, host_pull
 from trino_tpu.connectors.api import CatalogManager
 from trino_tpu.expr.ir import (
     Call,
@@ -36,6 +36,7 @@ from trino_tpu.ops.sort import LimitOperator, OrderByOperator, TopNOperator
 from trino_tpu.ops.values import ValuesOperator
 from trino_tpu.planner import plan as P
 from trino_tpu.planner.functions import HOLISTIC_AGGS
+from trino_tpu.telemetry.programs import jit_program
 
 
 class PhysicalPlan:
@@ -500,7 +501,7 @@ class LocalExecutionPlanner:
                 need, self._budget(), self.properties
             )
             spiller = self._make_spiller()
-            build_host = device_get_async(list(build_batches))
+            build_host = host_pull(list(build_batches), "build_to_host")
             build_batches.clear()
             build_side = _spill.partition_side(
                 build_host, build_keys, n_waves, spiller, "jb"
@@ -509,7 +510,7 @@ class LocalExecutionPlanner:
 
             def wave_stream():
                 try:
-                    probe_host = device_get_async(list(probe.stream))
+                    probe_host = host_pull(list(probe.stream), "probe_to_host")
                     probe_side = _spill.partition_side(
                         probe_host, probe_keys, n_waves, spiller, "jp"
                     )
@@ -550,7 +551,7 @@ class LocalExecutionPlanner:
             # its own device references at its next batch boundary
             spiller = self._make_spiller()
             k = _spill.wave_count(need, self._budget(), self.properties)
-            host = device_get_async(list(build_batches))
+            host = host_pull(list(build_batches), "build_to_host")
             holder["side"] = _spill.partition_side(
                 host, build_keys, k, spiller, "jb"
             )
@@ -777,7 +778,7 @@ def _revoked_join_remainder(make_op, holder, probe_keys, probe_iter, ctx,
     against the complete build, so the split point is exact."""
     from trino_tpu.runtime import spill as _spill
 
-    probe_host = device_get_async(list(probe_iter))
+    probe_host = host_pull(list(probe_iter), "probe_to_host")
     probe_side = _spill.partition_side(
         probe_host, probe_keys, holder["k"], holder["spiller"], "jp"
     )
@@ -850,7 +851,7 @@ def _agg_wave_stream(make_op, feed, key_channels: list, budget: int,
                 return 0
             if acc[0] is None:
                 acc[0] = _spill.SpillingAccumulator(get_spiller(), "aggstate")
-            acc[0].push_chunk(device_get_async(list(state["device"])))
+            acc[0].push_chunk(host_pull(list(state["device"]), "spill"))
             state["device"].clear()
             freed = state["bytes"]
             state["bytes"] = 0
@@ -964,7 +965,7 @@ def _window_wave_stream(make_op, feed, key_channels: list, budget: int,
             # shared dictionaries counted once across the accumulation
             total += batch_bytes(b, _seen_dicts=seen_dicts)
             if store is not None:
-                store.push_chunk(device_get_async([b]))
+                store.push_chunk(host_pull([b], "spill"))
             else:
                 acc_dev.append(b)
                 if total > budget:
@@ -973,7 +974,7 @@ def _window_wave_stream(make_op, feed, key_channels: list, budget: int,
                     )
                     store = _spill.SpillingAccumulator(spiller, "window")
                     # device memory -> spill tier
-                    store.push_chunk(device_get_async(list(acc_dev)))
+                    store.push_chunk(host_pull(list(acc_dev), "spill"))
                     acc_dev.clear()
         if store is None:
             yield from make_op().process(iter(acc_dev))
@@ -1006,7 +1007,7 @@ def _agg_raw_wave_stream(make_op, op, feed, key_channels: list, budget: int,
     spool = []
     over = False
     for b in it:
-        spool.append(device_get_async(b))
+        spool.append(host_pull(b, "spill"))
         try:
             op.push(b)
             if op.state_bytes() > budget:
@@ -1021,7 +1022,7 @@ def _agg_raw_wave_stream(make_op, op, feed, key_channels: list, budget: int,
             op.memory_ctx.close()
         return
     consumed = len(spool)
-    spool.extend(device_get_async(list(it)))
+    spool.extend(host_pull(list(it), "spill"))
     frac = consumed / max(len(spool), 1)
     projected = op.state_bytes() / max(frac, 1e-3)
     n_waves = _spill.wave_count(int(2 * projected), budget, properties)
@@ -1163,7 +1164,6 @@ def _host_minmax(batches, channel: int):
     blocks the dispatch thread for the whole copy, per batch."""
     import numpy as np
 
-    import jax
     import jax.numpy as jnp
 
     lo = hi = None
@@ -1195,12 +1195,12 @@ def _host_minmax(batches, channel: int):
                 n = jnp.any(live).astype(data.dtype)
                 return jnp.stack([lo_, hi_, n])
 
-            step = jax.jit(_step)
+            step = jit_program(_step, "minmax_stats")
             _MINMAX_STEP_CACHE[dt.str] = step
         live = b.mask()
         if c.valid is not None:
             live = jnp.logical_and(live, c.valid)
-        packed = np.asarray(step(c.data, live))
+        packed = host_pull(step(c.data, live), "dynamic_filter")
         if packed[2] == 0:
             continue
         blo, bhi = packed[0], packed[1]
@@ -1230,13 +1230,7 @@ def _range_expr(sym, lo, hi) -> Expr:
     )
 
 
-#: jitted compaction per static output capacity (shape-bucketed)
-_COMPACT_CACHE: dict = {}
-
-
 def _compact_stream(stream):
-    import jax
-
     from trino_tpu.ops.common import next_pow2
 
     for b in stream:
@@ -1245,8 +1239,4 @@ def _compact_stream(stream):
         if cap >= b.capacity:
             yield b
             continue
-        fn = _COMPACT_CACHE.get(cap)
-        if fn is None:
-            fn = jax.jit(Batch.compact_device, static_argnames=("out_capacity",))
-            _COMPACT_CACHE[cap] = fn
-        yield fn(b, out_capacity=cap)
+        yield COMPACT(b, out_capacity=cap)

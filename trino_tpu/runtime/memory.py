@@ -23,8 +23,6 @@ from __future__ import annotations
 import threading
 from typing import Optional
 
-import numpy as np
-
 
 class ExceededMemoryLimitException(RuntimeError):
     def __init__(self, message: str, node: Optional["MemoryContext"] = None):
@@ -65,7 +63,8 @@ def batch_bytes(batch, _seen_dicts: "set | None" = None) -> int:
             seen_dicts.add(id(d))
             total += _cached_dictionary_bytes(d)
     if batch.row_mask is not None:
-        total += np.asarray(batch.row_mask).size
+        # a shape attribute: accounting must not move the mask to the host
+        total += batch.row_mask.size
     return int(total)
 
 

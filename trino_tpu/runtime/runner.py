@@ -542,6 +542,10 @@ class LocalQueryRunner:
             }
         stats.peak_memory_bytes = ctx.peak_memory
         stats.gate_wait_s = round(ctx.gate_wait_s, 6)
+        stats.launches = ctx.launches
+        stats.host_pulls = ctx.host_pulls
+        stats.host_pull_s = round(ctx.host_pull_s, 6)
+        stats.d2h_bytes = ctx.d2h_bytes
         adm = current_admission()
         if adm is not None:
             stats.group, stats.queued_s = adm[0], round(adm[1], 6)
@@ -549,7 +553,7 @@ class LocalQueryRunner:
         if ref is not None:
             stats.profile_key = ref["key"]
         if tracer.enabled:
-            stats.spans = len(tracer.flat_spans())
+            stats.spans = tracer.count()
         return stats
 
     def _check_table_access(self, plan) -> None:
@@ -702,8 +706,11 @@ class LocalQueryRunner:
         from trino_tpu.runtime.dispatcher import device_slice
         from trino_tpu.runtime.lifecycle import check_current
 
-        with self._tracer.span("execute"):
-            with device_slice():
+        tr = self._tracer
+        with tr.span("execute"):
+            # `build`: planning drains blocking builds, so their launches
+            # and host pulls nest under it
+            with tr.span("build"), device_slice():
                 lp = LocalExecutionPlanner(
                     self.catalogs,
                     target_splits=self.target_splits,
@@ -714,13 +721,16 @@ class LocalQueryRunner:
             rows = []
             it = iter(physical.stream)
             done = object()
-            while True:
-                with device_slice():
-                    batch = next(it, done)
-                if batch is done:
-                    break
-                check_current()  # cancel/deadline between result batches
-                rows.extend(tuple(r) for r in batch.to_pylist())
+            # `result`: every batch pull (its launches) and its rows'
+            # transfer (a host_pull with why=result); nothing per batch
+            with tr.span("result"):
+                while True:
+                    with device_slice():
+                        batch = next(it, done)
+                    if batch is done:
+                        break
+                    check_current()  # cancel/deadline between result batches
+                    rows.extend(tuple(r) for r in batch.to_pylist())
             self._last_peak_memory = lp.memory.peak
         return MaterializedResult(
             list(plan.column_names), rows, [s.type for s in plan.symbols]

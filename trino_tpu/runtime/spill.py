@@ -332,9 +332,9 @@ def pull_host(*trees):
     host exactly here, immediately before being partitioned and spilled.
     Lives in runtime/ (not the linted device paths) because moving data
     off-device is this module's whole purpose."""
-    from trino_tpu.columnar.batch import device_get_async
+    from trino_tpu.columnar.batch import host_pull
 
-    out = device_get_async(tuple(trees))
+    out = host_pull(tuple(trees), "spill")
     return out if len(out) > 1 else out[0]
 
 

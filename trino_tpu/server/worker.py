@@ -603,9 +603,9 @@ class WorkerServer:
             return [batches_to_bytes(batches)], ranges
         channels, n = desc.output_partitioning
         host = concat_batches(batches)
-        import jax
+        from trino_tpu.columnar.batch import host_pull
 
-        host = jax.device_get(host)
+        host = host_pull(host, "remote_page")
         buckets = partition_batches([host], channels, n)
         return [batches_to_bytes(bs) for bs in buckets], ranges
 
@@ -613,24 +613,26 @@ class WorkerServer:
 def _result_ranges(batches, symbols) -> dict:
     """{symbol name: [lo, hi]} over 1-D numeric result columns (the
     dynamic-filter summary; dictionary/limb-plane/bool columns skipped)."""
-    import jax
     import numpy as np
+
+    from trino_tpu.columnar.batch import host_pull
 
     out: dict = {}
     for i, sym in enumerate(symbols):
         lo = hi = None
         for b in batches:
             c = b.columns[i]
-            d = np.asarray(jax.device_get(c.data))
+            d, live, valid = host_pull(
+                (c.data, b.mask(), c.valid), "dynamic_filter"
+            )
             if d.ndim != 1 or c.dictionary is not None or d.dtype == np.bool_:
                 lo = None
                 break
             if not np.issubdtype(d.dtype, np.number):
                 lo = None
                 break
-            live = np.asarray(jax.device_get(b.mask()))
-            if c.valid is not None:
-                live = live & np.asarray(jax.device_get(c.valid))
+            if valid is not None:
+                live = live & valid
             if not live.any():
                 continue
             vals = d[live]

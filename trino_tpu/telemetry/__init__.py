@@ -8,9 +8,47 @@ served here as Prometheus text at GET /v1/metrics.
   * `spans` — per-query span trees (query -> analyze -> optimize ->
     fragment -> schedule -> per-fragment SPMD launches), exportable as
     Chrome-trace/Perfetto JSON; zero-overhead NULL_TRACER when off.
+  * `programs` — the launch door: `jit_program` names every device program
+    and records/counts its launches.  (The host-pull door,
+    `columnar.batch.host_pull`, is its counterpart for device->host reads.)
   * `metrics` — counters/gauges/histograms registered once and bumped
     everywhere; the single home for the engine's formerly scattered
     counters (MeshProfile, trace cache, buffer pool).
+
+The vocabularies, listed once (tests/test_telemetry.py holds the engine to
+them; a new site takes a name from here or adds one here):
+
+span names:
+    query, queued, analyze, optimize, fragment, execute, build, result,
+    schedule, fragment-N, transfer, launch, compile, host_pull, task
+
+launch steps (`step=` of a `launch` span; the XLA module is `jit_<step>`):
+    local — filter_project, unnest, sample, window, sort, sort_merge,
+    compact, row_count, minmax_stats, agg_reduce, agg_range, agg_mark,
+    agg_key_stats, agg_pallas_kernel, join_locate_sorted,
+    join_locate_table, join_expand, join_expand_unique,
+    join_nested_expand, join_semi_mark, join_semi_mark_residual;
+    mesh (`cached_spmd_step` kinds; `_x` appended when the program holds a
+    collective; `chain_` followed by the kinds a deferred chain fused) —
+    chain, scan_pred, dyn_filter, filter, project, agg_partial,
+    agg_colocated, recode,
+    mark_distinct, window, sort, topn, limit, dynfilters, dyn_counts,
+    gather_compact, broadcast_compact, state_compact,
+    licensed_probe_compact, licensed_compact, agg_wave_filter,
+    licensed_expand, fused_expand, locate, expand, semi_mark, unnest,
+    exchange_counts, fused_exchange, agg_final, agg_single, broadcast
+
+host_pull why (`why=` of a `host_pull` span: what the host needed it for):
+    result (rows for the client), capacity (a count that sizes the next
+    program's static shape), overflow_flag (a speculative capacity's
+    check), group_stats (key ranges that choose an aggregation or join
+    layout), dynamic_filter (build-side key ranges and pruning counts),
+    build_to_host, probe_to_host (join sides leaving the device for
+    partition waves), spill (operator state to the spill tier),
+    sort_compact (a sort run leaving the device), dictionary (codes read
+    for host-side string work), host_operator (an operator that runs on
+    the host: pattern matching), stage_output (a mesh stage's output to
+    the coordinator or the spool), remote_page (a worker task's pages)
 """
 
 from trino_tpu.telemetry.metrics import (

@@ -728,8 +728,8 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
     )
     fastpath = reg.counter(
         _PREFIX + "decimal_fastpath_total",
-        "decimal-sum kernel path selections at TRACE time (ops/aggregation "
-        "+ ops/window): proven = statically licensed single-plane i64 sum "
+        "decimal-sum kernel path selections, counted per traced program, "
+        "not per execution (ops/aggregation + ops/window): proven = statically licensed single-plane i64 sum "
         "(range certificate or precision proof, no runtime check), "
         "runtime_check = a lax.cond fits probe was compiled in, limb = "
         "unconditional limb-plane arithmetic",
@@ -739,8 +739,9 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
         fastpath.touch(p)
     aggpath = reg.counter(
         _PREFIX + "aggregation_path_total",
-        "grouped-aggregation kernel path selections at TRACE time "
-        "(ops/aggregation): pallas = Mosaic one-hot MXU kernel, onehot = "
+        "grouped-aggregation kernel path per EXECUTION: the choice a step "
+        "made while tracing, replayed by the launch door on every launch "
+        "of that program (telemetry/programs.py): pallas = Mosaic one-hot MXU kernel, onehot = "
         "exact int64 one-hot masked reduction, segmented = scatter-adds over dense codes, "
         "positional = range-positional domain, sort = sort-based numbering",
         labelnames=("path",),
@@ -858,9 +859,9 @@ def decimal_fastpath_counter() -> Counter:
 
 
 def aggregation_path_counter() -> Counter:
-    """Trace-time grouped-aggregation path selections, labeled
-    path=pallas|onehot|segmented|positional|sort (static per compiled
-    program, so warm replays add nothing)."""
+    """Grouped-aggregation path per execution, labeled
+    path=pallas|onehot|segmented|positional|sort: chosen while the step
+    traces, replayed on every launch (telemetry/programs.note_path)."""
     return REGISTRY.counter(_PREFIX + "aggregation_path_total")
 
 

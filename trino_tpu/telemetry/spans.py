@@ -17,7 +17,15 @@ Design constraints:
   * spans nest by runtime containment: the tracer keeps an open-span stack,
     `span()` pushes/pops, `record()` appends an already-closed child to the
     innermost open span (the shape `parallel/runner.py::_call` needs — it
-    knows the duration only after the launch returned).
+    knows the duration only after the launch returned);
+  * one clock with the device: `span()` also enters a
+    `jax.profiler.TraceAnnotation("tt:<name>")` for the span's lifetime, so
+    in any profiler session (the benchmark's traced run, the `profile_dir`
+    session property) the engine's spans lie in the host plane of the same
+    `.xplane.pb` as the device ops.  `record()` writes none: a launch
+    already has jax's own host event under the program's name
+    (telemetry/programs.py).  Outside a session an annotation costs a
+    flag test.
 """
 
 from __future__ import annotations
@@ -25,7 +33,13 @@ from __future__ import annotations
 import itertools
 import json
 import time
+from threading import get_ident
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of the engine's spans in a profiler trace's host plane
+ANNOTATION_PREFIX = "tt:"
 
 #: THE phase-timing clock.  Every engine-side wall measurement (spans,
 #: MeshProfile phases, stage self-time) reads this one callable so span and
@@ -69,16 +83,19 @@ class Span:
 class _OpenSpan:
     """Context manager returned by SpanTracer.span()."""
 
-    __slots__ = ("tracer", "sp")
+    __slots__ = ("tracer", "sp", "annotation")
 
     def __init__(self, tracer: "SpanTracer", sp: Span):
         self.tracer = tracer
         self.sp = sp
+        self.annotation = TraceAnnotation(ANNOTATION_PREFIX + sp.name)
 
     def __enter__(self) -> Span:
+        self.annotation.__enter__()
         return self.sp
 
     def __exit__(self, et, ev, tb) -> bool:
+        self.annotation.__exit__(et, ev, tb)
         self.sp.end_s = now()
         if et is not None:
             self.sp.attrs["error"] = et.__name__
@@ -104,9 +121,10 @@ _NULL_CTX = _NullCtx()
 
 
 class SpanTracer:
-    """Per-query span tree.  Not thread-safe: the engine serializes one
-    statement at a time (the coordinator's engine lock), matching the
-    reference's per-query trace context."""
+    """Per-query span tree.  Not thread-safe: one open-span stack, owned
+    by the thread that created the tracer (the statement's own, matching
+    the reference's per-query trace context).  The launch and host-pull
+    doors compare `thread_id` and record nothing from another thread."""
 
     enabled = True
 
@@ -116,6 +134,11 @@ class SpanTracer:
         self._stack: list[Span] = []
         self._ids = itertools.count(1)
         self.t0 = now()
+        self.thread_id = get_ident()
+        #: the newest `launch` span the launch door recorded, so that
+        #: StageExecutor._call can add its phase booking and compile
+        #: children to it instead of recording the launch a second time
+        self.last_launch: Optional[Span] = None
 
     # -- recording ------------------------------------------------------------
 
@@ -198,6 +221,10 @@ class SpanTracer:
 
         if self.root is not None:
             yield from rec(self.root)
+
+    def count(self) -> int:
+        """Spans in the tree (QueryStatistics.spans)."""
+        return sum(1 for _ in self._walk())
 
     def flat_spans(self) -> list:
         """Depth-first flattened spans as plain dicts (the
