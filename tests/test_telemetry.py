@@ -1105,6 +1105,9 @@ def test_vocabulary_parses():
     )
     assert {"execute", "build", "result", "launch", "host_pull",
             "fragment-N"} <= _vocabulary("span names")
+    from trino_tpu.telemetry.metrics import AGGREGATION_PATHS
+
+    assert _vocabulary("launch paths") == set(AGGREGATION_PATHS)
     assert _step_in_vocabulary("chain_scan_pred_dyn_filter", steps)
     assert _step_in_vocabulary("fused_exchange_agg_final_x", steps)
     assert not _step_in_vocabulary("chain_local", steps)

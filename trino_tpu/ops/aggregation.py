@@ -81,11 +81,11 @@ def _group_ranks(varg, gid_c, cap: int, nseg: int):
     rank of each kept row (varg) within its group in sorted order, and the
     per-group kept-row counts.  Shared by _collect_one and _minmax_by_n."""
     rank_incl = jnp.cumsum(varg.astype(jnp.int64))
-    base = jax.ops.segment_min(
-        jnp.where(varg, rank_incl - 1, cap + 1), gid_c, nseg
+    base = segment_reduce(
+        jnp.where(varg, rank_incl - 1, cap + 1), gid_c, nseg, "min"
     )
     pos_in_group = rank_incl - 1 - jnp.take(base, gid_c, mode="clip")
-    counts = jax.ops.segment_sum(varg.astype(jnp.int64), gid_c, nseg)
+    counts = segment_reduce(None, gid_c, nseg, "count", valid=varg)
     return pos_in_group, counts
 
 
@@ -171,8 +171,8 @@ def _hll_registers(col: Column, valid) -> jnp.ndarray:
     bitlen = bitlen + (x > 0).astype(jnp.int32)
     rank = 64 - bitlen + 1
     bucket = jnp.where(valid, bucket, HLL_M)
-    return jax.ops.segment_max(
-        jnp.where(valid, rank, 0), bucket, HLL_M + 1
+    return segment_reduce(
+        jnp.where(valid, rank, 0), bucket, HLL_M + 1, "max"
     )[:HLL_M].astype(jnp.int32)
 
 
@@ -299,7 +299,7 @@ def _reduce128(d, gid, nseg: int, kind: str, valid):
     if kind == "any":
         n = d.shape[0]
         idx = jnp.where(valid, jnp.arange(n, dtype=jnp.int64), n)
-        first = jax.ops.segment_min(idx, gid, nseg)
+        first = segment_reduce(idx, gid, nseg, "min")
         return jnp.take(d, jnp.clip(first, 0, n - 1), axis=0, mode="clip")
     raise NotImplementedError(f"long decimal {kind}")
 
@@ -339,7 +339,8 @@ def _note_fastpath(path: str) -> None:
 
 def _note_agg_path(path: str) -> None:
     """Record the grouped-aggregation kernel choice (pallas | onehot |
-    segmented | positional | sort).  Called where a step chooses, which
+    segmented | positional | sort; `segment_reduce` adds dense | scatter
+    for the segment ops under it).  Called where a step chooses, which
     under jit is while it TRACES: the launch door remembers the choice on
     the program and replays it on every launch (`path=` on the `launch`
     span; `trino_tpu_aggregation_path_total` counts executions)."""
@@ -358,14 +359,19 @@ def _sum128(
         sum_certificate): every partial sum of every subset of contributing
         rows is statically bounded by |s| <= sum_bound < 2**63, from
         per-column generator stats / literal bounds x a sound total-row
-        bound.  ONE i64 segment_sum is provably exact: values individually
+        bound.  ONE i64 segment sum is provably exact: values individually
         fit i64 (|v| <= sum_bound), so the high limb is pure sign
         extension and never needs summing.
       * declared-precision proof — 10**in_precision * rows < 2**63 (static
         per trace): the type's range contract alone bounds the batch.
       * otherwise a fused runtime fits probe picks narrow/wide per batch
         under lax.cond (exact either way, but the probe and the compiled
-        wide branch are the cost the certificates exist to delete)."""
+        wide branch are the cost the certificates exist to delete).
+
+    Every segment sum here and in types/int128 goes through
+    `segment_reduce`: a dense masked reduction at few segments (`nseg` 1
+    from `_global_reduce`, the direct path's `prod + 1`), a scatter-add
+    only above DENSE_SEGMENT_LIMIT.  The integers are the same."""
     from trino_tpu.types import int128 as i128
 
     rows = d.shape[0]
@@ -391,7 +397,8 @@ def _sum128(
             # column never pays the limb-plane cost).
             _note_fastpath("proven")
             return jnp.stack(
-                i128.widen64(jax.ops.segment_sum(l, gid, nseg)), axis=-1
+                i128.widen64(segment_reduce(l, gid, nseg, "sum")),
+                axis=-1,
             )
         # Runtime-adaptive narrow path (the common TPC-H shape: a product
         # typed decimal(25+) whose actual values are ~10 digits).  One cheap
@@ -415,7 +422,7 @@ def _sum128(
         )
 
         def _fast(_):
-            return i128.widen64(jax.ops.segment_sum(l, gid, nseg))
+            return i128.widen64(segment_reduce(l, gid, nseg, "sum"))
 
         def _wide(_):
             return i128.segment_sum128(
@@ -432,14 +439,14 @@ def _sum128(
             and (10**in_precision) * rows < (1 << 63)
         ):
             _note_fastpath("proven")
-            red = jax.ops.segment_sum(d, gid, nseg)
+            red = segment_reduce(d, gid, nseg, "sum")
             h, l = i128.widen64(red)
         else:
             _note_fastpath("runtime_check")
             fits = jnp.logical_and(jnp.max(d) < thr, jnp.min(d) > -thr)
 
             def _fast(_):
-                return i128.widen64(jax.ops.segment_sum(d, gid, nseg))
+                return i128.widen64(segment_reduce(d, gid, nseg, "sum"))
 
             def _wide(_):
                 return i128.sum128_widened(d, gid, nseg, valid=None)
@@ -790,7 +797,7 @@ class AggregationOperator:
             gid = gid * size + jnp.clip(code, 0, size - 1)
         gid = jnp.where(live, gid, prod)
         nseg = prod + 1
-        occupancy = jax.ops.segment_sum(live.astype(jnp.int64), gid, nseg)[:prod]
+        occupancy = segment_reduce(None, gid, nseg, "count", valid=live)[:prod]
         out_live = occupancy > 0
         # decode positional slot -> group key codes
         idx = jnp.arange(prod, dtype=jnp.int64)
@@ -840,7 +847,9 @@ class AggregationOperator:
     #: sums must stay inside i64: 2**32 chunks * 2**30 rows = 2**62).  The
     #: row bound was 2**21 while the sums rode f64 (2**53 mantissa); a
     #: mesh worker's stacked scan batch (SF1 lineitem on one chip: 2**23
-    #: rows) then fell to the segmented scatter-adds
+    #: rows) then fell to the per-aggregate "segmented" reductions, which
+    #: were scatter-adds then and are dense too since `segment_reduce`
+    #: chooses (they still pay `_sum128`'s probe per aggregate)
     ONEHOT_GROUP_LIMIT = 32
     ONEHOT_ROW_LIMIT = 1 << 30
 
@@ -848,8 +857,10 @@ class AggregationOperator:
         """EXACT one-hot aggregation (default on the direct path off-CPU):
         every sum/count is a masked reduction of a [cap] plane against the
         [cap, G] one-hot of the group ids, fused by XLA into one pass over
-        the rows — instead of K segmented scatter-adds, which the TPU has
-        no hardware for (Q1 SF1 warm on a v5e: 4.49 s segmented).
+        the rows — instead of K scatter-adds, which the TPU has no hardware
+        for and serialises at 74-127 ns a row (Q1 SF1 warm on a v5e: 4.49 s
+        that way).  The occupancy count in front of it (`_direct_reduce`)
+        is the same kind of reduction, through `segment_reduce`.
 
         It is exact: integer inputs split into 32-bit chunk planes summed
         in int64, and the chunks recombine into i64/i128 with carries; only
@@ -868,8 +879,9 @@ class AggregationOperator:
             return None
         if not self.aggregates:
             return None  # pure dedupe (e.g. DISTINCT pre-aggregation)
-        # the one-hot reduction is the accelerator formulation; CPU's scalar
-        # pipelines prefer the segmented scatter-adds
+        # the shared one-hot over chunk planes is the accelerator
+        # formulation; CPU's scalar pipelines prefer one reduction per
+        # aggregate (the "segmented" path)
         if jax.default_backend() == "cpu" and not getattr(
             self, "force_onehot", False
         ):
@@ -1167,7 +1179,7 @@ class AggregationOperator:
             gid = gid * sizes[i] + code
         gid = jnp.where(live, gid, out_cap)
         nseg = out_cap + 1
-        occupancy = jax.ops.segment_sum(live.astype(jnp.int64), gid, nseg)[:out_cap]
+        occupancy = segment_reduce(None, gid, nseg, "count", valid=live)[:out_cap]
         out_live = occupancy > 0
         # decode slot index -> group key values (traced div/mod chain)
         idx = jnp.arange(out_cap, dtype=jnp.int64)
@@ -1533,8 +1545,9 @@ class AggregationOperator:
             # min it must only win when every key is NaN — remap to +inf
             # instead of letting segment_min propagate it
             keyed = jnp.where(jnp.isnan(keyed), jnp.inf, keyed)
-        red = jax.ops.segment_min if want_min else jax.ops.segment_max
-        kext = red(keyed, gid_c, nseg)
+        kext = segment_reduce(
+            keyed, gid_c, nseg, "min" if want_min else "max"
+        )
         pos = jnp.arange(cap, dtype=jnp.int64)
         kext_g = jnp.take(kext, gid_c, mode="clip")
         match = keyed == kext_g
@@ -1545,11 +1558,15 @@ class AggregationOperator:
                 match, jnp.logical_and(jnp.isnan(keyed), jnp.isnan(kext_g))
             )
         at_ext = jnp.logical_and(vkey, match)
-        first = jax.ops.segment_min(jnp.where(at_ext, pos, cap), gid_c, nseg)
+        first = segment_reduce(
+            jnp.where(at_ext, pos, cap), gid_c, nseg, "min"
+        )
         idx = jnp.clip(first[:out_cap], 0, cap - 1)
         vd = jnp.take(vcol.data, perm, axis=0, mode="clip")
         out = jnp.take(vd, idx, axis=0, mode="clip")
-        has_key = jax.ops.segment_sum(vkey.astype(jnp.int64), gid_c, nseg)[:out_cap] > 0
+        has_key = (
+            segment_reduce(None, gid_c, nseg, "count", valid=vkey)[:out_cap] > 0
+        )
         valid = has_key
         if vcol.valid is not None:
             vvalid = jnp.take(
@@ -1578,8 +1595,8 @@ class AggregationOperator:
         nseg = out_cap + 1
         # nulls sort last within the group: the group's first live row starts
         # the non-null run, whose length is the valid count
-        start = jax.ops.segment_min(jnp.where(varg, pos, cap), gid_c, nseg)
-        nvalid = jax.ops.segment_sum(varg.astype(jnp.int64), gid_c, nseg)
+        start = segment_reduce(jnp.where(varg, pos, cap), gid_c, nseg, "min")
+        nvalid = segment_reduce(None, gid_c, nseg, "count", valid=varg)
         p = float(spec.param if spec.param is not None else 0.5)
         target = start + jnp.round(
             p * jnp.maximum(nvalid - 1, 0).astype(jnp.float64)

@@ -10,11 +10,13 @@ iterated stable argsorts (lexicographic) + key-change flags + cumsum group ids
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 from trino_tpu.columnar import Batch, Column
+from trino_tpu.telemetry.programs import note_path
 from trino_tpu.types import DecimalType
 
 
@@ -124,37 +126,76 @@ def group_ids_from_sorted(batch: Batch, perm, key_channels):
     return gid, ngroups, new_group
 
 
+#: A segmented reduction over at most this many segments runs DENSE — one
+#: fused masked pass over the rows per segment, `reduce_n where(gid[n] == s,
+#: value[n], identity)` — and only above it as a scatter
+#: (`jax.ops.segment_*`).  The TPU has no scatter hardware: XLA serialises
+#: a scatter-add at 60-130 ns a row whatever the segment count (63-133 ms a
+#: 2^20-row batch on a v5e), while the dense pass costs rows x segments
+#: vector work and materialises nothing [rows, segments]-shaped.  Chosen by
+#: the on-chip sweep in PERF.md section 6 (PR 27, tools/segment_sweep.py):
+#: the largest swept count at which dense was at least 4x faster (12.4 ms
+#: against 69.5 ms at 2049; 20.2 against 69.5 at 4097 is 3.4x).  Segment
+#: counts are a capacity plus one dead slot, hence the odd number.
+DENSE_SEGMENT_LIMIT = (1 << 11) + 1
+
+_SEGMENT_SCATTER = {
+    "sum": jax.ops.segment_sum,
+    "min": jax.ops.segment_min,
+    "max": jax.ops.segment_max,
+}
+
+
+def _reduce_segments(values, gid, num_segments: int, op: str):
+    """[num_segments] sum/min/max of a [n] plane by segment id; rows that
+    must not count already hold the identity.  Out-of-range and negative
+    ids drop and an empty segment reads the identity, on both lowerings;
+    `num_segments` is static, so the choice is made while the step traces
+    and `note_path` carries it to the launch span (`dense` | `scatter`)."""
+    if num_segments > DENSE_SEGMENT_LIMIT:
+        note_path("scatter")
+        return _SEGMENT_SCATTER[op](values, gid, num_segments)
+    note_path("dense")
+    if op == "sum":
+        identity = jnp.zeros((), values.dtype)
+        reduce = partial(jnp.sum, dtype=values.dtype)
+    else:
+        identity = (_max_sentinel if op == "min" else _min_sentinel)(values.dtype)
+        reduce = jnp.min if op == "min" else jnp.max
+    if num_segments == 1:
+        return reduce(jnp.where(gid == 0, values, identity))[None]
+    onehot = gid[:, None] == jnp.arange(num_segments, dtype=gid.dtype)[None, :]
+    return reduce(jnp.where(onehot, values[:, None], identity), axis=0)
+
+
 def segment_reduce(values, gid, num_segments: int, kind: str, valid=None):
-    """Null-skipping segmented reduction. kind: sum/min/max/count/any."""
+    """Null-skipping segmented reduction -> [num_segments].  kind:
+    sum/min/max/count/any.  THE one place that lowers a segment op: dense
+    masked reductions up to DENSE_SEGMENT_LIMIT segments, a scatter above
+    (see `_reduce_segments`); same integers either way."""
     if kind == "count":
-        w = jnp.ones_like(gid, dtype=jnp.int64)
+        w = jnp.ones(gid.shape, jnp.int64)
         if valid is not None:
-            w = jnp.where(valid, w, 0)
-        return jax.ops.segment_sum(w, gid, num_segments)
-    if valid is not None:
-        if kind == "sum":
-            values = jnp.where(valid, values, 0)
-        elif kind == "min":
-            values = jnp.where(valid, values, _max_sentinel(values.dtype))
-        elif kind == "max":
-            values = jnp.where(valid, values, _min_sentinel(values.dtype))
-        elif kind == "any":
-            pass
-    if kind == "sum":
-        return jax.ops.segment_sum(values, gid, num_segments)
-    if kind == "min":
-        return jax.ops.segment_min(values, gid, num_segments)
-    if kind == "max":
-        return jax.ops.segment_max(values, gid, num_segments)
+            w = valid.astype(jnp.int64)
+        return _reduce_segments(w, gid, num_segments, "sum")
     if kind == "any":
         # first VALID value per segment (any_value): min row index among valid
         n = values.shape[0]
         idx = jnp.arange(n, dtype=jnp.int64)
         if valid is not None:
             idx = jnp.where(valid, idx, n)
-        first = jax.ops.segment_min(idx, gid, num_segments)
+        first = _reduce_segments(idx, gid, num_segments, "min")
         return jnp.take(values, jnp.clip(first, 0, n - 1), mode="clip")
-    raise ValueError(kind)
+    if kind not in _SEGMENT_SCATTER:
+        raise ValueError(kind)
+    if valid is not None:
+        if kind == "sum":
+            values = jnp.where(valid, values, 0)
+        elif kind == "min":
+            values = jnp.where(valid, values, _max_sentinel(values.dtype))
+        else:
+            values = jnp.where(valid, values, _min_sentinel(values.dtype))
+    return _reduce_segments(values, gid, num_segments, kind)
 
 
 def _max_sentinel(dtype):

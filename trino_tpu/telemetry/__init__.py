@@ -38,6 +38,13 @@ launch steps (`step=` of a `launch` span; the XLA module is `jit_<step>`):
     licensed_expand, fused_expand, locate, expand, semi_mark, unnest,
     exchange_counts, fused_exchange, agg_final, agg_single, broadcast
 
+launch paths (`path=` of a `launch` span, `+`-joined; each also a label of
+`trino_tpu_aggregation_path_total`):
+    pallas, onehot, segmented, positional, sort (the grouped-aggregation
+    formulation the step chose), dense, scatter (how its segment
+    reductions lowered: masked reductions over few segments, or
+    `jax.ops.segment_*` over many — `ops/common.segment_reduce`)
+
 host_pull why (`why=` of a `host_pull` span: what the host needed it for):
     result (rows for the client), capacity (a count that sizes the next
     program's static shape), overflow_flag (a speculative capacity's

@@ -414,9 +414,13 @@ DECIMAL_FASTPATHS = ("proven", "runtime_check", "limb")
 
 #: grouped-aggregation kernel path vocabulary (ops/aggregation): which
 #: formulation a traced step compiled — the Pallas MXU kernel, the exact
-#: int64 one-hot masked reduction, segmented scatter-adds over dense codes, the
-#: range-positional domain, or the sort-based numbering
-AGGREGATION_PATHS = ("pallas", "onehot", "segmented", "positional", "sort")
+#: int64 one-hot masked reduction, per-aggregate segment reductions over
+#: dense codes, the range-positional domain, or the sort-based numbering —
+#: and how its segment reductions lowered (ops/common.segment_reduce):
+#: dense masked reductions (few segments) or scatters (many)
+AGGREGATION_PATHS = (
+    "pallas", "onehot", "segmented", "positional", "sort", "dense", "scatter",
+)
 
 
 #: join capacity-sizing outcome vocabulary (verify/capacity.py +
@@ -742,8 +746,11 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
         "grouped-aggregation kernel path per EXECUTION: the choice a step "
         "made while tracing, replayed by the launch door on every launch "
         "of that program (telemetry/programs.py): pallas = Mosaic one-hot MXU kernel, onehot = "
-        "exact int64 one-hot masked reduction, segmented = scatter-adds over dense codes, "
-        "positional = range-positional domain, sort = sort-based numbering",
+        "exact int64 one-hot masked reduction, segmented = per-aggregate segment "
+        "reductions over dense codes, positional = range-positional domain, "
+        "sort = sort-based numbering; and how the program's segment reductions "
+        "lowered: dense = masked reductions (few segments), scatter = "
+        "jax.ops.segment_* (many)",
         labelnames=("path",),
     )
     for p in AGGREGATION_PATHS:

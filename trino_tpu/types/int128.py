@@ -21,8 +21,6 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-import jax
-
 # numpy scalars, NOT jnp arrays: module-level device arrays become captured
 # buffers of every jitted program that closes over them, breaking executable
 # reuse across operator instances ("supplied N buffers but expected N+1")
@@ -297,6 +295,16 @@ def rescale128(h, l, from_scale: int, to_scale: int):
     return add128(q_h, q_l, bh, bl)
 
 
+def _segment_reducer(gid, num_segments: int, kind: str):
+    """plane -> its [num_segments] `kind` by `gid`, through the engine's one
+    segment lowering (ops/common.segment_reduce: dense up to
+    DENSE_SEGMENT_LIMIT segments, a scatter above).  Imported here because
+    ops/common imports this package."""
+    from trino_tpu.ops.common import segment_reduce
+
+    return lambda plane: segment_reduce(plane, gid, num_segments, kind)
+
+
 def segment_sum128(h, l, gid, num_segments: int, valid=None, hi_direct=False):
     """Exact segmented i128 sum via 32-bit plane sums (each plane sum fits
     i64 for < 2**31 rows), recombined with carries.
@@ -304,23 +312,23 @@ def segment_sum128(h, l, gid, num_segments: int, valid=None, hi_direct=False):
     hi_direct: the caller proves |hi| * rows < 2**62 (e.g. from the decimal
     precision bound), so the high limb sums in ONE pass without chunking —
     three segment sums instead of four, and half the mask/shift traffic."""
+    seg_sum = _segment_reducer(gid, num_segments, "sum")
     if valid is not None:
         h = jnp.where(valid, h, 0)
         l = jnp.where(valid, l, 0)
     l0 = l & _MASK32
     l1 = (l >> 32) & _MASK32
-    s_l0 = jax.ops.segment_sum(l0, gid, num_segments)
-    s_l1 = jax.ops.segment_sum(l1, gid, num_segments)
+    s_l0 = seg_sum(l0)
+    s_l1 = seg_sum(l1)
     c1 = (s_l0 >> 32) + s_l1  # nonneg
     lo = (s_l0 & _MASK32) | ((c1 & _MASK32) << 32)
     carry = c1 >> 32  # nonneg
     if hi_direct:
-        s_h = jax.ops.segment_sum(h, gid, num_segments)
-        return s_h + carry, lo
+        return seg_sum(h) + carry, lo
     h0 = h & _MASK32
     h1 = h >> 32  # signed top chunk
-    s_h0 = jax.ops.segment_sum(h0, gid, num_segments)
-    s_h1 = jax.ops.segment_sum(h1, gid, num_segments)
+    s_h0 = seg_sum(h0)
+    s_h1 = seg_sum(h1)
     c2 = carry + s_h0  # nonneg
     hi = ((s_h1 + (c2 >> 32)) << jnp.int64(32)) | (c2 & _MASK32)
     return hi, lo
@@ -337,9 +345,8 @@ def sum128_widened(d, gid, num_segments: int, valid=None):
         d = jnp.where(valid, d, 0)
     d0 = d & _MASK32  # in [0, 2**32)
     d1 = d >> 32  # signed top chunk in [-2**31, 2**31)
-    s0 = jax.ops.segment_sum(d0, gid, num_segments)
-    s1 = jax.ops.segment_sum(d1, gid, num_segments)
-    return recombine2(s0, s1)
+    seg_sum = _segment_reducer(gid, num_segments, "sum")
+    return recombine2(seg_sum(d0), seg_sum(d1))
 
 
 def segment_minmax128(h, l, gid, num_segments: int, valid, is_max: bool):
@@ -348,18 +355,11 @@ def segment_minmax128(h, l, gid, num_segments: int, valid, is_max: bool):
     big = jnp.int64(np.iinfo(np.int64).max)
     small = jnp.int64(np.iinfo(np.int64).min)
     lu = l ^ _SIGN  # low limb in signed-comparable (unsigned) order
-    if is_max:
-        h_m = jnp.where(valid, h, small)
-        win_h = jax.ops.segment_max(h_m, gid, num_segments)
-        on_win = jnp.logical_and(valid, h == jnp.take(win_h, gid, mode="clip"))
-        l_m = jnp.where(on_win, lu, small)
-        win_l = jax.ops.segment_max(l_m, gid, num_segments)
-    else:
-        h_m = jnp.where(valid, h, big)
-        win_h = jax.ops.segment_min(h_m, gid, num_segments)
-        on_win = jnp.logical_and(valid, h == jnp.take(win_h, gid, mode="clip"))
-        l_m = jnp.where(on_win, lu, big)
-        win_l = jax.ops.segment_min(l_m, gid, num_segments)
+    pick = _segment_reducer(gid, num_segments, "max" if is_max else "min")
+    lose = small if is_max else big
+    win_h = pick(jnp.where(valid, h, lose))
+    on_win = jnp.logical_and(valid, h == jnp.take(win_h, gid, mode="clip"))
+    win_l = pick(jnp.where(on_win, lu, lose))
     return win_h, win_l ^ _SIGN
 
 
