@@ -1104,13 +1104,36 @@ def test_vocabulary_parses():
         "host_pull why"
     )
     assert {"execute", "build", "result", "launch", "host_pull",
-            "fragment-N"} <= _vocabulary("span names")
+            "fragment-N", "join"} <= _vocabulary("span names")
     from trino_tpu.telemetry.metrics import AGGREGATION_PATHS
 
     assert _vocabulary("launch paths") == set(AGGREGATION_PATHS)
     assert _step_in_vocabulary("chain_scan_pred_dyn_filter", steps)
     assert _step_in_vocabulary("fused_exchange_agg_final_x", steps)
     assert not _step_in_vocabulary("chain_local", steps)
+
+
+def test_join_span_vocabulary_and_counter(runner):
+    """The `join` span's attributes and values are listed once, and the
+    engine keeps to them; its NULL-key count is a registered counter."""
+    import trino_tpu.telemetry as telemetry
+    from trino_tpu.telemetry import REGISTRY
+
+    section = telemetry.__doc__.split("join span", 1)[1].split("\n\n", 1)[0]
+    listed = set(re.findall(r"[a-z][a-z_]+", section))
+    _, flat = _run_with_context(runner, _tpch(3))
+    joins = [s for s in flat if s["name"] == "join"]
+    assert len(joins) == 2
+    steps = _vocabulary("launch steps")
+    for j in joins:
+        attrs = _attrs(j)
+        assert set(attrs) == {"kind", "strategy", "build_rows", "probe_rows",
+                              "out_rows", "null_keys"} <= listed
+        assert attrs["kind"] in listed and attrs["strategy"] in listed
+        assert attrs["strategy"] in steps
+        assert attrs["null_keys"] == 0  # TPC-H has no NULL
+    assert "trino_tpu_join_null_keys_total" in section
+    assert "trino_tpu_join_null_keys_total" in REGISTRY.render_prometheus()
 
 
 def test_local_execute_has_build_result_launch_and_pull(runner):
@@ -1177,7 +1200,19 @@ def test_mesh_launches_carry_step_and_are_counted_once(dist):
     for l in launches:
         assert _step_in_vocabulary(_attrs(l)["step"], steps), _attrs(l)
     for l in booked:
-        assert by_id[l["parent_id"]]["name"].startswith("fragment-")
+        parent = by_id[l["parent_id"]]
+        if parent["name"] == "join":  # a join's launches nest under its span
+            parent = by_id[parent["parent_id"]]
+        assert parent["name"].startswith("fragment-")
+    # one `join` span per join operator, inside its fragment
+    joins = [s for s in flat if s["name"] == "join"]
+    assert len(joins) == 2
+    for j in joins:
+        assert by_id[j["parent_id"]]["name"].startswith("fragment-")
+        assert _attrs(j)["kind"] == "inner"
+        assert _attrs(j)["strategy"] in (
+            "broadcast", "partitioned", "colocated"
+        )
     # one span per launch: _call adds to the door's span, it records none
     assert ctx.launches == len(launches)
     assert ctx.host_pulls == len([s for s in flat if s["name"] == "host_pull"])

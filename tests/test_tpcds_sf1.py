@@ -18,8 +18,10 @@ from trino_tpu.connectors.tpcds.queries import QUERIES
 pytestmark = pytest.mark.heavy
 
 #: structurally diverse slice: star joins (3, 7, 19), date-dim correlated
-#: subquery (25), grouping breadth (42, 52), inventory semi-join shape (82)
-SPOT = [3, 7, 19, 25, 42, 52, 82]
+#: subquery (25), grouping breadth (42, 52), inventory semi-join shape (82),
+#: ROLLUP (27) and a window over the grouped rows (89): with 3 and 7 the
+#: four statements of the benchmark's `tpcds_sf1.star_report`
+SPOT = [3, 7, 19, 25, 27, 42, 52, 82, 89]
 
 
 @pytest.fixture(scope="module")
@@ -36,11 +38,27 @@ def mesh():
     return DistributedQueryRunner(catalog="tpcds", schema="sf1")
 
 
+def _sql(qid: int) -> str:
+    """The suite's text, but for 27 and 89 the benchmark's template with a
+    seed's parameters (`tpcds_sf1.star_report`): as written they name a
+    state and classes this generator has not, and answer from no rows."""
+    if qid not in (27, 89):
+        return QUERIES[qid]
+    from benchmark.harness import spec, traffic
+
+    mix = traffic.Mix(spec.Cell("tpcds_sf1.star_report").traffic, 1)
+    return {st.query: st.sql for st in mix.warmup()}[f"q{qid}"]
+
+
 @pytest.mark.parametrize("qid", SPOT)
 def test_sf1_local_vs_mesh(local, mesh, qid):
-    sql = QUERIES[qid]
+    sql = _sql(qid)
     a = local.execute(sql)
     b = mesh.execute(sql)
     assert a.column_names == b.column_names
-    assert sorted(map(tuple, a.rows)) == sorted(map(tuple, b.rows))
+    # ROLLUP's absent keys are NULL (q27): order rows with None last
+    key = lambda row: tuple((v is None, v) for v in row)
+    assert sorted(map(tuple, a.rows), key=key) == sorted(
+        map(tuple, b.rows), key=key
+    )
     assert a.row_count > 0, f"q{qid} degenerate empty result at sf1"

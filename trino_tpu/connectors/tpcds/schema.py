@@ -8,6 +8,7 @@ decimal(7,2); business ids are fixed-width strings.
 
 from __future__ import annotations
 
+import functools
 import math
 
 from trino_tpu import types as T
@@ -346,5 +347,6 @@ def schema_scale(schema: str) -> float:
     raise KeyError(f"unknown tpcds schema: {schema}")
 
 
-def column_types(table: str):
-    return [(name, T.parse_type(t)) for name, t in TABLES[table]]
+@functools.lru_cache(maxsize=None)
+def column_types(table: str) -> tuple:
+    return tuple((name, T.parse_type(t)) for name, t in TABLES[table])

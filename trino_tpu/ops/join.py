@@ -33,7 +33,9 @@ from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
 from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.ops.common import next_pow2
+from trino_tpu.telemetry.metrics import join_null_keys_counter
 from trino_tpu.telemetry.programs import jit_program
+from trino_tpu.telemetry.spans import NULL_TRACER, now
 
 
 def _dense_build(batches: list[Batch], types: Sequence[T.Type]) -> tuple[Batch, int]:
@@ -170,7 +172,9 @@ def _prepare_sorted_build(build: Batch, key_channels: Sequence[int]):
                 composite = composite * w + (d.astype(jnp.int64) - mn)
             composite = jnp.where(nomatch, total, composite)
             perm = jnp.argsort(composite, stable=True)
-            if total <= TABLE_DOMAIN_LIMIT and total <= 64 * max(n_match, 1):
+            if total <= TABLE_DOMAIN_LIMIT and total <= max(
+                64 * n_match, TABLE_SPARSE_LIMIT
+            ):
                 # direct-addressed probe tables over the packed key domain:
                 # start/count per composite code, O(1) gather per probe row
                 # (the PagesHash open-addressing analog, but positional)
@@ -221,6 +225,12 @@ def _build_recode_table(probe_dict, build_dict) -> Optional[jnp.ndarray]:
 
 #: packed-domain cap for direct-addressed probe tables (2 i32 arrays)
 TABLE_DOMAIN_LIMIT = 1 << 25
+#: a domain this small gets its tables (16 MB) however few of its codes the
+#: build holds; above it only a build that fills 1/64 of the domain does.
+#: The sorted locate costs 2 x log2(build) dependent gathers a probe row
+#: where the tables cost two: a 27 440-row build over customer_demographics'
+#: 1.92 M keys took 2.9 us a probe row sorted (v5e, PERF.md section 6, PR 30)
+TABLE_SPARSE_LIMIT = 1 << 21
 
 
 def _locate_table(probe_canon, probe_nomatch, mins, widths, start_t, count_t):
@@ -280,6 +290,29 @@ def _locate_sorted(build_canon, n_match, probe_canon, probe_nomatch, cap_b: int)
     return start, count
 
 
+def _null_keys(probe_live, probe_nomatch):
+    """Live probe rows whose key can match nothing because it is NULL (or
+    NaN): one more output of the local operators' locate programs, so the
+    count rides the launch and the pull a join makes anyway."""
+    return jnp.sum(jnp.logical_and(probe_live, probe_nomatch), dtype=jnp.int64)
+
+
+def _locate_table_step(probe_live, probe_canon, probe_nomatch, *table):
+    return (
+        *_locate_table(probe_canon, probe_nomatch, *table),
+        _null_keys(probe_live, probe_nomatch),
+    )
+
+
+def _locate_sorted_step(
+    probe_live, build_canon, n_match, probe_canon, probe_nomatch, cap_b: int
+):
+    return (
+        *_locate_sorted(build_canon, n_match, probe_canon, probe_nomatch, cap_b),
+        _null_keys(probe_live, probe_nomatch),
+    )
+
+
 #: process-level jitted-step cache (cross-query reuse; see filter_project).
 #: CONTRACT: a cached step must read NO per-query state off `self` — only
 #: configuration captured in its cache key; per-query data (the build batch,
@@ -293,6 +326,81 @@ def _jit_cached(key, factory):
     if key not in _STEP_CACHE:
         _STEP_CACHE[key] = factory()
     return _STEP_CACHE[key]
+
+
+class JoinSpan:
+    """The `join` span of one join operator in one statement: from the
+    operator's first probe batch to its last output, with `kind`,
+    `strategy` (the locate step the build chose; `partition_waves` for a
+    build over the memory budget), `build_rows` and, where the operator
+    reads them anyway, `probe_rows`, `out_rows` (matches emitted, before a
+    residual filter) and `null_keys` (probe rows dropped for a NULL key).
+    Made while planning, on the statement's thread; the stream may then
+    run on a prefetch thread, where the span only moves its own ends (the
+    doors record nothing off the statement's thread, so no `launch` or
+    `host_pull` nests there)."""
+
+    __slots__ = ("tracer", "sp")
+
+    def __init__(self, kind: str, op=None, strategy: str = ""):
+        from trino_tpu.runtime.lifecycle import current_query
+        from trino_tpu.telemetry.programs import recording_tracer
+
+        ctx = current_query()
+        self.tracer = (
+            ctx is not None and recording_tracer(ctx) or NULL_TRACER
+        )
+        #: None when tracing is off: `wrap` then only counts NULL keys
+        self.sp = self.tracer.held("join", under="execute", kind=kind)
+        if self.sp is not None:
+            if op is not None:
+                self.sp.attrs["strategy"] = op.strategy
+                self.sp.attrs["build_rows"] = op.build_rows
+            else:
+                self.sp.attrs["strategy"] = strategy
+
+    def wrap(self, process, probe_stream, op=None):
+        """`process(probe_stream)` under the span: the operator's own work
+        on a batch is inside it (its launches and pulls nest there, on the
+        statement's thread), the probe side's stays outside.  `op`: the
+        operator whose counts the span takes when the stream is done."""
+        tracer, sp = self.tracer, self.sp
+        done = object()
+        started = False
+
+        def tap():
+            nonlocal started
+            it = iter(probe_stream)
+            while True:
+                with tracer.left(sp):
+                    b = next(it, done)
+                if b is done:
+                    return
+                if sp is not None and not started:
+                    started = True
+                    sp.start_s = sp.end_s = now()
+                yield b
+
+        out = process(tap())
+        try:
+            while True:
+                with tracer.entered(sp):
+                    b = next(out, done)
+                if b is done:
+                    return
+                if sp is not None:
+                    sp.end_s = now()
+                yield b
+        finally:
+            out.close()  # an abandoned stream (LIMIT) releases its build now
+            nulls = getattr(op, "null_keys", 0)
+            if nulls:
+                join_null_keys_counter().inc(nulls)
+            if sp is not None and hasattr(op, "out_rows"):
+                sp.attrs.update(
+                    probe_rows=op.probe_rows, out_rows=op.out_rows,
+                    null_keys=op.null_keys,
+                )
 
 
 class _SortedBuildJoinBase:
@@ -310,13 +418,27 @@ class _SortedBuildJoinBase:
         self._locate = _jit_cached(
             ("locate", len(self.build_keys)),
             lambda: jit_program(
-                _locate_sorted, "join_locate_sorted", static_argnames=("cap_b",)
+                _locate_sorted_step, "join_locate_sorted",
+                static_argnames=("cap_b",),
             ),
         )
         self._locate_t = _jit_cached(
             ("locate_table", len(self.build_keys)),
-            lambda: jit_program(_locate_table, "join_locate_table"),
+            lambda: jit_program(_locate_table_step, "join_locate_table"),
         )
+
+    @property
+    def strategy(self) -> str:
+        """The locate step this build chose (a launch step's name)."""
+        return (
+            "join_locate_table" if self._table is not None
+            else "join_locate_sorted"
+        )
+
+    @property
+    def build_rows(self) -> int:
+        """Build rows a probe key can match (live, key not NULL)."""
+        return self._n_match
 
     def release_build(self) -> None:
         """Drop every device reference to the indexed build side (the
@@ -382,12 +504,17 @@ class _SortedBuildJoinBase:
         return arrs, nomatch
 
     def _locate_batch(self, probe: Batch):
+        """(start, count) of each probe row's matching run, and the live
+        probe rows whose key is NULL: SQL `=` matches none of them."""
         pc, pn = self._probe_canonical(probe)
         if self._table is not None:
             mins, widths, start_t, count_t = self._table
-            return self._locate_t(pc, pn, mins, widths, start_t, count_t)
+            return self._locate_t(
+                probe.mask(), pc, pn, mins, widths, start_t, count_t
+            )
         return self._locate(
-            self._build_canon, self._n_match, pc, pn, cap_b=self.build.capacity
+            probe.mask(), self._build_canon, self._n_match, pc, pn,
+            cap_b=self.build.capacity,
         )
 
 
@@ -424,6 +551,9 @@ class HashJoinOperator(_SortedBuildJoinBase):
         self.residual = residual
         self._build_rows = 0
         self._build_matched = None  # bool[cap_b], for full outer
+        #: what `_join_batch` reads to the host anyway, summed over the
+        #: probe: the `join` span's attributes
+        self.probe_rows = self.out_rows = self.null_keys = 0
         cache_key = None
         if residual is None or residual_key is not None:
             cache_key = (
@@ -573,12 +703,15 @@ class HashJoinOperator(_SortedBuildJoinBase):
 
     def _join_batch(self, probe: Batch) -> Batch:
         cap_b = self.build.capacity
-        start, count = self._locate_batch(probe)
-        maxc, total_inner, probe_live = (
+        start, count, null = self._locate_batch(probe)
+        maxc, total_inner, probe_live, null = (
             int(x) for x in host_pull(
-                (jnp.max(count), jnp.sum(count), probe.count()), "capacity"
+                (jnp.max(count), jnp.sum(count), probe.count(), null),
+                "capacity",
             )
         )
+        self.probe_rows += probe_live
+        self.null_keys += null
         if maxc <= 1:
             out, new_matched = self._expand_unique(
                 probe, self.build, start, count, self._build_matched, cap_b=cap_b
@@ -586,6 +719,7 @@ class HashJoinOperator(_SortedBuildJoinBase):
             if new_matched is not None:
                 self._build_matched = new_matched
             n_out = total_inner if self.kind == "inner" else probe_live
+            self.out_rows += n_out
             cc = next_pow2(max(n_out, 1), floor=1024)
             if cc * 2 <= out.capacity:
                 # selective join: hand downstream a dense batch, not a
@@ -599,6 +733,7 @@ class HashJoinOperator(_SortedBuildJoinBase):
                 jnp.sum(jnp.where(probe.mask(), jnp.maximum(count, 1), 0)),
                 "capacity",
             ))
+        self.out_rows += total
         out_cap = next_pow2(max(total, 1), floor=1024)
         out, new_matched = self._expand(
             probe, self.build, start, count, self._build_matched,
@@ -647,6 +782,12 @@ class NestedLoopJoinOperator:
                 static_argnames=("out_cap", "nb"),
             ),
         )
+
+    strategy = "join_nested_expand"
+
+    @property
+    def build_rows(self) -> int:
+        return self._nb
 
     def set_build(self, batches: list[Batch]) -> None:
         self.build, self._nb = _dense_build(batches, self.build_types)
@@ -817,7 +958,7 @@ class SemiJoinOperator(_SortedBuildJoinBase):
         assert self.build is not None
         cap_b = self.build.capacity
         for probe in stream:
-            start, count = self._locate_batch(probe)
+            start, count, _ = self._locate_batch(probe)
             if self.residual is None:
                 yield self._mark(probe, count, has_null=self._filter_has_null)
             else:

@@ -101,7 +101,12 @@ from trino_tpu.planner.fragmenter import (
     fragment_text,
 )
 from trino_tpu.runtime.lifecycle import check_current
-from trino_tpu.runtime.local_planner import LocalExecutionPlanner, PhysicalPlan
+from trino_tpu.runtime.local_planner import (
+    LocalExecutionPlanner,
+    PhysicalPlan,
+    defer_integer_averages,
+    divide_deferred,
+)
 from trino_tpu.runtime.memory import batch_bytes
 from trino_tpu.runtime.query_stats import MeshProfile
 from trino_tpu.telemetry import now
@@ -345,8 +350,11 @@ class DistributedQueryRunner(LocalQueryRunner):
             raise RuntimeError(f"workers failed heartbeat: {sorted(dead)}")
         tr = self._tracer
         plan = self.plan_query(query)
+        # as on the local runner: an avg(integer) that only moves to the
+        # client is planned as sum and count, and the host divides
+        split, counts = defer_integer_averages(plan)
         with tr.span("fragment"):
-            sub = self.create_subplan(plan)
+            sub = self.create_subplan(split)
         # EXPLAIN ANALYZE runs the SAME distributed path, with the profile
         # in blocking mode so per-phase times measure device work
         profile = MeshProfile(blocking=stats is not None, tracer=tr)
@@ -374,6 +382,7 @@ class DistributedQueryRunner(LocalQueryRunner):
                 for batch in host.stream:
                     check_current()  # cancel/deadline between batches
                     rows.extend(tuple(r) for r in batch.to_pylist())
+            rows = divide_deferred(rows, counts)
         if stats is not None:
             stats.mesh_profile = profile
         return MaterializedResult(
@@ -1706,126 +1715,131 @@ class StageExecutor:
             def residual(batch: Batch, _e=expr):
                 return ExprCompiler(batch).filter_mask(_e)
 
-        did = node.decision_id
-        if node.distribution == "broadcast":
-            # partitioned-build economy for the broadcast that remains:
-            # all_gather replicates the build's FULL static capacity W
-            # times, dead padding included (the measured Q3 wall: a ~20%
-            # live filtered build shipped 27 MB).  Compact to the live
-            # bucket first — the build boundary already pays a host sync
-            # for the dynamic-filter summary, so the [W] live read adds
-            # no new dispatch stall, and the collective moves only live
-            # rows.  Compaction is stable, so build-row order (and with
-            # it the sorted-probe tie-break order) is unchanged.
-            bs = build.stacked
-            with decision_scope(did):
-                if _trailing_cap(bs) > 64:
-                    bs = self._compact_live(bs, "broadcast_compact")
-                build_stacked = self._call(
-                    ex.broadcast, bs, self.wm, phase="collective"
-                )
-                self.profile.add_collective(
-                    self._current_fid, batch_bytes(build_stacked),
-                    "all_gather", "broadcast",
-                )
-        else:
-            with decision_scope(did):
-                build = self._place_join_side(
-                    build_node, build, [r for _, r in node.criteria]
-                )
-                probe = self._place_join_side(
-                    probe_node, probe, [l for l, _ in node.criteria]
-                )
-            build_stacked = build.stacked
+        # one `join` span per join operator (the local runner's name): from
+        # both sides ready to the joined output; the sides' own work is outside
+        with self.profile.tracer.span(
+            "join", kind=node.kind, strategy=node.distribution
+        ):
+            did = node.decision_id
+            if node.distribution == "broadcast":
+                # partitioned-build economy for the broadcast that remains:
+                # all_gather replicates the build's FULL static capacity W
+                # times, dead padding included (the measured Q3 wall: a ~20%
+                # live filtered build shipped 27 MB).  Compact to the live
+                # bucket first — the build boundary already pays a host sync
+                # for the dynamic-filter summary, so the [W] live read adds
+                # no new dispatch stall, and the collective moves only live
+                # rows.  Compaction is stable, so build-row order (and with
+                # it the sorted-probe tie-break order) is unchanged.
+                bs = build.stacked
+                with decision_scope(did):
+                    if _trailing_cap(bs) > 64:
+                        bs = self._compact_live(bs, "broadcast_compact")
+                    build_stacked = self._call(
+                        ex.broadcast, bs, self.wm, phase="collective"
+                    )
+                    self.profile.add_collective(
+                        self._current_fid, batch_bytes(build_stacked),
+                        "all_gather", "broadcast",
+                    )
+            else:
+                with decision_scope(did):
+                    build = self._place_join_side(
+                        build_node, build, [r for _, r in node.criteria]
+                    )
+                    probe = self._place_join_side(
+                        probe_node, probe, [l for l, _ in node.criteria]
+                    )
+                build_stacked = build.stacked
 
-        op = HashJoinOperator(
-            node.kind, pk, bk,
-            [s.type for s in build.symbols],
-            probe_types=[s.type for s in probe.symbols],
-            residual=residual,
-        )
-        cap_b = _trailing_cap(build_stacked)
-        jkey = (
-            node.kind, tuple(pk), tuple(bk), cap_b,
-            _sig(probe.symbols), _sig(build.symbols), residual_key,
-        )
-        # capacity-history discriminator: two queries can share the same
-        # join signature (and compiled programs) while filtering the probe
-        # differently — their deferred-chain keys tell them apart so their
-        # recorded capacities don't ping-pong
-        probe_fp = tuple(k for k, _, _ in probe.pending)
-        probe_stacked = probe.stacked
-        probe_types = [s.type for s in probe.symbols]
-        if did is not None:
-            # outcome inputs for the hindsight join (telemetry/decisions):
-            # static-shape byte math only, no device sync.  build_bytes is
-            # ONE logical build copy (a broadcast's stacked batch holds W
-            # replicas); probe_move_bytes is what the rejected partitioned
-            # plan would have had to move for an unplaced probe.
-            bb = int(batch_bytes(build_stacked))
-            observe_decision(
-                did,
-                build_bytes=(
-                    bb // max(1, self.wm.n)
-                    if node.distribution == "broadcast" else bb
-                ),
-                probe_move_bytes=(
-                    0 if (node.distribution == "broadcast"
-                          and probe.placements)
-                    else int(batch_bytes(probe_stacked))
-                ),
+            op = HashJoinOperator(
+                node.kind, pk, bk,
+                [s.type for s in build.symbols],
+                probe_types=[s.type for s in probe.symbols],
+                residual=residual,
             )
-
-        # budget enforcement: reserve the build's device footprint (raw +
-        # sorted copy) BEFORE the expansion materializes; over budget the
-        # join degrades to hash-partition waves with filesystem-SPI spill
-        # instead of dying (runtime/spill, SURVEY §5.7's k-pass loop)
-        from trino_tpu.runtime import spill as _spill
-        from trino_tpu.runtime.memory import ExceededMemoryLimitException
-
-        ctx = self.memory.child("join_build")
-        need = 2 * batch_bytes(build_stacked)
-        wave_k = 0
-        try:
-            ctx.add_bytes(need)
-        except ExceededMemoryLimitException:
-            wave_k = _spill.wave_count(need, self._budget(), self.properties)
-        if wave_k:
-            wdid = record_decision(
-                "wave", "runtime.join_build", "waves", "direct",
-                {"waves": int(wave_k), "need_bytes": int(need),
-                 "budget_bytes": int(self._budget() or 0)},
+            cap_b = _trailing_cap(build_stacked)
+            jkey = (
+                node.kind, tuple(pk), tuple(bk), cap_b,
+                _sig(probe.symbols), _sig(build.symbols), residual_key,
             )
-            with decision_scope(wdid):
-                out = self._wave_join(
-                    node, op, probe_stacked, build_stacked, pk, bk, jkey,
-                    probe_types, wave_k, ctx,
+            # capacity-history discriminator: two queries can share the same
+            # join signature (and compiled programs) while filtering the probe
+            # differently — their deferred-chain keys tell them apart so their
+            # recorded capacities don't ping-pong
+            probe_fp = tuple(k for k, _, _ in probe.pending)
+            probe_stacked = probe.stacked
+            probe_types = [s.type for s in probe.symbols]
+            if did is not None:
+                # outcome inputs for the hindsight join (telemetry/decisions):
+                # static-shape byte math only, no device sync.  build_bytes is
+                # ONE logical build copy (a broadcast's stacked batch holds W
+                # replicas); probe_move_bytes is what the rejected partitioned
+                # plan would have had to move for an unplaced probe.
+                bb = int(batch_bytes(build_stacked))
+                observe_decision(
+                    did,
+                    build_bytes=(
+                        bb // max(1, self.wm.n)
+                        if node.distribution == "broadcast" else bb
+                    ),
+                    probe_move_bytes=(
+                        0 if (node.distribution == "broadcast"
+                              and probe.placements)
+                        else int(batch_bytes(probe_stacked))
+                    ),
                 )
-        else:
-            locate, device_emit_total, expand = self._join_step_fns(
-                node, op, pk, bk, _trailing_cap(build_stacked), probe_types
-            )
-            # proof-licensed capacity (verify/capacity.py): a certificate
-            # sealed for THIS mesh width licenses a fixed expand capacity
-            # — the sizing gather, overflow flag, and speculative retry
-            # are deleted, not skipped.  Any mismatch (mesh shrink, knob
-            # off, memory-pressure waves above) falls back to the runtime
-            # sizing path: the license is an optimization with a proof,
-            # never a correctness dependency.
-            cert = getattr(node, "capacity_cert", None)
-            if not (
-                self.license_caps
-                and cert is not None
-                and cert.valid_for(self.wm.n)
-            ):
-                cert = None
-            out = self._sized_expansion(
-                ("join",) + jkey, probe_stacked, build_stacked,
-                locate, device_emit_total, expand, compact_probe=True,
-                stats_key=("join",) + jkey + (probe_fp,),
-                cert=cert,
-            )
-            ctx.close()
+
+            # budget enforcement: reserve the build's device footprint (raw +
+            # sorted copy) BEFORE the expansion materializes; over budget the
+            # join degrades to hash-partition waves with filesystem-SPI spill
+            # instead of dying (runtime/spill, SURVEY §5.7's k-pass loop)
+            from trino_tpu.runtime import spill as _spill
+            from trino_tpu.runtime.memory import ExceededMemoryLimitException
+
+            ctx = self.memory.child("join_build")
+            need = 2 * batch_bytes(build_stacked)
+            wave_k = 0
+            try:
+                ctx.add_bytes(need)
+            except ExceededMemoryLimitException:
+                wave_k = _spill.wave_count(need, self._budget(), self.properties)
+            if wave_k:
+                wdid = record_decision(
+                    "wave", "runtime.join_build", "waves", "direct",
+                    {"waves": int(wave_k), "need_bytes": int(need),
+                     "budget_bytes": int(self._budget() or 0)},
+                )
+                with decision_scope(wdid):
+                    out = self._wave_join(
+                        node, op, probe_stacked, build_stacked, pk, bk, jkey,
+                        probe_types, wave_k, ctx,
+                    )
+            else:
+                locate, device_emit_total, expand = self._join_step_fns(
+                    node, op, pk, bk, _trailing_cap(build_stacked), probe_types
+                )
+                # proof-licensed capacity (verify/capacity.py): a certificate
+                # sealed for THIS mesh width licenses a fixed expand capacity
+                # — the sizing gather, overflow flag, and speculative retry
+                # are deleted, not skipped.  Any mismatch (mesh shrink, knob
+                # off, memory-pressure waves above) falls back to the runtime
+                # sizing path: the license is an optimization with a proof,
+                # never a correctness dependency.
+                cert = getattr(node, "capacity_cert", None)
+                if not (
+                    self.license_caps
+                    and cert is not None
+                    and cert.valid_for(self.wm.n)
+                ):
+                    cert = None
+                out = self._sized_expansion(
+                    ("join",) + jkey, probe_stacked, build_stacked,
+                    locate, device_emit_total, expand, compact_probe=True,
+                    stats_key=("join",) + jkey + (probe_fp,),
+                    cert=cert,
+                )
+                ctx.close()
         return self._dist(
             out, out_symbols,
             placements=join_output_placements(

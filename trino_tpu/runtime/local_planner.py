@@ -30,7 +30,12 @@ from trino_tpu.expr import ExprCompiler
 from trino_tpu.ops.aggregation import AggregationOperator, AggSpec
 from trino_tpu.ops.common import SortKey
 from trino_tpu.ops.filter_project import FilterProjectOperator
-from trino_tpu.ops.join import HashJoinOperator, NestedLoopJoinOperator, SemiJoinOperator
+from trino_tpu.ops.join import (
+    HashJoinOperator,
+    JoinSpan,
+    NestedLoopJoinOperator,
+    SemiJoinOperator,
+)
 from trino_tpu.ops.scan import ScanOperator
 from trino_tpu.ops.sort import LimitOperator, OrderByOperator, TopNOperator
 from trino_tpu.ops.values import ValuesOperator
@@ -93,10 +98,29 @@ class LocalExecutionPlanner:
         if stats is not None:
             stats.memory = self.memory
         self._depth = 0
-        #: symbol name -> (lo, hi) host values collected from materialized
-        #: join build sides (reference: server/DynamicFilterService.java:107 +
-        #: DynamicFilterSourceOperator — build-side ranges prune probe scans)
+        #: symbol name -> frozenset of keys or (lo, hi), host values collected
+        #: from materialized join build sides (reference: server/
+        #: DynamicFilterService.java:107 + DynamicFilterSourceOperator —
+        #: build-side key sets and ranges prune probe scans)
         self.dynamic_filters: dict = {}
+        #: id(join tree) -> the SharedInput its consumer reads it through
+        #: (`share_repeated_inputs`); empty unless the runner asked
+        self._shared: dict = {}
+
+    def share_repeated_inputs(self, root: P.PlanNode) -> None:
+        """Run a join tree that `root` holds more than once (the input of
+        a ROLLUP, CUBE or GROUPING SETS) one time for all its consumers
+        (`runtime/shared_input.py`).  Not under a memory budget, where the
+        operators spill and what a shared input keeps would not; not under
+        EXPLAIN ANALYZE, which reports every operator of the plan."""
+        from trino_tpu.runtime.shared_input import SharedInput, repeated_inputs
+
+        if self.stats is not None or self._budget():
+            return
+        for members in repeated_inputs(root):
+            shared = SharedInput(members)
+            for node in members:
+                self._shared[id(node)] = shared
 
     def _session_budget(self) -> int:
         """Per-query session budget in bytes (query_max_memory / legacy
@@ -132,6 +156,12 @@ class LocalExecutionPlanner:
         return SpillManager(observer=self._observer())
 
     def plan(self, node: P.PlanNode) -> PhysicalPlan:
+        shared = self._shared.get(id(node))
+        if shared is not None:
+            return shared.plan_for(self, node)
+        return self.plan_unshared(node)
+
+    def plan_unshared(self, node: P.PlanNode) -> PhysicalPlan:
         method = getattr(self, "_visit_" + type(node).__name__, None)
         if method is None:
             raise NotImplementedError(f"no local plan for {type(node).__name__}")
@@ -209,9 +239,9 @@ class LocalExecutionPlanner:
         # scan's output symbols) fuse into the scan's first device step
         dyn = []
         for s, _ in node.assignments:
-            rng = self.dynamic_filters.get(s.name)
-            if rng is not None:
-                dyn.append(_range_expr(s, *rng))
+            domain = self.dynamic_filters.get(s.name)
+            if domain is not None:
+                dyn.append(_domain_expr(s, domain))
         if dyn:
             from trino_tpu.expr.ir import and_
 
@@ -422,7 +452,10 @@ class LocalExecutionPlanner:
             right = self.plan(node.right)
             op = NestedLoopJoinOperator(right.types())
             op.set_build(list(right.stream))
-            return PhysicalPlan(op.process(left.stream), left.symbols + right.symbols)
+            span = JoinSpan("cross", op)
+            return PhysicalPlan(
+                span.wrap(op.process, left.stream), left.symbols + right.symbols
+            )
         if node.kind == "right":
             flipped = P.JoinNode(
                 "left", node.right, node.left,
@@ -444,13 +477,15 @@ class LocalExecutionPlanner:
         build = self.plan(node.right)
         build_batches = list(build.stream)
         if node.kind == "inner":
-            # dynamic filtering: build-side key ranges prune the probe scan
-            # (registered before the probe subtree is planned, the
-            # DynamicFilterService ordering)
+            # dynamic filtering: build-side key sets or ranges prune the
+            # probe scan (registered before the probe subtree is planned,
+            # the DynamicFilterService ordering)
             for lsym, rsym in node.criteria:
-                rng = _host_minmax(build_batches, build.channel(rsym.name))
-                if rng is not None:
-                    self.dynamic_filters[lsym.name] = rng
+                domain = _build_key_domain(
+                    build_batches, build.channel(rsym.name)
+                )
+                if domain is not None:
+                    self.dynamic_filters[lsym.name] = domain
         probe = self.plan(node.left)
         # pipeline parallelism (§2.7(4)): the probe feed starts decoding NOW,
         # overlapping the build side's device-side compaction/indexing.
@@ -508,9 +543,9 @@ class LocalExecutionPlanner:
             )
             del build_host
 
-            def wave_stream():
+            def waves(probe_stream):
                 try:
-                    probe_host = host_pull(list(probe.stream), "probe_to_host")
+                    probe_host = host_pull(list(probe_stream), "probe_to_host")
                     probe_side = _spill.partition_side(
                         probe_host, probe_keys, n_waves, spiller, "jp"
                     )
@@ -523,16 +558,18 @@ class LocalExecutionPlanner:
                     if spiller is not None:
                         spiller.close()
 
-            return PhysicalPlan(wave_stream(), out_symbols)
+            span = JoinSpan(node.kind, strategy="partition_waves")
+            return PhysicalPlan(span.wrap(waves, probe.stream), out_symbols)
         op = make_op()
         op.set_build(build_batches)
+        span = JoinSpan(node.kind, op)
         if node.kind == "full":
             # full outer tracks build-side matched flags across the whole
             # probe; a mid-stream revoke cannot split that state exactly,
             # so full joins stay non-revocable (waves still cover them on
             # the up-front over-budget path above)
             def stream():
-                yield from op.process(probe.stream)
+                yield from span.wrap(op.process, probe.stream, op)
                 ctx.close()
 
             return PhysicalPlan(stream(), out_symbols)
@@ -566,9 +603,9 @@ class LocalExecutionPlanner:
             _spill.RevocableOperator("join", ctx, revoke_spill)
         )
 
-        def stream():
+        def joined(probe_stream):
             try:
-                it = iter(probe.stream)
+                it = iter(probe_stream)
                 for pb in it:
                     if handle.revoked:
                         # build spilled by the revoke tier: drop our device
@@ -590,7 +627,7 @@ class LocalExecutionPlanner:
                 if sp is not None:
                     sp.close()
 
-        return PhysicalPlan(stream(), out_symbols)
+        return PhysicalPlan(span.wrap(joined, probe.stream, op), out_symbols)
 
     # -- memory-pressure join waves (spill analog) ----------------------------
 
@@ -616,7 +653,10 @@ class LocalExecutionPlanner:
             residual_key=residual_key,
         )
         op.set_build(list(filt.stream))
-        return PhysicalPlan(op.process(src.stream), src.symbols + [node.mark])
+        span = JoinSpan("semi", op)
+        return PhysicalPlan(
+            span.wrap(op.process, src.stream), src.symbols + [node.mark]
+        )
 
     def _visit_WindowNode(self, node: P.WindowNode) -> PhysicalPlan:
         from trino_tpu.ops.window import WindowOperator, WindowSpec
@@ -1077,6 +1117,133 @@ def build_distinct_dedupe(node: "P.AggregationNode", src) -> tuple:
     return proj, symbols
 
 
+def defer_integer_averages(root: "P.OutputNode") -> tuple:
+    """(plan, {output column: its count column}): every `avg(integer)` whose
+    value only MOVES from its aggregation to the client -- through Output,
+    Limit, Sort and TopN on other keys, projections that merely rename it,
+    and UNION ALL branches that all do the same (ROLLUP's levels) -- is
+    planned as `sum` and `count`, two BIGINT symbols that take the same
+    way, the count as one more output column at the end; the caller
+    divides where the rows reach the host (`divide_deferred`).  The chip
+    holds no IEEE double (its float64 is a pair of float32), so a quotient
+    that has been on the device is not the nearest double; `int / int` on
+    the host is.  An average that anything computes on, sorts by or
+    filters by stays a device double, as before."""
+    from dataclasses import replace
+
+    def pair(name: str) -> tuple:
+        return (
+            P.Symbol(name + "$sum", T.BIGINT), P.Symbol(name + "$count", T.BIGINT)
+        )
+
+    def split(node, name: str):
+        """(node without `name` but with its sum and count, those two
+        symbols), or None if anything but moves lies below."""
+        if isinstance(node, (P.LimitNode, P.SortNode, P.TopNNode)):
+            if any(o[0].name == name for o in getattr(node, "orderings", ())):
+                return None
+            got = split(node.source, name)
+            return got and (node.with_children([got[0]]), *got[1:])
+        if isinstance(node, P.ProjectNode):
+            at = [i for i, (s, _) in enumerate(node.assignments) if s.name == name]
+            ref = node.assignments[at[0]][1] if len(at) == 1 else None
+            if not isinstance(ref, SymbolRef) or sum(
+                ref.name in _symbol_names(e) for _, e in node.assignments
+            ) != 1:
+                return None
+            got = split(node.source, ref.name)
+            if got is None:
+                return None
+            source, total, count = got
+            moved = list(node.assignments)
+            moved[at[0]:at[0] + 1] = [(total, total.ref()), (count, count.ref())]
+            return P.ProjectNode(source, moved), total, count
+        if isinstance(node, P.UnionNode):
+            k = [s.name for s in node.symbols].index(name)
+            sources, mappings = [], []
+            for child, mapping in zip(node.sources, node.source_symbols):
+                if sum(m.name == mapping[k].name for m in mapping) != 1:
+                    return None
+                got = split(child, mapping[k].name)
+                if got is None:
+                    return None
+                sources.append(got[0])
+                mappings.append(
+                    mapping[:k] + [got[1]] + mapping[k + 1:] + [got[2]]
+                )
+            total, count = pair(name)
+            symbols = node.symbols[:k] + [total] + node.symbols[k + 1:] + [count]
+            return P.UnionNode(sources, symbols, mappings), total, count
+        if isinstance(node, P.AggregationNode) and node.step == "single":
+            for i, (sym, agg) in enumerate(node.aggregations):
+                if sym.name != name:
+                    continue
+                if (
+                    agg.function != "avg" or agg.distinct
+                    or agg.args[0].type.name not in _INTEGER_TYPES
+                ):
+                    return None
+                total, count = pair(name)
+                aggs = list(node.aggregations)
+                aggs[i:i + 1] = [
+                    (total, replace(agg, function="sum")),
+                    (count, replace(agg, function="count")),
+                ]
+                return replace(node, aggregations=aggs), total, count
+        return None
+
+    counts: dict = {}
+    if not isinstance(root, P.OutputNode):
+        return root, counts
+    for k, sym in enumerate(root.symbols):
+        if sym.type.name != "double" or root.symbols.count(sym) != 1:
+            continue
+        got = split(root.source, sym.name)
+        if got is None:
+            continue
+        source, total, count = got
+        symbols = list(root.symbols)
+        symbols[k] = total
+        counts[k] = len(symbols)
+        root = P.OutputNode(
+            source, list(root.column_names) + [count.name], symbols + [count]
+        )
+    return root, counts
+
+
+def divide_deferred(rows: list, counts: dict) -> list:
+    """The rows of a plan `defer_integer_averages` split, as the client
+    asked for them: each deferred average divided (`int / int` is the
+    double nearest the exact quotient; NULL over no rows), the count
+    columns dropped."""
+    if not counts:
+        return rows
+    width = min(counts.values())
+    out = []
+    for r in rows:
+        r = list(r)
+        for k, c in counts.items():
+            r[k] = None if not r[c] else r[k] / r[c]
+        out.append(tuple(r[:width]))
+    return out
+
+
+_INTEGER_TYPES = ("tinyint", "smallint", "integer", "bigint")
+
+
+def _symbol_names(expr) -> set:
+    names: set = set()
+
+    def walk(e):
+        if isinstance(e, SymbolRef):
+            names.add(e.name)
+        for k in e.children():
+            walk(k)
+
+    walk(expr)
+    return names
+
+
 def build_agg_inputs(node: "P.AggregationNode", src) -> tuple:
     """(projection exprs, AggSpecs, input types) for an AggregationNode —
     the ONE place the aggregate input layout is decided (group keys first,
@@ -1152,21 +1319,35 @@ def specs_args(specs: list) -> list:
 
 _MINMAX_STEP_CACHE: dict = {}
 
+#: a build side of at most this many live rows prunes the probe scan by its
+#: key SET; a larger one by its key range (reference:
+#: DynamicFilterSourceOperator collects distinct values up to
+#: dynamic-filtering.small.max-distinct-values-per-driver and only then
+#: falls back to min/max).  The set compiles to that many comparisons
+DYNAMIC_FILTER_SET_LIMIT = 64
+#: and only a batch this small is read to the host for it
+_DYNAMIC_FILTER_SET_CAPACITY = 1 << 17
 
-def _host_minmax(batches, channel: int):
-    """(lo, hi) of a materialized column's live+valid values, or None when
-    the domain is empty/unfilterable (dictionary codes aren't portable
-    across scans).
+
+def _build_key_domain(batches, channel: int):
+    """What a materialized build column lets through: a frozenset of its
+    live+valid values when they are few and have holes between them, else
+    their (lo, hi), or None when the domain is empty/unfilterable
+    (dictionary codes aren't portable across scans).
 
     The reduction runs ON DEVICE and only three scalars come back per batch
-    (packed into one array = one host sync).  Pulling the whole column to
-    host — the previous design — moves a build batch's bytes over PCIe and
-    blocks the dispatch thread for the whole copy, per batch."""
+    (one host sync).  Pulling the whole column to host moves a build
+    batch's bytes over PCIe and blocks the dispatch thread for the whole
+    copy, so only a small batch of a build with few live keys is read, to
+    tell WHICH keys: stores 1 and 11 as a range let 11/12 of a fact table
+    through, as a set 2/12."""
     import numpy as np
 
     import jax.numpy as jnp
 
     lo = hi = None
+    total = 0
+    held = []  # (data, live) of the batches that hold a live key
     for b in batches:
         c = b.columns[channel]
         if c.dictionary is not None:
@@ -1189,26 +1370,52 @@ def _host_minmax(batches, channel: int):
                     small = jnp.asarray(info.min, data.dtype)
                 lo_ = jnp.min(jnp.where(live, data, big))
                 hi_ = jnp.max(jnp.where(live, data, small))
-                # any-live flag, NOT a count: a count cast to a narrow key
-                # dtype (int8/int16) wraps to 0 at 256/65536 live rows and
+                # the count apart, as int32: cast to a narrow key dtype
+                # (int8/int16) it wraps to 0 at 256/65536 live rows and
                 # would silently skip the batch
-                n = jnp.any(live).astype(data.dtype)
-                return jnp.stack([lo_, hi_, n])
+                return jnp.stack([lo_, hi_]), jnp.sum(live, dtype=jnp.int32)
 
             step = jit_program(_step, "minmax_stats")
             _MINMAX_STEP_CACHE[dt.str] = step
         live = b.mask()
         if c.valid is not None:
             live = jnp.logical_and(live, c.valid)
-        packed = host_pull(step(c.data, live), "dynamic_filter")
-        if packed[2] == 0:
+        (blo, bhi), n = host_pull(step(c.data, live), "dynamic_filter")
+        if n == 0:
             continue
-        blo, bhi = packed[0], packed[1]
+        total += int(n)
+        held.append((c.data, live))
         lo = blo if lo is None else min(lo, blo)
         hi = bhi if hi is None else max(hi, bhi)
     if lo is None:
         return None
+    if (
+        np.dtype(held[0][0].dtype).kind in "iu"
+        and total <= DYNAMIC_FILTER_SET_LIMIT
+        and int(hi) - int(lo) >= total  # else the range says as much
+        and all(d.shape[0] <= _DYNAMIC_FILTER_SET_CAPACITY for d, _ in held)
+    ):
+        keys = set()
+        for data, live in host_pull(held, "dynamic_filter"):
+            keys.update(int(k) for k in data[live])
+        return frozenset(keys)
     return (lo, hi)
+
+
+def _domain_expr(sym, domain) -> Expr:
+    """The probe-side predicate of a build-side key domain."""
+    if isinstance(domain, frozenset):
+        t = sym.type
+        if isinstance(t, T.DecimalType):
+            from decimal import Decimal
+
+            values = [Decimal(k) / t.scale_factor for k in sorted(domain)]
+        else:
+            values = sorted(domain)
+        return SpecialForm(
+            Form.IN, [sym.ref()] + [Literal(v, t) for v in values], T.BOOLEAN
+        )
+    return _range_expr(sym, *domain)
 
 
 def _range_expr(sym, lo, hi) -> Expr:

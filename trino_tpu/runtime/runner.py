@@ -14,7 +14,11 @@ from typing import Optional, Sequence
 from trino_tpu.connectors.api import CatalogManager, default_catalogs
 from trino_tpu.planner.logical_planner import LogicalPlanner, Session
 from trino_tpu.planner.plan import OutputNode, plan_text
-from trino_tpu.runtime.local_planner import LocalExecutionPlanner
+from trino_tpu.runtime.local_planner import (
+    LocalExecutionPlanner,
+    defer_integer_averages,
+    divide_deferred,
+)
 from trino_tpu.sql import ast
 from trino_tpu.sql.parser import parse_statement
 
@@ -717,7 +721,11 @@ class LocalQueryRunner:
                     stats=stats,
                     properties=self.properties,
                 )
-                physical = lp.plan(plan)
+                # the rows below go from the stream to the host and nowhere
+                # else, so an avg(integer) may leave its division to it
+                split, counts = defer_integer_averages(plan)
+                lp.share_repeated_inputs(split)
+                physical = lp.plan(split)
             rows = []
             it = iter(physical.stream)
             done = object()
@@ -731,6 +739,7 @@ class LocalQueryRunner:
                         break
                     check_current()  # cancel/deadline between result batches
                     rows.extend(tuple(r) for r in batch.to_pylist())
+            rows = divide_deferred(rows, counts)
             self._last_peak_memory = lp.memory.peak
         return MaterializedResult(
             list(plan.column_names), rows, [s.type for s in plan.symbols]

@@ -20,7 +20,25 @@ them; a new site takes a name from here or adds one here):
 
 span names:
     query, queued, analyze, optimize, fragment, execute, build, result,
-    schedule, fragment-N, transfer, launch, compile, host_pull, task
+    schedule, fragment-N, transfer, launch, compile, host_pull, task, join
+
+join span (one per join operator per statement, under `execute`; on the
+mesh under its `fragment-N`, from both sides ready to the joined output):
+    from the operator's first probe batch to its last output (local runner:
+    `ops/join.py` JoinSpan; the build side's work lies before it, under
+    `build`).  Attributes: kind (inner | left | full | semi | cross),
+    strategy (local: the locate step the build chose, join_locate_table |
+    join_locate_sorted | join_nested_expand, or partition_waves for a build
+    over the memory budget, which has no build_rows and no counts (its
+    operators are made wave by wave); mesh: broadcast |
+    partitioned | colocated), build_rows and, for a hash join, what it
+    reads to size its output anyway: probe_rows, out_rows (matches emitted,
+    before a residual filter), null_keys (probe rows dropped for a NULL
+    key, counted by the locate program; summed in
+    `trino_tpu_join_null_keys_total`).  `launch` and `host_pull` spans
+    of the operator's own batches nest under it on the statement's thread;
+    a join below another join runs on a prefetch thread, where the doors
+    record nothing, and keeps its span and its counts all the same.
 
 launch steps (`step=` of a `launch` span; the XLA module is `jit_<step>`):
     local — filter_project, unnest, sample, window, sort, sort_merge,
@@ -49,7 +67,7 @@ host_pull why (`why=` of a `host_pull` span: what the host needed it for):
     result (rows for the client), capacity (a count that sizes the next
     program's static shape), overflow_flag (a speculative capacity's
     check), group_stats (key ranges that choose an aggregation or join
-    layout), dynamic_filter (build-side key ranges and pruning counts),
+    layout), dynamic_filter (build-side key sets, ranges and pruning counts),
     build_to_host, probe_to_host (join sides leaving the device for
     partition waves), spill (operator state to the spill tier),
     sort_compact (a sort run leaving the device), dictionary (codes read

@@ -766,6 +766,12 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
     )
     for o in JOIN_CAPACITY_OUTCOMES:
         joincap.touch(o)
+    reg.counter(
+        _PREFIX + "join_null_keys_total",
+        "probe rows a local join dropped because a join key was NULL (SQL "
+        "`=` never matches NULL): the `null_keys` attribute of the `join` "
+        "spans, summed (ops/join.py JoinSpan.wrap)",
+    ).touch()
     decisions = reg.counter(
         _PREFIX + "plan_decisions_total",
         "plan-decision ledger entries (telemetry/decisions.py) by decision "
@@ -877,6 +883,11 @@ def join_capacity_counter() -> Counter:
     A warm licensed workload bumps ONLY proven — compare_bench
     check_licenses gates runtime_check == 0 over the benched warm runs."""
     return REGISTRY.counter(_PREFIX + "join_capacity_total")
+
+
+def join_null_keys_counter() -> Counter:
+    """Probe rows local joins dropped for a NULL join key."""
+    return REGISTRY.counter(_PREFIX + "join_null_keys_total")
 
 
 def collective_async_counter() -> Counter:

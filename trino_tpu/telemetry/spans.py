@@ -105,6 +105,32 @@ class _OpenSpan:
         return False
 
 
+class _Restacked:
+    """Context manager of SpanTracer.entered() and left(): for the body
+    `sp` is pushed on the stack of open spans (or, `push` false, popped)."""
+
+    __slots__ = ("stack", "sp", "push")
+
+    def __init__(self, stack: list, sp: Span, push: bool):
+        self.stack = stack
+        self.sp = sp
+        self.push = push
+
+    def _move(self, push: bool) -> None:
+        if push:
+            self.stack.append(self.sp)
+        elif self.stack and self.stack[-1] is self.sp:
+            self.stack.pop()
+
+    def __enter__(self) -> Span:
+        self._move(self.push)
+        return self.sp
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self._move(not self.push)
+        return False
+
+
 class _NullCtx:
     """Shared no-op context manager (the off-path of span())."""
 
@@ -161,6 +187,38 @@ class SpanTracer:
             self.root.children.append(sp)
         self._stack.append(sp)
         return _OpenSpan(self, sp)
+
+    def held(self, name: str, under: str = "", **attrs) -> Span:
+        """A span an operator holds across the batches of its stream: a
+        child of the innermost open span (of the innermost named `under`,
+        if one is open), closed at birth (zero length) and NOT pushed.
+        Its holder moves `start_s`/`end_s` as batches pass -- from any
+        thread, the span is its own -- and wraps its own work in
+        `entered()` so that the doors' spans nest under it."""
+        t = now()
+        for parent in reversed(self._stack):
+            if parent.name == under:
+                return self.attach(parent, name, t, t, attrs)
+        return self.record(name, t, t, attrs)
+
+    def left(self, sp: Optional[Span]):
+        """The inverse of `entered()`: for the body `sp` is off the stack
+        if it is the innermost open span (an operator pulling its input,
+        whose work is not its own)."""
+        if (
+            sp is None or self.thread_id != get_ident()
+            or not self._stack or self._stack[-1] is not sp
+        ):
+            return _NULL_CTX
+        return _Restacked(self._stack, sp, push=False)
+
+    def entered(self, sp: Optional[Span]):
+        """Context manager: `sp` (from `held()`) is the innermost open
+        span for the body.  Off the tracer's own thread it is a no-op, as
+        the doors record nothing there."""
+        if sp is None or self.thread_id != get_ident():
+            return _NULL_CTX
+        return _Restacked(self._stack, sp, push=True)
 
     def record(self, name: str, start_s: float, end_s: float,
                attrs: Optional[dict] = None) -> Optional[Span]:
@@ -305,6 +363,14 @@ class NullTracer:
 
     def record(self, name, start_s, end_s, attrs=None) -> None:
         pass
+
+    def held(self, name: str, under: str = "", **attrs) -> None:
+        return None
+
+    def entered(self, sp) -> _NullCtx:
+        return _NULL_CTX
+
+    left = entered
 
     def attach(self, parent, name, start_s, end_s, attrs=None) -> None:
         pass
