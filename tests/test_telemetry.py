@@ -1347,6 +1347,28 @@ def test_agg_path_is_replayed_per_execution(runner):
     assert total() - before >= len(with_path)
 
 
+def test_compaction_form_rides_the_compact_launch(runner):
+    """Every `compact` launch says how it found its slots' source rows
+    (`path=compact_sort`: a one-key sort, never a scatter), counted per
+    execution; a streaming aggregation's fold goes through the same door."""
+    from trino_tpu.telemetry.metrics import aggregation_path_counter
+
+    runner.execute(_tpch(18))  # traces (or finds the programs cached)
+    c = aggregation_path_counter()
+    before = c.value(("compact_sort",))
+    _, flat = _run_with_context(runner, _tpch(18))
+    paths = [
+        _attrs(s).get("path") for s in flat
+        if s["name"] == "launch" and _attrs(s)["step"] == "compact"
+    ]
+    assert paths and set(paths) == {"compact_sort"}, paths
+    assert c.value(("compact_sort",)) - before >= len(paths)
+    _, flat = _run_with_context(runner, _tpch(1))  # its partial states fold
+    assert any(
+        s["name"] == "launch" and _attrs(s)["step"] == "compact" for s in flat
+    )
+
+
 def test_pull_off_the_statement_thread_is_counted_without_a_span():
     import contextvars
     import threading

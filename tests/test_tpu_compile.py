@@ -12,6 +12,7 @@ module-scoped fixture — never at import, never in conftest.py.
 """
 
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -194,6 +195,39 @@ def test_locate_sorted_compiles(one_chip, cap_b, probe):
         S((cap_b,), jnp.int64), S((), jnp.int64),
         S((probe,), jnp.int64), S((probe,), jnp.bool_),
     ).compile()
+
+
+@pytest.mark.parametrize(
+    "cap,outc", [(1 << 19, 1 << 10), (1 << 20, 1 << 19)],
+    ids=["512K_to_1K", "1M_to_512K"],
+)
+def test_compact_compiles_without_scatter(one_chip, cap, outc):
+    """`COMPACT` at a dynamically filtered store_sales split and at a
+    lineitem split: each slot's source row comes from a one-key sort in
+    blocks.  As a scatter it cost 38.8 ms a 2^19-row split whatever came out
+    (13 of `star_report`'s 21 busy seconds, PERF.md section 6, PR 31); a long
+    cumsum or one sort of the whole plane compiles for 11-34 s a variant."""
+    from trino_tpu import types as T
+    from trino_tpu.columnar import Batch, Column
+    from trino_tpu.columnar.batch import COMPACT
+
+    S = _shapes(one_chip)
+    batch = Batch(
+        [
+            Column(S((cap,), jnp.int64), T.BIGINT, None),
+            Column(S((cap, 2), jnp.int64), T.DecimalType(38, 2), None),
+        ],
+        S((cap,), jnp.bool_),
+    )
+    t0 = time.perf_counter()
+    compiled = COMPACT.lower(batch, out_capacity=outc).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    assert " scatter(" not in text and " sort(" in text  # ops, not frame names
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # one [rows, slots] int32 plane would be 2 GiB at the smaller shape
+    assert temp < (64 << 20), f"temp grew to {temp} bytes"
+    assert seconds < 20, f"compiled for {seconds:.1f} s"
 
 
 # -- the cross-chip path: one program over the four chips of a v5e:2x2 ---------

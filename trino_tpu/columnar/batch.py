@@ -17,7 +17,7 @@ import numpy as np
 
 from trino_tpu.columnar.column import Column
 from trino_tpu.runtime.lifecycle import current_query
-from trino_tpu.telemetry.programs import jit_program, recording_tracer
+from trino_tpu.telemetry.programs import jit_program, note_path, recording_tracer
 from trino_tpu.telemetry.spans import now
 
 
@@ -82,23 +82,17 @@ class Batch:
         return Batch(cols, valid)
 
     def compact_device(self, out_capacity: Optional[int] = None) -> "Batch":
-        """Pack live rows to the front (stable) via cumsum-scatter.
+        """Pack live rows to the front (stable): each output slot finds its
+        source row (`slot_sources`), then every column gathers.
 
         Shape-stable: output capacity is static (`out_capacity` or input
-        capacity); trailing slots are dead.  This is the selection-vector ->
-        dense step the reference does in PageProcessor output.
+        capacity); trailing slots are dead and read row 0.  When more rows
+        are live than fit, the first `out_capacity` come out.  This is the
+        selection-vector -> dense step the reference does in PageProcessor
+        output.
         """
-        cap = self.capacity
-        outc = out_capacity or cap
-        m = self.mask()
-        pos = jnp.cumsum(m) - 1  # target slot per live row
-        idx = jnp.where(m, pos, outc)  # dead rows scatter out of range
-        n = jnp.sum(m)
-        # inverse permutation: for each output slot, which input row
-        inv = jnp.zeros(outc + 1, dtype=jnp.int64).at[idx].set(
-            jnp.arange(cap, dtype=jnp.int64), mode="drop"
-        )[:outc]
-        live = jnp.arange(outc, dtype=jnp.int64) < n
+        outc = out_capacity or self.capacity
+        inv, live = slot_sources(self.mask(), outc)
         cols = [c.gather(inv) for c in self.columns]
         return Batch(cols, live)
 
@@ -170,6 +164,54 @@ def host_pull(tree, why: str):
     ctx.host_pull_s += now() - t0
     ctx.d2h_bytes += nbytes
     return out
+
+
+#: rows of one block of `slot_sources`' sort: a batched sort of rows this
+#: long compiles in 2-3 s whatever the capacity and sorts in on-chip memory;
+#: one `lax.sort` of a whole 2^19..2^20-row plane compiled for 11-17 s a
+#: variant (tools/compact_sweep.py, PERF.md section 6, PR 31)
+_SORT_BLOCK = 4096
+
+
+def slot_sources(m, outc: int):
+    """(inv, live) of a stable compaction of the rows `m` marks into `outc`
+    slots: `inv[j]` is the source row of slot j and `live[j] = j < n`, n the
+    live rows; a dead slot reads row 0, and rows past the first `outc` live
+    ones are left out.  Positions and counts are int32: the chip emulates
+    int64 as two u32 planes.
+
+    Never a scatter: the TPU serialises one at ~75 ns a SOURCE row whatever
+    comes out (38.8 ms a 2^19-row split, 63-89 ms a 2^20-row one; this form
+    1.1-4.2 ms and 1.2-13.7 ms: tools/compact_sweep.py, PERF.md section 6,
+    PR 31).  A one-key sort in blocks instead: the key is the row number
+    with bit 31 set on dead rows (unique, so no payload and no stability
+    needed); sorted, each block of `_SORT_BLOCK` rows holds its live rows
+    first, in order.  Slot j then lies in the block after those whose
+    running total it has passed (a dense compare against at most
+    capacity / `_SORT_BLOCK` totals), at its offset from that block's first
+    slot: one gather of `outc` keys.
+    """
+    cap = m.shape[0]
+    assert cap < (1 << 31) and outc < (1 << 31), (cap, outc)
+    if cap == 0:
+        return jnp.zeros(outc, jnp.int32), jnp.zeros(outc, bool)
+    note_path("compact_sort")
+    width = min(_SORT_BLOCK, cap)
+    m = jnp.pad(m, (0, -cap % width)).reshape(-1, width)
+    rows = jnp.arange(m.size, dtype=jnp.uint32).reshape(m.shape)
+    blocks = jax.lax.sort(
+        jnp.where(m, rows, rows | jnp.uint32(1 << 31)), dimension=1
+    )
+    count = jnp.sum(m, axis=1, dtype=jnp.int32)
+    end = jnp.cumsum(count)
+    slot = jnp.arange(outc, dtype=jnp.int32)
+    live = slot < end[-1]
+    passed = end[None, :] <= slot[:, None]
+    block = jnp.sum(passed, axis=1, dtype=jnp.int32)
+    start = jnp.sum(jnp.where(passed, count[None, :], 0), axis=1, dtype=jnp.int32)
+    key = jnp.take(blocks.reshape(-1), block * width + (slot - start), mode="clip")
+    inv = jnp.where(live, key & jnp.uint32(0x7FFF_FFFF), 0).astype(jnp.int32)
+    return inv, live
 
 
 #: jitted stable compaction (Batch.compact_device), ONE program object for

@@ -2109,7 +2109,7 @@ class AggregationOperator:
         """Merge accumulated state batches into one, compacted to live size."""
         merged = self._combine(concat_batches(self._acc), "merge")
         n = merged.num_rows_host()
-        self._acc = [merged.compact_device(next_pow2(max(n, 1), floor=1))]
+        self._acc = [COMPACT(merged, out_capacity=next_pow2(max(n, 1), floor=1))]
 
     def finish(self) -> Batch:
         if not self._acc:

@@ -318,8 +318,8 @@ class PatternRecognitionOperator:
         pid = np.cumsum(change)
         # DEFINE bools on device: rewrite prev/next -> $nav calls with the
         # pid channel appended.  Padded dead slots get pid -1 so navigation
-        # never treats them as in-partition (compact_device fills dead rows
-        # with row 0's data).
+        # never treats them as in-partition (compact_device's dead slots
+        # read row 0: `slot_sources` gives them source row 0).
         pid_col = Column(
             jnp.asarray(
                 np.pad(pid, (0, cap - n), constant_values=-1)

@@ -978,7 +978,7 @@ class StageExecutor:
         (MergeOperator/MergeSortedPages role)."""
         from trino_tpu.ops.merge import merge_sorted_shards
 
-        # compaction is STABLE (cumsum-scatter keeps live-row order), so
+        # compaction is STABLE (`slot_sources` keeps live-row order), so
         # the per-worker sorted runs stay sorted for the host merge
         host = host_pull(self._gather_compact(child.stacked), why)
         keys = [

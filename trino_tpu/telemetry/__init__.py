@@ -61,7 +61,11 @@ launch paths (`path=` of a `launch` span, `+`-joined; each also a label of
     pallas, onehot, segmented, positional, sort (the grouped-aggregation
     formulation the step chose), dense, scatter (how its segment
     reductions lowered: masked reductions over few segments, or
-    `jax.ops.segment_*` over many — `ops/common.segment_reduce`)
+    `jax.ops.segment_*` over many — `ops/common.segment_reduce`),
+    compact_sort (the program packs live rows to the front and found
+    each output slot's source row by a one-key sort in blocks, never a
+    scatter — `columnar/batch.slot_sources`; on `compact` and every mesh
+    `*_compact` / `fused_expand` step that packs rows)
 
 host_pull why (`why=` of a `host_pull` span: what the host needed it for):
     result (rows for the client), capacity (a count that sizes the next

@@ -417,9 +417,13 @@ DECIMAL_FASTPATHS = ("proven", "runtime_check", "limb")
 #: int64 one-hot masked reduction, per-aggregate segment reductions over
 #: dense codes, the range-positional domain, or the sort-based numbering —
 #: and how its segment reductions lowered (ops/common.segment_reduce):
-#: dense masked reductions (few segments) or scatters (many)
+#: dense masked reductions (few segments) or scatters (many); and that a
+#: program packed rows, finding its slots' source rows by a one-key sort
+#: (columnar/batch.slot_sources; prefixed, because `sort` is the
+#: aggregation's sort-based numbering)
 AGGREGATION_PATHS = (
     "pallas", "onehot", "segmented", "positional", "sort", "dense", "scatter",
+    "compact_sort",
 )
 
 
@@ -750,7 +754,9 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
         "reductions over dense codes, positional = range-positional domain, "
         "sort = sort-based numbering; and how the program's segment reductions "
         "lowered: dense = masked reductions (few segments), scatter = "
-        "jax.ops.segment_* (many)",
+        "jax.ops.segment_* (many); compact_sort = the program packed live "
+        "rows to the front, each output slot's source row found by a "
+        "one-key sort in blocks (columnar/batch.slot_sources)",
         labelnames=("path",),
     )
     for p in AGGREGATION_PATHS:
