@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import argparse
 import datetime
+import functools
 import json
 import math
 import statistics
@@ -82,18 +83,31 @@ def say(**facts) -> None:
 
 
 #: lineitem columns of the exact references, ONE set for both queries so
-#: bench_numpy's column cache generates them once per schema (SF10: ~40 s)
+#: the column cache generates them once per schema (SF10: ~40 s)
 EXACT_COLUMNS = (
     "l_returnflag", "l_linestatus", "l_quantity", "l_extendedprice",
     "l_discount", "l_tax", "l_shipdate",
 )
 
-
+@functools.lru_cache(maxsize=None)
 def _lineitem(schema: str) -> dict:
-    from bench_numpy import _columns
+    """Full host columns of lineitem, concatenated across the connector's
+    pages: {name: (values, dictionary or None)}."""
+    from trino_tpu.connectors.api import TableHandle
     from trino_tpu.connectors.tpch import TpchConnector
 
-    return _columns(TpchConnector(), schema, "lineitem", EXACT_COLUMNS)
+    conn = TpchConnector()
+    names = list(EXACT_COLUMNS)
+    parts: dict[str, list] = {n: [] for n in names}
+    dicts: dict[str, object] = {}
+    handle = TableHandle("tpch", schema, "lineitem")
+    for split in conn.splits(handle, target_splits=1):
+        src = conn.page_source(split, names, max_rows_per_page=1 << 22)
+        for page in src.pages():
+            for n, cd in zip(names, page):
+                parts[n].append(np.asarray(cd.values))
+                dicts[n] = cd.dictionary
+    return {n: (np.concatenate(parts[n]), dicts.get(n)) for n in names}
 
 
 def _days(date: str) -> int:

@@ -3,7 +3,8 @@
 Mirrors the reference's DistributedQueryRunner trick (N workers in one JVM,
 testing/trino-testing/.../DistributedQueryRunner.java:84): N logical TPU
 workers are N XLA host devices in one process.  The chip is driven by
-chip_smoke.py (and bench.py), one process per chip — never by the tests.
+chip_smoke.py and benchmark/run.py, one process per chip — never by the
+tests.
 """
 
 import os

@@ -68,8 +68,22 @@ def dist():
     return d
 
 
+#: a join whose build key (l_suppkey) is not unique
+SUPPKEY_JOIN = (
+    "select count(*) from customer c join lineitem l "
+    "on c.c_custkey = l.l_suppkey"
+)
+
+
 def _joins(plan):
     return [n for n in _walk(plan) if isinstance(n, P.JoinNode)]
+
+
+def _suppkey_join(plan):
+    return next(
+        j for j in _joins(plan)
+        if "l_suppkey" in {s.name for pair in j.criteria for s in pair}
+    )
 
 
 def _scan(plan, table):
@@ -133,16 +147,21 @@ class TestDerivation:
         )
         assert frozenset({"o_custkey"}) in unique_sets(agg, local.catalogs)
 
-    def test_unlicensable_join_gets_no_cert(self, local):
-        # build side keyed on a non-unique column: no proof, no license
-        plan = local.create_plan(
-            "select count(*) from customer c join lineitem l "
-            "on c.c_custkey = l.l_suppkey"
-        )
-        for j in _joins(plan):
-            rkeys = frozenset(r.name for _, r in j.criteria)
-            if "l_suppkey" in rkeys:
-                assert j.capacity_cert is None
+    def test_non_unique_build_key_gets_only_the_wide_cert(self, local):
+        # build side keyed on a non-unique column: there is no uniqueness
+        # proof, so the width-adaptive derivation grants only the bound
+        # that is sound by counting -- one probe row matches at most every
+        # build row -- and leaves holding it to the runner's economy policy
+        # (TestMeshExecution.test_wide_cert_is_declined_cold)
+        plan = local.create_plan(SUPPKEY_JOIN)
+        j = _suppkey_join(plan)
+        cert = j.capacity_cert
+        assert cert is not None
+        build_rows = rows_bound(j.right, local.catalogs)
+        assert cert.fanout_bound == cert.build_rows_bound == build_rows
+        assert cert.provenance[0] == f"multiplicity:build<={build_rows}/key"
+        assert not any(p.startswith("unique:build") for p in cert.provenance)
+        assert check_capacity_certificates(plan, local.catalogs) == []
 
     def test_witness_columns_actually_unique_in_generated_data(self, local):
         # empirical audit of the proof's ground truth: the generator
@@ -260,7 +279,7 @@ class TestCertificateAndVerifier:
     def test_seal_and_mesh_validity(self, local):
         plan = local.create_plan(Q3)
         n = seal_licenses(plan, 8)
-        assert n == 2
+        assert n == 3  # two joins and the grouped aggregation
         for j in _joins(plan):
             assert j.capacity_cert.valid_for(8)
             assert not j.capacity_cert.valid_for(7)
@@ -284,15 +303,27 @@ class TestCertificateAndVerifier:
         assert violations and violations[0].rule == "capacity-unsound"
 
     def test_cert_without_uniqueness_witness_rejected(self, local):
+        # the derivable certificate of a non-unique build key is the wide
+        # one; a claim of fanout 1 there has no witness and is tighter
+        # than anything admissible
+        plan = local.create_plan(SUPPKEY_JOIN)
+        j = _suppkey_join(plan)
+        assert j.capacity_cert.fanout_bound > 1
+        j.capacity_cert = CapacityCertificate(fanout_bound=1)
+        violations = check_capacity_certificates(plan, local.catalogs)
+        assert violations and violations[0].rule == "capacity-unsound"
+        assert "tighter than the provable bound" in str(violations[0])
+
+    def test_cert_on_unprovable_join_rejected(self, local):
+        # a 'right' join is never licensed before its sides flip (the
+        # certificate would describe the wrong build side): no proof is
+        # derivable, so any attached claim is rejected outright
         plan = local.create_plan(
-            "select count(*) from customer c join lineitem l "
+            "select count(*) from customer c right join lineitem l "
             "on c.c_custkey = l.l_suppkey"
         )
-        j = next(
-            x for x in _joins(plan)
-            if "l_suppkey" in {r.name for _, r in x.criteria}
-        )
-        assert j.capacity_cert is None
+        j = _suppkey_join(plan)
+        assert j.kind == "right" and j.capacity_cert is None
         j.capacity_cert = CapacityCertificate(fanout_bound=1)
         violations = check_capacity_certificates(plan, local.catalogs)
         assert violations and violations[0].rule == "capacity-unsound"
@@ -310,8 +341,17 @@ class TestCertificateAndVerifier:
         assert check_capacity_certificates(plan, local.catalogs) == []
 
     def test_license_pass_is_idempotent_and_counts(self, local):
+        def certs(plan):
+            return [
+                n.capacity_cert for n in _walk(plan)
+                if getattr(n, "capacity_cert", None) is not None
+            ]
+
         plan = local.create_plan(Q3)
-        assert license_join_capacities(plan, local.catalogs) == 2
+        before = certs(plan)
+        # Q3's two joins and its grouped aggregation
+        assert license_join_capacities(plan, local.catalogs) == 3
+        assert certs(plan) == before  # the planner's own pass derived these
 
 
 # -- part (c): range certificates for filter/join outputs ----------------------
@@ -445,10 +485,20 @@ class TestScheduleLicense:
 
 class TestMeshExecution:
     def test_q3_runs_with_zero_runtime_sizing(self, dist, local):
+        from trino_tpu.telemetry.metrics import join_capacity_counter
+
         dist.execute(Q3)  # settle
+        runtime_checks = join_capacity_counter().value(("runtime_check",))
         res = dist.execute(Q3)
+        assert (
+            join_capacity_counter().value(("runtime_check",))
+            == runtime_checks
+        )
         prof = dist.last_mesh_profile
         counters = dict(prof.counters)
+        # under the co-partitioned layouts the probe is not repartitioned
+        assert counters.get("repartition_collective", 0) == 0
+        assert counters.get("exchange_elided", 0) > 0
         assert counters.get("join_overflow_check", 0) == 0
         assert counters.get("join_capacity_sync", 0) == 0
         assert counters.get("join_speculative_retry", 0) == 0
@@ -507,6 +557,28 @@ class TestMeshExecution:
             + counters.get("join_capacity_sync", 0)
         ) >= 1
         assert rows == local.execute(sql).rows
+
+    def test_wide_cert_is_declined_cold(self, dist, local):
+        # the wide certificate of a non-unique build key (fanout bound =
+        # the build's row bound) is sound but, with no learned width to
+        # hold it against, far wider than the probe: the economy policy
+        # declines it and the join sizes itself by the runtime protocol
+        from trino_tpu.partitioning.speculative import CAP_HISTORY
+
+        saved = CAP_HISTORY.snapshot()
+        CAP_HISTORY.clear()
+        try:
+            res = dist.execute(SUPPKEY_JOIN)
+        finally:
+            CAP_HISTORY.seed(saved)
+        counters = dict(dist.last_mesh_profile.counters)
+        assert counters.get("join_license_declined", 0) == 1
+        assert counters.get("join_capacity_proven", 0) == 0
+        assert (
+            counters.get("join_overflow_check", 0)
+            + counters.get("join_capacity_sync", 0)
+        ) >= 1
+        assert res.rows == local.execute(SUPPKEY_JOIN).rows
 
     def test_license_knob_off_runs_runtime_path(self, dist, local):
         sql = (

@@ -51,18 +51,21 @@ def lockgraph():
 
 
 @pytest.fixture(scope="module", autouse=True)
-def no_spool_leaks():
+def no_spool_leaks(tmp_path_factory):
     """Chaos kills must never leak spool directories: every query-owned
     spool (fault-tolerant recovery included) is removed when its query
-    ends, so /tmp holds zero orphan .npz spools after the module."""
+    ends, so the module's own temporary root (not the shared /tmp, where
+    another xdist worker's live spool would read as a leak) holds zero
+    orphan .npz spools after the module."""
     import glob
     import os
     import tempfile
 
-    pat = os.path.join(tempfile.gettempdir(), "trino_tpu_spool_*")
-    before = set(glob.glob(pat))
-    yield
-    leaked = set(glob.glob(pat)) - before
+    root = str(tmp_path_factory.mktemp("spool_root"))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(tempfile, "tempdir", root)
+        yield
+    leaked = glob.glob(os.path.join(root, "trino_tpu_spool_*"))
     assert not leaked, f"spool directories leaked: {sorted(leaked)}"
 
 

@@ -836,3 +836,154 @@ def test_concurrent_lanes_isolate_decision_ledgers():
         assert len(qids) == len(set(qids))
     finally:
         srv.shutdown()
+
+
+# -- the mesh served to concurrent clients -------------------------------------
+
+
+def _serve(dispatcher, mix, oracle, clients=4, rounds=3):
+    """K client threads through the dispatcher, each statement checked
+    against the serial oracle; returns (answered, shed, errors)."""
+    answered, shed, errors = [0], [0], []
+    lock = threading.Lock()
+
+    def client(i):
+        for j in range(rounds):
+            sql = mix[(i + j) % len(mix)]
+            try:
+                ticket = dispatcher.enqueue()
+                ticket.wait()
+                res = dispatcher.run_admitted(
+                    ticket, lambda r: r.execute(sql)
+                )
+            except QueryShedError:
+                with lock:
+                    shed[0] += 1
+                continue
+            except Exception as e:  # a classified failure is still wrong
+                with lock:
+                    errors.append(f"{type(e).__name__}: {e}"[:200])
+                continue
+            with lock:
+                if sorted(map(str, res.rows)) == oracle[sql]:
+                    answered[0] += 1
+                else:
+                    errors.append(f"rows differ: {sql[:60]}")
+
+    threads = [
+        threading.Thread(
+            target=client, args=(i,), daemon=True, name=f"serve-client-{i}"
+        )
+        for i in range(clients)
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive(), "a served client hung"
+    return answered[0], shed[0], errors
+
+
+@pytest.fixture(scope="module")
+def served_mesh():
+    """The 8-worker mesh behind a one-lane dispatcher, a TPC-H mix (Q1, Q6,
+    Q3 at tiny) warmed by serial passes, and the serial oracle."""
+    from trino_tpu.connectors.tpch.queries import QUERIES
+    from trino_tpu.parallel import DistributedQueryRunner
+    from trino_tpu.runtime.prewarm import replay_statements
+
+    dist = DistributedQueryRunner(n_workers=8, schema="tiny")
+    mix = [QUERIES[q] for q in (1, 6, 3)]
+    oracle = {
+        sql: sorted(map(str, dist.execute(sql).rows)) for sql in mix
+    }
+    # a statement that learns a join capacity compiles its fused expand
+    # once more on its next run: settle before any watermark
+    replay_statements(dist, mix)
+    mgr = ResourceGroupManager(
+        ResourceGroupConfig("global", hard_concurrency=1, max_queued=32)
+    )
+    return dist, QueryDispatcher(dist, mgr, lanes=1), mix, oracle
+
+
+def test_warm_mesh_serving_compiles_nothing(served_mesh):
+    """Concurrent clients of the warmed mesh share its one trace-cache key
+    set: every statement answers the serial oracle (or is shed) and the
+    compile observatory records no event above the warm-up watermark."""
+    from trino_tpu.telemetry.compile_events import OBSERVATORY
+
+    _, dispatcher, mix, oracle = served_mesh
+    watermark = OBSERVATORY.mark()
+    answered, shed, errors = _serve(dispatcher, mix, oracle)
+    assert not errors, errors
+    assert answered + shed == 12 and answered >= 1
+    assert OBSERVATORY.mark() - watermark == 0
+
+
+def test_served_mesh_recovers_a_killed_stage_from_the_spool(served_mesh):
+    """A stage killed mid-statement while the mesh serves concurrent
+    clients, with fault_tolerant_execution on: every statement still
+    answers the serial oracle, the kill is classified as a task retry and
+    never a failure, the retry resumes from spooled stage outputs, and the
+    query is never re-planned."""
+    from trino_tpu.runtime.retry import FAILURE_INJECTOR, InjectedFailure
+    from trino_tpu.telemetry.metrics import (
+        membership_events_counter,
+        mesh_events_counter,
+        spooled_fragments_counter,
+        task_retries_counter,
+    )
+
+    dist, dispatcher, mix, oracle = served_mesh
+
+    def recovery():
+        retries = task_retries_counter()
+        return {
+            "retry": retries.labels("retry").value(),
+            "fail": retries.labels("fail").value(),
+            "spooled": spooled_fragments_counter().value(),
+            "spool_read": mesh_events_counter().labels("spool_read").value(),
+            "replans": membership_events_counter().labels(
+                "shrink_replan"
+            ).value(),
+        }
+
+    q3_first = [mix[2], mix[0], mix[1]]  # client 0 opens with the join
+    fired = [0]
+    orig = FAILURE_INJECTOR.maybe_fail
+
+    def kill_once(point):
+        # the finish hook of a stage whose children already completed and
+        # spooled, in client 0's first statement
+        if (
+            not fired[0]
+            and point.startswith("stage:")
+            and point.endswith(":finish")
+            and not point.startswith("stage:0:")
+            and threading.current_thread().name == "serve-client-0"
+        ):
+            fired[0] += 1
+            raise InjectedFailure(f"chaos: worker killed at {point}")
+        return orig(point)
+
+    dist.properties.set("fault_tolerant_execution", True)
+    try:
+        for sql in mix:  # the spooled execution's own programs
+            dist.execute(sql)
+        before = recovery()
+        FAILURE_INJECTOR.maybe_fail = kill_once
+        try:
+            answered, shed, errors = _serve(dispatcher, q3_first, oracle)
+        finally:
+            FAILURE_INJECTOR.maybe_fail = orig
+        after = recovery()
+    finally:
+        dist.properties.set("fault_tolerant_execution", False)
+    assert fired[0] == 1
+    assert not errors, errors
+    assert answered + shed == 12 and answered >= 1
+    assert after["retry"] - before["retry"] >= 1
+    assert after["fail"] == before["fail"]
+    assert after["spooled"] > before["spooled"]
+    assert after["spool_read"] > before["spool_read"]
+    assert after["replans"] == before["replans"]

@@ -23,18 +23,19 @@ def clean_injector():
 
 
 @pytest.fixture(autouse=True)
-def no_spool_leaks():
+def no_spool_leaks(tmp_path, monkeypatch):
     """Every query-owned spool directory must be gone when the query ends
     (SpoolManager.close): chaos tests that leak orphan .npz spools fail
-    HERE, not as unbounded /tmp growth in a long-lived deployment."""
+    HERE, not as unbounded /tmp growth in a long-lived deployment.  The
+    spools are made under this test's own temporary root, so that another
+    xdist worker's live spool is never taken for a leak."""
     import glob
     import os
     import tempfile
 
-    pat = os.path.join(tempfile.gettempdir(), "trino_tpu_spool_*")
-    before = set(glob.glob(pat))
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
     yield
-    leaked = set(glob.glob(pat)) - before
+    leaked = glob.glob(os.path.join(str(tmp_path), "trino_tpu_spool_*"))
     assert not leaked, f"spool directories leaked: {sorted(leaked)}"
 
 

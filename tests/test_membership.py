@@ -549,6 +549,35 @@ def test_grow_joins_next_query_mesh(cluster3):
     assert len(mh.last_plan_workers) == 3 and mh.last_replans == 0
 
 
+def test_shrink_grow_round_trip_leaves_the_warm_path_clean(cluster3):
+    """Membership churn must not dirty the warm path: after a worker is
+    killed (the query re-plans at W-1) and a replacement has joined (the
+    next query plans at W again), a warm repeat at the restored W re-plans
+    nothing and retraces nothing, and every state answered the same rows."""
+    from trino_tpu.parallel.spmd import TRACE_CACHE
+    from trino_tpu.runtime.retry import BREAKERS
+    from trino_tpu.server.worker import WorkerServer
+
+    mh = _mh(cluster3)
+    assert sorted(mh.execute(SQL).rows) == WANT  # baseline at W
+    cluster3[2].shutdown()
+    mh._worker_health.clear()  # fresh probe evidence, no TTL'd verdicts
+    BREAKERS.reset()
+    assert sorted(mh.execute(SQL).rows) == WANT  # shrink
+    assert len(mh.last_plan_workers) == 2 and mh.last_replans >= 1
+    replacement = WorkerServer(port=0).start()
+    try:
+        mh.add_worker(replacement.url)
+        assert sorted(mh.execute(SQL).rows) == WANT  # grow
+        assert len(mh.last_plan_workers) == 3 and mh.last_replans == 0
+        retraces = TRACE_CACHE.stats().get("retraces", 0)
+        assert sorted(mh.execute(SQL).rows) == WANT  # warm repeat at W
+        assert len(mh.last_plan_workers) == 3 and mh.last_replans == 0
+        assert TRACE_CACHE.stats().get("retraces", 0) == retraces
+    finally:
+        replacement.shutdown()
+
+
 def test_single_refused_submit_does_not_evict_live_worker(cluster3):
     """One ECONNREFUSED on submit (restart blip, backlog overflow) against
     a worker whose probe still answers must NOT sticky-evict it: another

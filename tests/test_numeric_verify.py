@@ -250,6 +250,29 @@ class TestLicensing:
         # the license is a REAL i64 proof
         assert all(a.sum_bound < (1 << 63) for a in sums)
 
+    def test_q1_traces_only_the_proven_sum_path(self):
+        """The licence reaches the kernels: a Q1 that traces afresh (its
+        date is no other test's, and a literal is a jit key) selects the
+        proven single-plane i64 sum for its decimal sums and never the
+        runtime fits probe.  Path selection is counted while a step
+        traces (ops/aggregation._sum128)."""
+        from trino_tpu.runtime.runner import LocalQueryRunner
+        from trino_tpu.telemetry.metrics import decimal_fastpath_counter
+
+        counter = decimal_fastpath_counter()
+        before = {p: counter.value((p,)) for p in ("proven", "runtime_check")}
+        r = LocalQueryRunner(catalog="tpch", schema="tiny", target_splits=2)
+        rows = r.execute(
+            "select l_returnflag, l_linestatus, sum(l_quantity), "
+            "sum(l_extendedprice * (1 - l_discount)), "
+            "sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) "
+            "from lineitem where l_shipdate <= date '1998-08-17' "
+            "group by l_returnflag, l_linestatus"
+        ).rows
+        assert len(rows) == 4
+        assert counter.value(("proven",)) > before["proven"]
+        assert counter.value(("runtime_check",)) == before["runtime_check"]
+
     def test_row_upper_bound_sound_shapes(self):
         from trino_tpu.connectors.tpch.queries import QUERIES
         from trino_tpu.runtime.runner import LocalQueryRunner

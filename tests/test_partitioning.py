@@ -437,11 +437,10 @@ class TestLintBudget:
         assert errs and "suppression budget exceeded" in errs[0]
 
 
-# -- mesh execution (slow ring: excluded from tier-1) -------------------------
+# -- co-located execution (tier-1: tiny data, two joins) ----------------------
 
 
-@pytest.mark.slow
-class TestMeshExecution:
+class TestColocatedExecution:
     def test_colocated_join_zero_repartitions(self, dist, local):
         sql = (
             "select count(*), sum(l_quantity) from lineitem join orders "
@@ -452,6 +451,42 @@ class TestMeshExecution:
         assert c.get("repartition_collective", 0) == 0
         assert c.get("exchange_elided", 0) >= 2
 
+    def test_varchar_key_colocated_join_via_global_dictionary(self, local):
+        """End-to-end claim of the global dictionary service: a varchar
+        business key under a layout co-locates and elides exchanges like
+        an integer key (codes hash-mirror under the shared versioned
+        assignment), and the dictionary-backed `unique` entry licenses
+        the join's capacity — zero repartition collectives, zero runtime
+        sizing, rows identical to local."""
+        from trino_tpu.parallel import DistributedQueryRunner
+
+        d = DistributedQueryRunner(n_workers=8, catalog="tpcds")
+        d.execute(
+            "set session table_layouts = 'tpcds.tiny.customer:c_customer_id:8'"
+        )
+        sql = (
+            "select count(*) from tpcds.tiny.customer c1 "
+            "join tpcds.tiny.customer c2 "
+            "on c1.c_customer_id = c2.c_customer_id"
+        )
+        dr = d.execute(sql).rows
+        lr = local.execute(sql).rows
+        assert dr == lr
+        c = d.last_mesh_profile.counters
+        assert c.get("repartition_collective", 0) == 0
+        assert c.get("exchange_elided", 0) > 0
+        assert c.get("join_capacity_proven", 0) >= 1
+        # the lift is session-gated: turned off, plans fall back to
+        # producer-local codes — more exchanges, same rows
+        d.execute("set session global_dictionaries = false")
+        assert d.execute(sql).rows == lr
+
+
+# -- mesh execution (slow ring: excluded from tier-1) -------------------------
+
+
+@pytest.mark.slow
+class TestMeshExecution:
     @pytest.mark.parametrize("qid", [3, 7, 10])
     def test_tpch_copartitioned_matches_local(self, dist, local, qid):
         from tests.test_e2e import assert_rows_match
@@ -494,36 +529,6 @@ class TestMeshExecution:
         dr = d.execute(sql).rows
         lr = local.execute(sql).rows
         assert dr == lr
-
-    def test_varchar_key_colocated_join_via_global_dictionary(self, local):
-        """End-to-end claim of the global dictionary service: a varchar
-        business key under a layout co-locates and elides exchanges like
-        an integer key (codes hash-mirror under the shared versioned
-        assignment), and the dictionary-backed `unique` entry licenses
-        the join's capacity — zero repartition collectives, zero runtime
-        sizing, rows identical to local."""
-        from trino_tpu.parallel import DistributedQueryRunner
-
-        d = DistributedQueryRunner(n_workers=8, catalog="tpcds")
-        d.execute(
-            "set session table_layouts = 'tpcds.tiny.customer:c_customer_id:8'"
-        )
-        sql = (
-            "select count(*) from tpcds.tiny.customer c1 "
-            "join tpcds.tiny.customer c2 "
-            "on c1.c_customer_id = c2.c_customer_id"
-        )
-        dr = d.execute(sql).rows
-        lr = local.execute(sql).rows
-        assert dr == lr
-        c = d.last_mesh_profile.counters
-        assert c.get("repartition_collective", 0) == 0
-        assert c.get("exchange_elided", 0) > 0
-        assert c.get("join_capacity_proven", 0) >= 1
-        # the lift is session-gated: turned off, plans fall back to
-        # producer-local codes — more exchanges, same rows
-        d.execute("set session global_dictionaries = false")
-        assert d.execute(sql).rows == lr
 
     def test_residual_semi_with_misaligned_bucketized_scan(self, local):
         """A side bucketized on OTHER columns than the semi key (lineitem

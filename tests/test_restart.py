@@ -613,44 +613,6 @@ def test_coordinator_adopts_preattached_executor_lock(tmp_path):
         srv.shutdown()
 
 
-def test_compare_bench_restart_phase_error_fails_gate():
-    """A failed restart phase must FAIL the gate even when stale green
-    numbers from a previous run sit next to the error (BENCH_EXTRA
-    deep-merges)."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "compare_bench",
-        os.path.join(os.path.dirname(__file__), "..", "tools",
-                     "compare_bench.py"),
-    )
-    cb = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(cb)
-    healthy = {
-        "error": None, "wall_s": 1.0, "compile_s": 0.5,
-        "compile_events": 1, "query_events": 1,
-    }
-    prewarmed = {
-        **healthy, "query_events": 0, "prewarm_state": "WARM",
-    }
-    assert cb.check_restart("tiny", {
-        "cold": healthy, "persistent": healthy, "prewarmed": prewarmed,
-    }) == []
-    # a timed-out phase with stale siblings: one violation, no ghosts
-    stale = {**prewarmed, "error": "timed out after 600s"}
-    got = cb.check_restart("tiny", {
-        "cold": healthy, "persistent": healthy, "prewarmed": stale,
-    })
-    assert len(got) == 1 and "errored" in got[0]
-    # and a nonzero prewarmed query_events still drifts
-    got = cb.check_restart("tiny", {
-        "cold": healthy, "persistent": healthy,
-        "prewarmed": {**prewarmed, "query_events": 2},
-    })
-    assert any("query_events" in v for v in got)
-
-
 def test_coordinator_register_requires_hmac_when_secret_set(monkeypatch):
     from trino_tpu.parallel.remote import MultiHostQueryRunner
     from trino_tpu.server.coordinator import CoordinatorServer

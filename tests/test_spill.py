@@ -492,11 +492,14 @@ def test_mesh_wave_join_matches_local(join_oracle):
     d = DistributedQueryRunner(n_workers=8, schema="tiny")
     waves0 = memory_waves_counter().value(("join",))
     spill0 = spill_bytes_counter().value()
+    rev0 = memory_revocations_counter().value()
     base = sorted(d.execute(JOIN_SQL).rows)
     assert base == join_oracle
-    # unconstrained mesh execution is wave/spill free (zero-cost-when-idle)
+    # unconstrained mesh execution is wave/spill/revocation free
+    # (zero-cost-when-idle)
     assert memory_waves_counter().value(("join",)) == waves0
     assert spill_bytes_counter().value() == spill0
+    assert memory_revocations_counter().value() == rev0
     d.properties.set("query_max_memory", 250_000)
     d.properties.set("memory_wave_partitions", 2)
     rows = sorted(d.execute(JOIN_SQL).rows)

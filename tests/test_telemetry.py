@@ -12,15 +12,6 @@ import pytest
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def _compare_bench():
-    sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
-    try:
-        import compare_bench
-    finally:
-        sys.path.pop(0)
-    return compare_bench
-
 from trino_tpu.parallel import DistributedQueryRunner
 from trino_tpu.runtime.query_stats import MESH_PHASES, FragmentStats, MeshProfile
 from trino_tpu.runtime.runner import LocalQueryRunner
@@ -325,7 +316,7 @@ def test_residency_holds_with_tracing_enabled(dist):
 
 
 # -- MeshProfile / FragmentStats JSON contract (the EXPLAIN ANALYZE and
-# BENCH_EXTRA.json schema, asserted instead of documented) --------------------
+# profile-artifact schema, asserted instead of documented) --------------------
 
 FRAGMENT_JSON_KEYS = {
     "fragment", "kind", "wall_s", "phases_ms",
@@ -394,343 +385,6 @@ def test_phase_totals_rollup():
     totals = prof.phase_totals()
     assert totals["compute"] == pytest.approx(0.005)
     assert totals["transfer"] == pytest.approx(0.001)
-
-
-# -- counter regression gate (tools/compare_bench.py) -------------------------
-
-
-def _clean_drift():
-    return {
-        "schema": "sf1",
-        "query": "q3",
-        "baseline": {"ref": "PR3", "mesh_warm_s": 5.985,
-                     "local_warm_s": 3.6998, "ratio": 1.618},
-        "current": {"mesh_warm_s": 3.6, "local_warm_s": 1.45,
-                    "ratio": 2.5, "matches_local": True,
-                    "profile_ref": {"key": "k"}},
-        "mesh_wall_delta_s": -2.4,
-        "local_wall_delta_s": -2.25,
-        "ratio_factors": {"mesh": 0.6, "local_inverse": 2.55},
-        "attribution": {"dominant_phase": "transfer",
-                        "dominant_fragment": 1, "sums_to_wall": True,
-                        "phases_s": {}},
-        "null_diff": {"query": "q6", "pass": True, "sums_to_wall": True,
-                      "wall_delta_s": 0.001, "max_phase_delta_s": 0.002},
-    }
-
-
-def _clean_extra():
-    return {
-        "membership": _clean_membership(),
-        "serve": _clean_serve(),
-        "drift": _clean_drift(),
-        "mesh": {
-            "sf1": {
-                "error": None,
-                "profile": {
-                    "trace_cache": {"hits": 5, "misses": 0, "retraces": 0},
-                    "counters": {"scan_cache_hit": 1},
-                },
-                "q3_counters": {
-                    "repartition_collective": 0,
-                    "join_capacity_sync": 0,
-                    "join_speculative_retry": 0,
-                },
-                "pressure": _clean_pressure(),
-                "dictionary": _clean_dictionary(),
-                "decisions": _clean_decisions(),
-            }
-        },
-    }
-
-
-def _clean_decisions():
-    def d(did, kind, choice, xbytes=0):
-        return {
-            "decision_id": did, "kind": kind, "site": "join@f1",
-            "choice": choice, "alternative": "other", "inputs": {},
-            "audit_seq": 0, "measured": {"fragment_wall_s": 0.01},
-            "bytes_by": {"all_to_all/repartition": xbytes} if xbytes else {},
-            "exchange_bytes": xbytes, "fragments": [1],
-            "hindsight": "vindicated", "hindsight_detail": "",
-        }
-
-    return {
-        "q3": {
-            "query_id": "query_3",
-            "ledger": {
-                "query_id": "query_3",
-                "decisions": [
-                    d("d000", "join_distribution", "partitioned", xbytes=4096),
-                    d("d001", "join_capacity", "licensed"),
-                ],
-                "unattributed_bytes_by": {},
-                "finalized": True,
-            },
-            "collective_bytes_by": {"all_to_all/repartition": 4096},
-        }
-    }
-
-
-def _clean_dictionary():
-    return {
-        "exchange_elided": 2,
-        "repartition_collective": 0,
-        "join_capacity_proven": 1,
-        "matches_local": True,
-        "service": {"keys": 4, "versions": 4, "unique": 1},
-    }
-
-
-def _clean_pressure():
-    return {
-        "unconstrained": {
-            "memory_waves_total": 0,
-            "spill_bytes_total": 0,
-            "memory_revocations_total": 0,
-        },
-        "pool_limit_bytes": 1 << 20,
-        "local": {"rows_match": True, "waves": 4, "spill_bytes": 100},
-        "mesh": {"rows_match": True, "waves": 4, "spill_bytes": 100},
-    }
-
-
-def _clean_serve():
-    phase = {
-        "clients": 8, "queries_total": 24, "qps": 20.0,
-        "p50_s": 0.3, "p95_s": 0.4, "p99_s": 0.5,
-        "shed_total": 0, "rows_match": True,
-    }
-    return {
-        "run_error": None,
-        "error": None,
-        "schema": "tiny",
-        "local": dict(phase),
-        "mesh": {**phase, "warm_compile_events": 0},
-        "chaos": {
-            **phase,
-            "query": "Q18",
-            "injected_kills": 1,
-            "task_retries": {"retry": 1, "replan": 0, "fail": 0},
-            "spooled_fragments": 12,
-            "spool_hits": 9,
-            "full_replans": 0,
-            "p99_degradation_ratio": 1.4,
-        },
-    }
-
-
-def _clean_membership():
-    return {
-        "workers": 3,
-        "baseline": {"rows_match": True, "plan_workers": 3, "replans": 0},
-        "shrink": {"rows_match": True, "plan_workers": 2, "replans": 1},
-        "grow": {"rows_match": True, "plan_workers": 3, "replans": 0},
-        "post_roundtrip_warm": {
-            "rows_match": True, "plan_workers": 3, "replans": 0, "retraces": 0,
-        },
-        "run_error": None,
-    }
-
-
-def test_compare_bench_clean():
-    violations, skipped = _compare_bench().check_extra(_clean_extra())
-    assert violations == [] and skipped == []
-
-
-def test_compare_bench_pressure_gate():
-    """The PR 12 degradation gate: unconstrained runs must be wave/spill
-    free, constrained runs must have actually degraded (k>1 waves, SPI
-    spill, rows == oracle)."""
-    check_extra = _compare_bench().check_extra
-    bad = _clean_extra()
-    p = bad["mesh"]["sf1"]["pressure"]
-    p["unconstrained"]["memory_waves_total"] = 3  # idle must be free
-    p["local"]["waves"] = 1  # k>1 required
-    p["mesh"]["rows_match"] = False  # degraded rows must equal oracle
-    p["mesh"]["spill_bytes"] = 0  # waves must spill through the SPI
-    violations, _ = check_extra(bad)
-    text = "\n".join(violations)
-    assert "pressure.unconstrained.memory_waves_total" in text
-    assert "pressure.local.waves" in text
-    assert "pressure.mesh.rows_match" in text
-    assert "pressure.mesh.spill_bytes" in text
-    # a missing pressure section is reported as skipped, not violated
-    missing = _clean_extra()
-    del missing["mesh"]["sf1"]["pressure"]
-    violations, skipped = check_extra(missing)
-    assert violations == []
-    assert any("no pressure section" in s for s in skipped)
-
-
-def test_compare_bench_dictionary_gate():
-    """The PR 18 global-dictionary gate: a varchar-keyed distributed join
-    under a layout must co-locate through the shared code assignment
-    (elided exchanges, zero repartition collectives), answer the local
-    oracle, and carry a capacity-proven join."""
-    check_extra = _compare_bench().check_extra
-    bad = _clean_extra()
-    d = bad["mesh"]["sf1"]["dictionary"]
-    d["exchange_elided"] = 0
-    d["repartition_collective"] = 2
-    d["join_capacity_proven"] = 0
-    d["matches_local"] = False
-    violations, _ = check_extra(bad)
-    text = "\n".join(violations)
-    assert "dictionary.exchange_elided" in text
-    assert "dictionary.repartition_collective" in text
-    assert "dictionary.join_capacity_proven" in text
-    assert "dictionary.matches_local" in text
-    # a missing dictionary section is reported as skipped, not violated
-    missing = _clean_extra()
-    del missing["mesh"]["sf1"]["dictionary"]
-    violations, skipped = check_extra(missing)
-    assert violations == []
-    assert any("no dictionary section" in s for s in skipped)
-    # an errored probe is skipped too
-    errored = _clean_extra()
-    errored["mesh"]["sf1"]["dictionary"] = {"error": "boom"}
-    violations, skipped = check_extra(errored)
-    assert violations == []
-    assert any("dictionary" in s for s in skipped)
-
-
-def test_compare_bench_serve_gate():
-    """The PR 13 serving gate: concurrent statements must answer the
-    serial oracle (or shed), and warm mesh serving must compile NOTHING
-    above the warm-up watermark (shared trace cache)."""
-    check_extra = _compare_bench().check_extra
-    bad = _clean_extra()
-    bad["serve"]["local"]["rows_match"] = False
-    bad["serve"]["mesh"]["warm_compile_events"] = 2
-    bad["serve"]["mesh"]["clients"] = 1
-    violations, _ = check_extra(bad)
-    text = "\n".join(violations)
-    assert "serve.local.rows_match" in text
-    assert "serve.mesh.warm_compile_events" in text
-    assert "serve.mesh.clients" in text
-    # a missing serve section is reported as skipped, not violated
-    missing = _clean_extra()
-    del missing["serve"]
-    violations, skipped = check_extra(missing)
-    assert violations == []
-    assert any("no serve section" in s for s in skipped)
-    # a serve bench that could not run is skipped too
-    errored = _clean_extra()
-    errored["serve"] = {"run_error": "boom"}
-    violations, skipped = check_extra(errored)
-    assert violations == []
-    assert any("serve: bench errored" in s for s in skipped)
-
-
-def test_compare_bench_chaos_gate():
-    """The fault-tolerance chaos gate: a worker killed mid-Q18 under
-    concurrent serve load must classify as a task RETRY (never fail),
-    resume from spooled intermediates, and never re-plan the mesh."""
-    check_extra = _compare_bench().check_extra
-    bad = _clean_extra()
-    bad["serve"]["chaos"].update(
-        rows_match=False, injected_kills=0, spool_hits=0, full_replans=2,
-        task_retries={"retry": 0, "replan": 0, "fail": 3}, clients=1,
-    )
-    violations, _ = check_extra(bad)
-    text = "\n".join(violations)
-    assert "serve.chaos.rows_match" in text
-    assert "serve.chaos.clients" in text
-    assert "serve.chaos.injected_kills" in text
-    assert "serve.chaos.task_retries.retry" in text
-    assert "serve.chaos.task_retries.fail" in text
-    assert "serve.chaos.spool_hits" in text
-    assert "serve.chaos.full_replans" in text
-    # a recorded serve section WITHOUT chaos is skipped, not violated
-    # (older BENCH_EXTRA recordings stay green until re-run)
-    missing = _clean_extra()
-    del missing["serve"]["chaos"]
-    violations, skipped = check_extra(missing)
-    assert violations == []
-    assert any("serve.chaos" in s for s in skipped)
-
-
-def test_compare_bench_flags_drift():
-    check_extra = _compare_bench().check_extra
-    bad = _clean_extra()
-    bad["mesh"]["sf1"]["profile"]["trace_cache"]["retraces"] = 2
-    bad["mesh"]["sf1"]["profile"]["counters"]["host_restack"] = 1
-    bad["mesh"]["sf1"]["q3_counters"]["join_capacity_sync"] = 3
-    violations, _ = check_extra(bad)
-    assert len(violations) == 3
-    assert any("retraces" in v for v in violations)
-    assert any("host_restack" in v for v in violations)
-    assert any("join_capacity_sync" in v for v in violations)
-
-
-def test_compare_bench_skips_errored_sections():
-    extra = {"mesh": {"sf1": {"error": "mesh child rc=1"}}}
-    violations, skipped = _compare_bench().check_extra(extra)
-    # the errored mesh section AND the absent membership section are both
-    # reported as skips, never as violations
-    assert violations == []
-    assert any("mesh child rc=1" in s for s in skipped)
-    assert any("membership" in s for s in skipped)
-
-
-def test_compare_bench_membership_gate():
-    """The shrink->grow round-trip gate (PR 7): every attempt must match
-    local, the shrink must have re-planned, the grow must restore W, and
-    the post-round-trip warm repeat must be clean."""
-    check_extra = _compare_bench().check_extra
-    bad = {"membership": _clean_membership()}
-    bad["membership"]["shrink"]["replans"] = 0
-    bad["membership"]["grow"]["plan_workers"] = 2
-    bad["membership"]["post_roundtrip_warm"]["retraces"] = 1
-    bad["membership"]["baseline"]["rows_match"] = False
-    violations, _ = check_extra(bad)
-    assert any("shrink.replans" in v for v in violations)
-    assert any("grow.plan_workers" in v for v in violations)
-    assert any("retraces" in v for v in violations)
-    assert any("baseline.rows_match" in v for v in violations)
-    # an errored membership bench is a skip, not a drift
-    violations, skipped = check_extra(
-        {"membership": {"run_error": "no workers"}}
-    )
-    assert violations == [] and any("no workers" in s for s in skipped)
-    # a MISSING attempt section is flagged exactly once (no follow-up
-    # counter violations computed over an empty dict)
-    partial = {"membership": _clean_membership()}
-    del partial["membership"]["shrink"]
-    violations, _ = check_extra(partial)
-    assert [v for v in violations if "shrink" in v] == [
-        "membership.shrink missing (round trip incomplete — re-run "
-        "tools/membership_bench.py)"
-    ]
-
-
-def test_compare_bench_snapshot_gate():
-    check_snapshot = _compare_bench().check_snapshot
-    ok = {
-        'trino_tpu_mesh_events_total{counter="host_restack"}': 0,
-        # cold sizing passes may fire this in a process-lifetime snapshot
-        'trino_tpu_mesh_events_total{counter="join_capacity_sync"}': 2,
-    }
-    bad = {'trino_tpu_mesh_events_total{counter="host_restack"}': 1}
-    assert check_snapshot(ok) == []
-    assert len(check_snapshot(bad)) == 1
-
-
-def test_compare_bench_gates_checked_in_file(tmp_path, capsys):
-    """The gate CI runs (`main`, through the file) passes a recorded side
-    file — written here from the sections the tests above build: no bench
-    capture is checked in — and with NO file recorded it reports that and
-    exits 0 instead of failing the pipeline."""
-    import json
-
-    main = _compare_bench().main
-    extra = tmp_path / "BENCH_EXTRA.json"
-    extra.write_text(json.dumps(_clean_extra()))
-    assert main(["--extra", str(extra)]) == 0
-    assert "all counter invariants hold" in capsys.readouterr().out
-    assert main(["--extra", str(tmp_path / "absent.json")]) == 0
-    assert "nothing recorded" in capsys.readouterr().out
 
 
 # -- compile observatory (PR 6: trace-cache misses as structured events) ------

@@ -159,7 +159,25 @@ def assert_same_rows(actual, expected):
             )
 
 
-@pytest.mark.parametrize("qid", sorted(QUERIES))
+#: engine gaps, one per query (tests/KNOWN_FAILURES.md): strict, so the
+#: case fails the day the gap closes and the entry has to go
+KNOWN_GAPS = {
+    51: pytest.mark.xfail(
+        strict=True,
+        raises=NotImplementedError,
+        reason="window min/max over a long-decimal input column "
+        "(trino_tpu/ops/window.py)",
+    ),
+}
+
+
+@pytest.mark.parametrize(
+    "qid",
+    [
+        pytest.param(q, marks=KNOWN_GAPS[q]) if q in KNOWN_GAPS else q
+        for q in sorted(QUERIES)
+    ],
+)
 def test_tpcds_query_vs_oracle(runner, qid):
     """Every workload query executes end-to-end AND matches the independent
     sqlite3 oracle (reference style: H2QueryRunner assertQuery).

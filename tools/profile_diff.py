@@ -3,15 +3,15 @@
 
 The observatory's second half: `telemetry/profile_store` makes profiles
 persistent and comparable; this tool makes the comparison.  Given two
-archived artifacts of the same statement (or two BENCH_EXTRA mesh
-sections), decompose the wall delta into compile(trace) vs compute vs
+archived artifacts of the same statement, decompose the wall delta into compile(trace) vs compute vs
 collective vs transfer vs gate-wait vs other per fragment, diff the
 per-collective byte attribution by (kind, purpose) and the counter
 vocabulary, and name the DOMINANT (phase, fragment) — so a "Q3 regressed
 1.62x -> 4.46x" ticket arrives with the phase and fragment that moved,
 not a wall and a shrug.
 
-Conservation contract (gated by tests and `compare_bench check_drift`):
+Conservation contract (held by tests/test_profile_store.py,
+TestProfileDiff and TestRunnerIntegration):
 each artifact's phases sum to its wall EXACTLY (the profile store's
 signed-`unattributed` construction), so the per-phase deltas here sum to
 the measured wall delta — attribution is conservative and complete, never
@@ -21,8 +21,6 @@ Usage:
   python tools/profile_diff.py A.json B.json              # two artifacts
   python tools/profile_diff.py A.json B.json --threshold 0.1
       # exit 2 when |wall delta| exceeds 10% of A's wall (the drift gate)
-  python tools/profile_diff.py --bench-extra OLD.json NEW.json \\
-      --schema sf1 --query q3                             # mesh sections
 
 Exit status: 0 = inside threshold, 2 = drift above threshold, 1 = bad
 input (missing files, incomparable statements).
@@ -180,100 +178,52 @@ def null_diff_ok(report: dict, rel_tol: float = NULL_DIFF_REL_TOL) -> bool:
     )
 
 
-def diff_mesh_sections(old: dict, new: dict, query: str = "q3") -> dict:
-    """Drift report between two BENCH_EXTRA mesh schema sections for one
-    benched query (wall-level: the sections record walls and counters; the
-    per-phase decomposition comes from the CURRENT side's archived
-    artifact when the caller has one — tools/drift_bench.py wires both)."""
-    wk = f"{query}_mesh8_warm_s"
-    lk = f"{query}_local_warm_s"
-    for side, sec in (("old", old), ("new", new)):
-        if wk not in sec:
-            raise ValueError(f"{side} section has no {wk}")
-    mesh_delta = new[wk] - old[wk]
-    out = {
-        "query": query,
-        "mesh_warm_s": {"old": old[wk], "new": new[wk]},
-        "mesh_wall_delta_s": round(mesh_delta, 4),
-        "local_warm_s": {"old": old.get(lk), "new": new.get(lk)},
-        "ratio": {
-            "old": round(old[wk] / old[lk], 3) if old.get(lk) else None,
-            "new": round(new[wk] / new[lk], 3) if new.get(lk) else None,
-        },
-    }
-    ck = f"{query}_counters"
-    if isinstance(old.get(ck), dict) and isinstance(new.get(ck), dict):
-        out["counters_delta"] = {
-            k: new[ck].get(k, 0) - old[ck].get(k, 0)
-            for k in sorted(set(old[ck]) | set(new[ck]))
-            if new[ck].get(k, 0) != old[ck].get(k, 0)
-        }
-    bk = f"{query}_collective_bytes_by"
-    if isinstance(old.get(bk), dict) and isinstance(new.get(bk), dict):
-        out["collective_bytes_delta"] = {
-            k: new[bk].get(k, 0) - old[bk].get(k, 0)
-            for k in sorted(set(old[bk]) | set(new[bk]))
-            if new[bk].get(k, 0) != old[bk].get(k, 0)
-        }
-    return out
-
-
 def render_text(report: dict) -> str:
     lines = []
-    if "phases_delta_s" in report:
-        a, b = report["a"], report["b"]
+    a, b = report["a"], report["b"]
+    lines.append(
+        f"profile_diff: {a['query_id']} ({a['wall_s']:.4f}s) -> "
+        f"{b['query_id']} ({b['wall_s']:.4f}s): "
+        f"wall {report['wall_delta_s']:+.4f}s "
+        f"(x{report['wall_ratio']})"
+    )
+    if not report["comparable"]:
         lines.append(
-            f"profile_diff: {a['query_id']} ({a['wall_s']:.4f}s) -> "
-            f"{b['query_id']} ({b['wall_s']:.4f}s): "
-            f"wall {report['wall_delta_s']:+.4f}s "
-            f"(x{report['wall_ratio']})"
+            "  WARNING: different statements (sql_hash mismatch) — "
+            "deltas compare apples to oranges"
         )
-        if not report["comparable"]:
-            lines.append(
-                "  WARNING: different statements (sql_hash mismatch) — "
-                "deltas compare apples to oranges"
-            )
-        for k, v in sorted(
-            report["phases_delta_s"].items(), key=lambda kv: -abs(kv[1])
-        ):
-            if abs(v) >= 1e-6:
-                lines.append(f"  phase {k:<13} {v:+.4f}s")
+    for k, v in sorted(
+        report["phases_delta_s"].items(), key=lambda kv: -abs(kv[1])
+    ):
+        if abs(v) >= 1e-6:
+            lines.append(f"  phase {k:<13} {v:+.4f}s")
+    lines.append(
+        f"  conservation: phase deltas sum to wall delta: "
+        f"{report['sums_to_wall']}"
+    )
+    dom = report.get("dominant")
+    if dom:
         lines.append(
-            f"  conservation: phase deltas sum to wall delta: "
-            f"{report['sums_to_wall']}"
+            f"  dominant: fragment {dom['fragment']} [{dom['kind']}] "
+            f"{dom['phase']} {dom['delta_s']:+.4f}s"
         )
-        dom = report.get("dominant")
-        if dom:
-            lines.append(
-                f"  dominant: fragment {dom['fragment']} [{dom['kind']}] "
-                f"{dom['phase']} {dom['delta_s']:+.4f}s"
-            )
-        for k, v in (report.get("collective_bytes_delta") or {}).items():
-            lines.append(f"  collective {k:<24} {v:+d} bytes")
-        for k, v in (report.get("counters_delta") or {}).items():
-            lines.append(f"  counter {k:<20} {v:+d}")
-        if abs(report.get("gate_wait_delta_s", 0.0)) >= 1e-6:
-            lines.append(
-                f"  gate_wait delta {report['gate_wait_delta_s']:+.4f}s"
-            )
-    else:
-        lines.append(json.dumps(report, indent=2, sort_keys=True))
+    for k, v in (report.get("collective_bytes_delta") or {}).items():
+        lines.append(f"  collective {k:<24} {v:+d} bytes")
+    for k, v in (report.get("counters_delta") or {}).items():
+        lines.append(f"  counter {k:<20} {v:+d}")
+    if abs(report.get("gate_wait_delta_s", 0.0)) >= 1e-6:
+        lines.append(
+            f"  gate_wait delta {report['gate_wait_delta_s']:+.4f}s"
+        )
     return "\n".join(lines)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="diff two archived query-profile artifacts "
-        "(or two BENCH_EXTRA mesh sections)"
+        description="diff two archived query-profile artifacts"
     )
-    ap.add_argument("a", help="baseline artifact JSON (or BENCH_EXTRA)")
-    ap.add_argument("b", help="current artifact JSON (or BENCH_EXTRA)")
-    ap.add_argument(
-        "--bench-extra", action="store_true",
-        help="treat A/B as BENCH_EXTRA files; diff mesh sections",
-    )
-    ap.add_argument("--schema", default="sf1", help="mesh section schema")
-    ap.add_argument("--query", default="q3", help="benched query (q1/q3/q6)")
+    ap.add_argument("a", help="baseline artifact JSON")
+    ap.add_argument("b", help="current artifact JSON")
     ap.add_argument(
         "--threshold", type=float, default=0.10,
         help="relative wall-drift threshold: exit 2 when |delta| exceeds "
@@ -290,26 +240,14 @@ def main(argv=None) -> int:
         print(f"profile_diff: cannot read inputs: {e}")
         return 1
     try:
-        if args.bench_extra:
-            old = a.get("mesh", {}).get(args.schema)
-            new = b.get("mesh", {}).get(args.schema)
-            if not isinstance(old, dict) or not isinstance(new, dict):
-                print(
-                    f"profile_diff: mesh.{args.schema} missing on one side"
-                )
-                return 1
-            report = diff_mesh_sections(old, new, args.query)
-            base = report["mesh_warm_s"]["old"]
-            delta = report["mesh_wall_delta_s"]
-        else:
-            report = diff_artifacts(a, b)
-            base = report["a"]["wall_s"]
-            delta = report["wall_delta_s"]
+        report = diff_artifacts(a, b)
     except ValueError as e:
         print(f"profile_diff: {e}")
         return 1
     print(json.dumps(report, indent=2, sort_keys=True) if args.json
           else render_text(report))
+    base = report["a"]["wall_s"]
+    delta = report["wall_delta_s"]
     if base > 0 and abs(delta) > args.threshold * base:
         print(
             f"profile_diff: DRIFT {delta:+.4f}s exceeds "

@@ -127,7 +127,7 @@ def configure_persistent_cache(
     min_entry_size_bytes: int = -1,
 ) -> Optional[str]:
     """THE one rule for where compiled programs persist; every entry point
-    (chip_smoke.py, bench.py, the CLI/server start-up, tests/conftest.py,
+    (chip_smoke.py, benchmark/, the CLI/server start-up, tests/conftest.py,
     `compile-cache.*` config installs) goes through here and nothing else
     touches `jax_compilation_cache_dir`:
 

@@ -30,9 +30,9 @@ tests/test_spill.py):
   4. **kill** — the LowMemoryKiller remains the last resort, its
      largest-victim choice unchanged (``trino_tpu_memory_kills_total``).
 
-Zero-cost-when-idle: none of this engages without a budget — the
-compare_bench gate asserts every unconstrained warm benched query records
-zero waves, zero spill, zero revocations.
+Zero-cost-when-idle: none of this engages without a budget —
+tests/test_spill.py::test_mesh_wave_join_matches_local asserts that an
+unconstrained run records zero waves, zero spill, zero revocations.
 """
 
 from __future__ import annotations

@@ -300,8 +300,8 @@ class MetricsRegistry:
     # -- export ---------------------------------------------------------------
 
     def snapshot(self) -> dict:
-        """{metric name (+series suffix/labels): value} — the flat form
-        bench.py records into BENCH_EXTRA.json and compare_bench.py diffs."""
+        """{metric name (+series suffix/labels): value} — a flat form of
+        the registry; since PR 32 only tests/test_telemetry.py reads it."""
         out: dict = {}
         with self._lock:
             metrics = list(self._metrics.values())
@@ -388,8 +388,9 @@ MESH_COUNTER_NAMES = (
 
 
 #: wave-capable operator vocabulary for trino_tpu_memory_waves_total,
-#: pre-registered so the compare_bench zero-when-unconstrained gate reads
-#: real zeros, not absent series
+#: pre-registered so a scrape and the zero-when-unconstrained test
+#: (tests/test_spill.py::test_mesh_wave_join_matches_local) read real
+#: zeros, not absent series
 MEMORY_WAVE_OPERATORS = ("join", "aggregation", "window", "sort")
 
 
@@ -407,8 +408,10 @@ COLLECTIVE_VOCABULARY = (
 
 
 #: decimal-sum kernel path vocabulary (ops/aggregation._sum128 + the
-#: window frame sums), pre-registered so the zero-runtime-check gate in
-#: tools/compare_bench.py reads real zeros, not absent series
+#: window frame sums), pre-registered so a scrape and the
+#: zero-runtime-check test (tests/test_numeric_verify.py::
+#: test_q1_traces_only_the_proven_sum_path) read real zeros, not absent
+#: series
 DECIMAL_FASTPATHS = ("proven", "runtime_check", "limb")
 
 
@@ -431,8 +434,8 @@ AGGREGATION_PATHS = (
 #: parallel/runner._sized_expansion): proven = a capacity certificate
 #: licensed a fixed-capacity expand (no sizing gather, no overflow flag),
 #: runtime_check = the speculative/sizing fallback ran its runtime
-#: protocol.  Pre-registered so the compare_bench check_licenses gate
-#: reads real zeros, not absent series.
+#: protocol.  Pre-registered so a scrape and tests/test_capacity.py
+#: (TestMeshExecution) read real zeros, not absent series.
 JOIN_CAPACITY_OUTCOMES = ("proven", "runtime_check", "declined")
 
 
@@ -886,8 +889,9 @@ def aggregation_path_counter() -> Counter:
 
 def join_capacity_counter() -> Counter:
     """Join expand-capacity decisions, labeled outcome=proven|runtime_check.
-    A warm licensed workload bumps ONLY proven — compare_bench
-    check_licenses gates runtime_check == 0 over the benched warm runs."""
+    A warm licensed workload bumps ONLY proven — tests/test_capacity.py::
+    test_q3_runs_with_zero_runtime_sizing holds runtime_check unmoved by
+    warm Q3 on the mesh."""
     return REGISTRY.counter(_PREFIX + "join_capacity_total")
 
 
@@ -903,8 +907,8 @@ def collective_async_counter() -> Counter:
 
 def plan_decisions_counter() -> Counter:
     """Plan-decision ledger entries, labeled (kind, outcome, hindsight).
-    compare_bench check_decisions gates regret == 0 over the warm benched
-    set."""
+    No test holds regret == 0: warm statements at `tiny` carry regrets
+    (tests/test_decisions.py holds the ledger's completeness)."""
     return REGISTRY.counter(_PREFIX + "plan_decisions_total")
 
 

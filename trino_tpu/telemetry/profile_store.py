@@ -9,7 +9,7 @@ statement can be diffed, weeks apart, without re-measuring from memory.
 This engine had the opposite shape until now: every profile surface was
 last-query-only (`runner.last_mesh_profile`, a 64-query span ring), so the
 ROADMAP item-2 Q3 drift (1.62x -> 4.46x across seven PRs) could be SEEN in
-BENCH_EXTRA walls but not ATTRIBUTED — there was literally nothing to diff
+recorded walls but not ATTRIBUTED — there was literally nothing to diff
 against.  This module closes that:
 
   * `build_artifact` assembles ONE structured JSON artifact per completed
@@ -389,7 +389,7 @@ class ProfileStore:
 
     def refs(self) -> list:
         """[{key, query_id, sql_hash, path}] of ring artifacts, oldest
-        first (the bench BENCH_EXTRA `profile_artifacts` feed)."""
+        first (what `tools/profile_diff.py` is handed two of)."""
         with self._lock:
             return [
                 {

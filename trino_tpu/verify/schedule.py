@@ -44,8 +44,8 @@ Licensing preconditions (all statically checked; no license otherwise):
     then authorizes overlapping the freed dispatch.
 
 The executor bumps `collective_async_total` per licensed pre-dispatch;
-`tools/compare_bench.py check_licenses` gates the counter alongside the
-join-capacity counters.
+tests/test_capacity.py::test_async_predispatch_counts holds the counter
+beside the join-capacity counters.
 """
 
 from __future__ import annotations
