@@ -213,3 +213,101 @@ def test_streaming_folds_state():
     got = Driver(_batches(types, rows, chunk=5), [op]).rows()  # 20 batches > FOLD_EVERY
     df = pd.DataFrame(rows, columns=["k", "x"]).groupby("k")["x"].sum()
     assert sorted(got) == [[k, int(v)] for k, v in df.items()]
+
+
+# -- the range-positional path over many groups (`_range_step`, forms `runs`
+# and `sorted_runs`): a per-batch key domain above DENSE_SEGMENT_LIMIT slots
+
+
+def _range_feed(keys, shuffled: bool, nullable: bool, rows_per_batch=3000, nbatches=10):
+    """(types, device batches): batch b holds keys of [b * rows, (b + 1) *
+    rows) — two rows a key, every 11th value NULL, and every 7th first key
+    NULL where `nullable` — clustered and ascending, NULLS LAST, or shuffled
+    within the batch with the batches out of order too (so the fold's
+    concatenation of per-batch states is out of order as well)."""
+    rng = np.random.default_rng(33 + keys)
+    types = [T.BIGINT] * keys + [T.BIGINT, DEC]
+    batches = []
+    for b in range(nbatches):
+        rows = []
+        for i in range(rows_per_batch):
+            k = b * rows_per_batch // 2 + i // 2
+            key = [None if nullable and k % 7 == 0 else 1000 + k]
+            if keys == 2:
+                key.append(i % 2)
+            v = int(rng.integers(-500, 500))
+            rows.append(
+                key + [None if i % 11 == 0 else v, Decimal(v) / 100]
+            )
+        if shuffled:
+            rows = [rows[j] for j in rng.permutation(len(rows))]
+        else:
+            rows.sort(key=lambda r: r[0] is None)  # stable: NULL keys last
+        batches.append(batch_from_rows(types, rows).device_put())
+    if shuffled:
+        batches = [batches[j] for j in rng.permutation(nbatches)]
+    return types, batches
+
+
+def _range_specs(keys):
+    return [
+        AggSpec("sum", keys, T.BIGINT),
+        AggSpec("count", keys, T.BIGINT),
+        AggSpec("min", keys, T.BIGINT),
+        AggSpec("max", keys, T.BIGINT),
+        AggSpec("sum", keys + 1, T.DecimalType(38, 2)),
+        AggSpec("count_star", None, T.BIGINT),
+    ]
+
+
+def _partial_merge_final(types, batches, keys):
+    """Rows of partial (streaming, folds after FOLD_EVERY batches) ->
+    merge -> final, and the `agg_range` forms the steps took."""
+    from trino_tpu.ops.aggregation import _STEP_CACHE, _primitives
+
+    _STEP_CACHE.clear()
+    specs = _range_specs(keys)
+    partial = AggregationOperator(
+        list(range(keys)), specs, types, mode="partial", streaming=True
+    )
+    states = list(Driver(iter(batches), [partial]).run())
+    state_types = [c.type for c in states[0].columns]
+    state_specs, ch = [], keys
+    for s in specs:
+        state_specs.append(AggSpec(s.name, ch, s.out_type))
+        ch += len(_primitives(s))
+    merge = AggregationOperator(
+        list(range(keys)), state_specs, state_types, mode="merge"
+    )
+    merged = list(Driver(iter(states), [merge]).run())
+    final = AggregationOperator(
+        list(range(keys)), state_specs, state_types, mode="final"
+    )
+    rows = Driver(iter(merged), [final]).rows()
+    forms = {
+        part
+        for key, program in _STEP_CACHE.items() if key[0] == "range"
+        for part in program.path.split("+")
+    }
+    return sorted(rows, key=lambda r: [(v is None, v) for v in r[:keys]]), forms
+
+
+@pytest.mark.parametrize("keys", [1, 2], ids=["one_key", "two_keys"])
+@pytest.mark.parametrize("nullable", [False, True], ids=["not_null", "nullable_key"])
+@pytest.mark.parametrize("shuffled", [False, True], ids=["clustered", "shuffled"])
+def test_range_positional_many_groups_matches_sort_path(
+    monkeypatch, shuffled, nullable, keys
+):
+    types, batches = _range_feed(keys, shuffled, nullable)
+    got, forms = _partial_merge_final(types, batches, keys)
+    assert "positional" in forms and "scatter" not in forms, forms
+    assert ("sorted_runs" if shuffled else "runs") in forms, forms
+    if not (shuffled or nullable):
+        # (a batch's NULL group sorts last, so the fold's concatenation of
+        # a nullable key's states is out of order and is sorted, rightly)
+        assert "sorted_runs" not in forms, forms
+    monkeypatch.setattr(AggregationOperator, "_positional_try", lambda self, b: None)
+    want, sort_forms = _partial_merge_final(types, batches, keys)
+    assert not sort_forms
+    assert len(got) > 10000
+    assert got == want

@@ -1001,6 +1001,36 @@ def test_agg_path_is_replayed_per_execution(runner):
     assert total() - before >= len(with_path)
 
 
+@pytest.mark.parametrize(
+    "keys,form",
+    [("l_orderkey", "runs"), ("l_partkey, l_suppkey", "sorted_runs")],
+    ids=["clustered_key", "shuffled_keys"],
+)
+def test_many_group_form_rides_the_agg_range_launch(runner, keys, form):
+    """An `agg_range` launch over more than 2 048 slots says how it reduced
+    its groups: over the runs of the group code as the rows came (lineitem
+    is clustered on l_orderkey), or sorted into code order first — chosen
+    from the order `agg_key_stats` observed — and never by a scatter."""
+    from trino_tpu.telemetry.metrics import aggregation_path_counter
+
+    sql = f"select {keys}, sum(l_quantity) from lineitem group by {keys}"
+    runner.execute(sql)  # traces (or finds the programs cached)
+    c = aggregation_path_counter()
+    before = c.value((form,))
+    _, flat = _run_with_context(runner, sql)
+    paths = [
+        set(_attrs(s)["path"].split("+")) for s in flat
+        if s["name"] == "launch" and _attrs(s)["step"] == "agg_range"
+    ]
+    assert paths, "the statement takes the range-positional path"
+    for path in paths:
+        assert "positional" in path and "scatter" not in path, path
+        assert len(path & {"dense", "runs", "sorted_runs"}) == 1, path
+    took = [path for path in paths if form in path]
+    assert took, paths
+    assert c.value((form,)) - before >= len(took)
+
+
 def test_compaction_form_rides_the_compact_launch(runner):
     """Every `compact` launch says how it found its slots' source rows
     (`path=compact_sort`: a one-key sort, never a scatter), counted per

@@ -230,6 +230,52 @@ def test_compact_compiles_without_scatter(one_chip, cap, outc):
     assert seconds < 20, f"compiled for {seconds:.1f} s"
 
 
+@pytest.mark.parametrize(
+    "cap,out_cap,mode",
+    [(1 << 20, 1 << 18, "partial"), (1 << 21, 1 << 21, "merge")],
+    ids=["split_1M_to_256K_partial", "fold_2M_to_2M_merge"],
+)
+def test_agg_range_compiles_without_scatter(one_chip, cap, out_cap, mode):
+    """Q18's inner aggregation (`sum(l_quantity) group by l_orderkey`) at a
+    lineitem split and at its fold, rows in key order: a group is a run of
+    rows, a run's sum a difference of a two-level prefix sum at two run
+    ends.  As `jax.ops.segment_*` into 262 145 / 2 097 153 slots the three
+    reductions cost 72 / 145 ms each whatever came out (10.85 of
+    `join_agg`'s 32 busy seconds, PERF.md section 6, PR 33); one plane-wide
+    cumsum compiles for 18-34 s, an `associative_scan` for minutes."""
+    from trino_tpu import types as T
+    from trino_tpu.columnar import Batch, Column
+    from trino_tpu.ops.aggregation import AggregationOperator, AggSpec
+
+    S = _shapes(one_chip)
+    state = T.DecimalType(38, 2)
+    spec = AggSpec("sum", 1, state, sum_bound=10**12)
+    if mode == "partial":
+        cols = [
+            Column(S((cap,), jnp.int64), T.BIGINT, None),
+            Column(S((cap,), jnp.int64), T.DecimalType(15, 2), None),
+        ]
+    else:
+        cols = [
+            Column(S((cap,), jnp.int64), T.BIGINT, None),
+            Column(S((cap, 2), jnp.int64), state, None),
+            Column(S((cap,), jnp.int64), T.BIGINT, None),
+        ]
+    op = AggregationOperator([0], [spec], [c.type for c in cols], mode=mode)
+    t0 = time.perf_counter()
+    compiled = jax.jit(
+        op._range_step, static_argnames=("out_cap", "form")
+    ).lower(
+        Batch(cols, S((cap,), jnp.bool_)), S((1,), jnp.int64), S((1,), jnp.int64),
+        out_cap=out_cap, form="runs",
+    ).compile()
+    seconds = time.perf_counter() - t0
+    assert " scatter(" not in compiled.as_text()  # the op, not a frame name
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (256 << 20), f"temp grew to {temp} bytes"
+    assert seconds < 30, f"compiled for {seconds:.1f} s"
+
+
 # -- the cross-chip path: one program over the four chips of a v5e:2x2 ---------
 
 

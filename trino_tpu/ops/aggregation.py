@@ -29,10 +29,13 @@ from trino_tpu import types as T
 from trino_tpu.columnar import Batch, Column
 from trino_tpu.columnar.batch import COMPACT, concat_batches, host_pull
 from trino_tpu.ops.common import (
+    DENSE_SEGMENT_LIMIT,
     SortKey,
     group_ids_from_sorted,
     multi_key_sort_perm,
     next_pow2,
+    run_ids,
+    running_max,
     segment_reduce,
 )
 
@@ -326,6 +329,33 @@ def _onehot_plane_sums(gid, live, planes, prod: int):
     ]
 
 
+def _rows(plane, perm):
+    """`plane` in the step's row order: gathered through `perm`, or as it
+    is where the step kept the input's order (`perm` None) — an identity
+    gather still costs 7-14 ns a row on the chip."""
+    if perm is None:
+        return plane
+    return jnp.take(plane, perm, axis=0, mode="clip")
+
+
+def _range_gid(batch: Batch, channels, mins, sizes):
+    """[capacity] int32 mixed-radix code of each row's group keys over the
+    domain `mins`/`sizes` ([keys] int64, traced): per key the value minus
+    its minimum, NULL the last code of a nullable key; lexicographic in the
+    keys.  Exact where the domain's product fits POSITIONAL_LIMIT."""
+    gid = jnp.zeros(batch.capacity, dtype=jnp.int32)
+    for i, ch in enumerate(channels):
+        col = batch.columns[ch]
+        size_v = sizes[i] - (1 if col.valid is not None else 0)
+        code = jnp.clip(
+            col.data.astype(jnp.int64) - mins[i], 0, jnp.maximum(size_v - 1, 0)
+        )
+        if col.valid is not None:
+            code = jnp.where(col.valid, code, size_v)
+        gid = gid * sizes[i].astype(jnp.int32) + code.astype(jnp.int32)
+    return gid
+
+
 def _note_fastpath(path: str) -> None:
     """Record the trace-time decimal-sum path choice (proven |
     runtime_check | limb).  Called while a kernel TRACES: the count is per
@@ -339,8 +369,9 @@ def _note_fastpath(path: str) -> None:
 
 def _note_agg_path(path: str) -> None:
     """Record the grouped-aggregation kernel choice (pallas | onehot |
-    segmented | positional | sort; `segment_reduce` adds dense | scatter
-    for the segment ops under it).  Called where a step chooses, which
+    segmented | positional | sort, and runs | sorted_runs for how the
+    positional step reduced many groups; `segment_reduce` adds dense |
+    scatter for the segment ops under it).  Called where a step chooses, which
     under jit is while it TRACES: the launch door remembers the choice on
     the program and replays it on every launch (`path=` on the `launch`
     span; `trino_tpu_aggregation_path_total` counts executions)."""
@@ -370,8 +401,11 @@ def _sum128(
 
     Every segment sum here and in types/int128 goes through
     `segment_reduce`: a dense masked reduction at few segments (`nseg` 1
-    from `_global_reduce`, the direct path's `prod + 1`), a scatter-add
-    only above DENSE_SEGMENT_LIMIT.  The integers are the same."""
+    from `_global_reduce`, the direct path's `prod + 1`); above
+    DENSE_SEGMENT_LIMIT a difference of prefix sums at run ends where
+    `gid` is a `Runs` (`_range_step`), a scatter-add from the sorted
+    numbering.  The integers are the same: a prefix sum wraps, a run's own
+    sum fits whenever the licences below say a segment's does."""
     from trino_tpu.types import int128 as i128
 
     rows = d.shape[0]
@@ -834,9 +868,8 @@ class AggregationOperator:
                     cols.append(_finalize(spec, state_cols))
             return Batch(cols, out_live)
         _note_agg_path("segmented")
-        perm = jnp.arange(cap, dtype=jnp.int64)
         for spec in self.aggregates:
-            state_cols = self._reduce_one(batch, spec, perm, live, gid, nseg, prod)
+            state_cols = self._reduce_one(batch, spec, None, live, gid, nseg, prod)
             if self.mode in ("partial", "merge"):
                 cols.extend(state_cols)
             else:
@@ -1084,7 +1117,12 @@ class AggregationOperator:
         return True
 
     def _key_stats(self, batch: Batch):
-        """Jitted per-key (min, max) over live, non-null key values."""
+        """Jitted per-key (min, max) over live, non-null key values, and
+        whether the live rows are non-decreasing in the group keys, taken
+        lexicographically with NULL last: the order of `_range_gid` over
+        the domain these same bounds span.  (Meaningful only where that
+        domain fits POSITIONAL_LIMIT; `_positional_try` reads it nowhere
+        else.)"""
         key = ("keystats", tuple(self.group_channels))
         step = _STEP_CACHE.get(key)
         if step is None:
@@ -1092,7 +1130,7 @@ class AggregationOperator:
 
             def stats(batch: Batch):
                 live = batch.mask()
-                mins, maxs = [], []
+                mins, maxs, sizes = [], [], []
                 for ch in chans:
                     col = batch.columns[ch]
                     d = col.data.astype(jnp.int64)
@@ -1100,9 +1138,22 @@ class AggregationOperator:
                     if col.valid is not None:
                         v = jnp.logical_and(v, col.valid)
                     big = jnp.iinfo(jnp.int64).max
-                    mins.append(jnp.min(jnp.where(v, d, big)))
-                    maxs.append(jnp.max(jnp.where(v, d, -big)))
-                return jnp.stack(mins), jnp.stack(maxs)
+                    lo = jnp.min(jnp.where(v, d, big))
+                    hi = jnp.max(jnp.where(v, d, -big))
+                    mins.append(lo)
+                    maxs.append(hi)
+                    sizes.append(
+                        jnp.where(hi >= lo, hi - lo + 1, 0)
+                        + (0 if col.valid is None else 1)
+                    )
+                mins, maxs = jnp.stack(mins), jnp.stack(maxs)
+                gid = jnp.where(
+                    live, _range_gid(batch, chans, mins, jnp.stack(sizes)), -1
+                )
+                before = jnp.concatenate(
+                    [jnp.full(1, -1, jnp.int32), running_max(gid)[:-1]]
+                )
+                return mins, maxs, jnp.all(jnp.where(live, gid >= before, True))
 
             step = jit_program(stats, "agg_key_stats")
             _STEP_CACHE[key] = step
@@ -1111,14 +1162,14 @@ class AggregationOperator:
     def _positional_try(self, batch: Batch) -> Optional[Batch]:
         """Sort-free grouped reduction when the key domain is dense enough:
         gid = mixed-radix positional code from per-key (min, size), group
-        values decoded back from the slot index.  One host sync for the key
-        stats; sizes/mins stay traced so data changes do not retrace."""
+        values decoded back from it.  One host sync for the key stats and
+        the rows' order; sizes/mins stay traced so data changes do not
+        retrace."""
         import numpy as np
 
         if not self._positional_static_eligible(batch):
             return None
-        mins_d, maxs_d = self._key_stats(batch)
-        mins, maxs = host_pull((mins_d, maxs_d), "group_stats")
+        mins, maxs, ordered = host_pull(self._key_stats(batch), "group_stats")
         prod = 1
         sizes = []
         for i, ch in enumerate(self.group_channels):
@@ -1137,17 +1188,28 @@ class AggregationOperator:
         if prod > max(1 << 16, 8 * batch.capacity):
             return None
         nseg = next_pow2(prod, floor=16)
+        # how the groups reduce is chosen from the (static) slot count and
+        # the order the rows were observed in, nothing else: few slots
+        # dense; many as runs of the group id, sorted first where the rows
+        # did not arrive in its order
+        if nseg + 1 <= DENSE_SEGMENT_LIMIT:
+            form = "dense"
+        else:
+            form = "runs" if ordered else "sorted_runs"
+        # (a program a form: a launch's `path=` is what its program chose
+        # while tracing, and must name the one form that launch ran)
         key = (
             "range",
             tuple(self.group_channels),
             tuple(self.aggregates),
             tuple(t.name for t in self.input_types),
             self.mode,
+            form,
         )
         step = _STEP_CACHE.get(key)
         if step is None:
             step = jit_program(
-                self._range_step, "agg_range", static_argnames=("out_cap",)
+                self._range_step, "agg_range", static_argnames=("out_cap", "form")
             )
             _STEP_CACHE[key] = step
         out = step(
@@ -1155,34 +1217,56 @@ class AggregationOperator:
             jnp.asarray(mins),
             jnp.asarray(np.asarray(sizes, dtype=np.int64)),
             out_cap=int(nseg),
+            form=form,
         )
-        # positional output is sparse (occupancy-masked); compact when the
-        # live groups are far below the domain so downstream sorts stay small
+        # the dense form's output is sparse (slot = group id, occupancy-
+        # masked), the run forms' packed; compact when the live groups are
+        # far below the domain so downstream sorts stay small
         ng = out.num_rows_host()
         cc = next_pow2(max(ng, 1), floor=16)
         if cc * 2 <= nseg:
             out = COMPACT(out, out_capacity=cc)
         return out
 
-    def _range_step(self, batch: Batch, mins, sizes, out_cap: int) -> Batch:
+    def _range_step(
+        self, batch: Batch, mins, sizes, out_cap: int, form: str
+    ) -> Batch:
+        """Grouped reduction by the positional code `_range_gid`, `form`
+        (static) as `_positional_try` chose it.  `dense`: slot = code, the
+        segment reductions dense masked passes.  `runs`: the rows arrive in
+        code order, so a group is a run of rows and the k-th group the k-th
+        run (`ops/common.Runs`).  `sorted_runs`: one stable sort of the
+        32-bit code with the row number as payload, each reduced plane
+        gathered through it, then the same.  No form scatters."""
         gch = self.group_channels
         cap = batch.capacity
+        assert out_cap <= self.POSITIONAL_LIMIT and cap < (1 << 31), (out_cap, cap)
         live = batch.mask()
-        gid = jnp.zeros(cap, dtype=jnp.int64)
-        for i, ch in enumerate(gch):
-            col = batch.columns[ch]
-            d = col.data.astype(jnp.int64)
-            size_v = sizes[i] - (1 if col.valid is not None else 0)
-            code = jnp.clip(d - mins[i], 0, jnp.maximum(size_v - 1, 0))
-            if col.valid is not None:
-                code = jnp.where(col.valid, code, size_v)
-            gid = gid * sizes[i] + code
-        gid = jnp.where(live, gid, out_cap)
-        nseg = out_cap + 1
-        occupancy = segment_reduce(None, gid, nseg, "count", valid=live)[:out_cap]
-        out_live = occupancy > 0
-        # decode slot index -> group key values (traced div/mod chain)
-        idx = jnp.arange(out_cap, dtype=jnp.int64)
+        gid = jnp.where(live, _range_gid(batch, gch, mins, sizes), out_cap)
+        _note_agg_path("positional")
+        perm = None
+        if form == "dense":
+            nseg = out_cap + 1
+            segments = gid
+            occupancy = segment_reduce(None, gid, nseg, "count", valid=live)
+            out_live = occupancy[:out_cap] > 0
+            code = jnp.arange(out_cap, dtype=jnp.int64)
+        else:
+            _note_agg_path(form)
+            if form == "sorted_runs":
+                gid, perm = jax.lax.sort(
+                    (gid.astype(jnp.uint32), jnp.arange(cap, dtype=jnp.int32)),
+                    num_keys=1,
+                    is_stable=True,
+                )
+                live = gid < out_cap
+            nseg = out_cap
+            segments = run_ids(gid, live, out_cap)
+            out_live = segments.live
+            code = jnp.take(segments.gid, segments.src, mode="clip").astype(
+                jnp.int64
+            )
+        # decode group code -> group key values (traced div/mod chain)
         sizes_list = [sizes[i] for i in range(len(gch))]
         divs = []
         d = jnp.ones((), dtype=jnp.int64)
@@ -1193,17 +1277,16 @@ class AggregationOperator:
         cols: list[Column] = []
         for i, ch in enumerate(gch):
             col = batch.columns[ch]
-            code = (idx // divs[i]) % sizes_list[i]
+            digit = (code // divs[i]) % sizes_list[i]
             valid = None
             if col.valid is not None:
-                valid = code < (sizes_list[i] - 1)
-            data = (code + mins[i]).astype(col.data.dtype)
+                valid = digit < (sizes_list[i] - 1)
+            data = (digit + mins[i]).astype(col.data.dtype)
             cols.append(Column(data, col.type, valid, col.dictionary))
-        _note_agg_path("positional")
-        perm = jnp.arange(cap, dtype=jnp.int64)
-        gid_c = jnp.minimum(gid, out_cap)
         for spec in self.aggregates:
-            state_cols = self._reduce_one(batch, spec, perm, live, gid_c, nseg, out_cap)
+            state_cols = self._reduce_one(
+                batch, spec, perm, live, segments, nseg, out_cap
+            )
             if self.mode in ("partial", "merge"):
                 cols.extend(state_cols)
             else:
@@ -1611,13 +1694,13 @@ class AggregationOperator:
         """(per-row series, pairwise-valid mask) for one bi_* primitive."""
         cx = batch.columns[spec.arg]
         cy = batch.columns[spec.arg2]
-        dx = _logical_double(jnp.take(cx.data, perm, mode="clip"), cx.type)
-        dy = _logical_double(jnp.take(cy.data, perm, mode="clip"), cy.type)
+        dx = _logical_double(_rows(cx.data, perm), cx.type)
+        dy = _logical_double(_rows(cy.data, perm), cy.type)
         v = live
         if cx.valid is not None:
-            v = jnp.logical_and(v, jnp.take(cx.valid, perm, mode="clip"))
+            v = jnp.logical_and(v, _rows(cx.valid, perm))
         if cy.valid is not None:
-            v = jnp.logical_and(v, jnp.take(cy.valid, perm, mode="clip"))
+            v = jnp.logical_and(v, _rows(cy.valid, perm))
         series = {
             "bi_sum_1": dx,
             "bi_sum_2": dy,
@@ -1636,10 +1719,10 @@ class AggregationOperator:
             ch = spec.arg
             for kind, _ in prims:
                 col = batch.columns[ch]
-                d = jnp.take(col.data, perm, axis=0, mode="clip")
+                d = _rows(col.data, perm)
                 v = live
                 if col.valid is not None:
-                    v = jnp.logical_and(v, jnp.take(col.valid, perm, mode="clip"))
+                    v = jnp.logical_and(v, _rows(col.valid, perm))
                 if (
                     kind == "sum"
                     and isinstance(col.type, T.DecimalType)
@@ -1676,12 +1759,10 @@ class AggregationOperator:
             if kind == "checksum":
                 col = batch.columns[arg]
                 h = _hll_hash(col).astype(jnp.int64)  # stable value hash
-                h = jnp.take(h, perm, mode="clip")
+                h = _rows(h, perm)
                 if col.valid is not None:
                     nullp = jnp.int64(np.int64(np.uint64(CHECKSUM_NULL_PRIME)))
-                    h = jnp.where(
-                        jnp.take(col.valid, perm, mode="clip"), h, nullp
-                    )
+                    h = jnp.where(_rows(col.valid, perm), h, nullp)
                 red = segment_reduce(
                     jnp.where(live, h, 0), gid, nseg, "sum", valid=live
                 )[:out_cap]
@@ -1697,10 +1778,10 @@ class AggregationOperator:
                     out.append(Column(red, T.DOUBLE, None))
                 continue
             col = batch.columns[arg]
-            d = jnp.take(col.data, perm, axis=0, mode="clip")
+            d = _rows(col.data, perm)
             v = live
             if col.valid is not None:
-                v = jnp.logical_and(v, jnp.take(col.valid, perm, mode="clip"))
+                v = jnp.logical_and(v, _rows(col.valid, perm))
             st = _state_types(spec, self.input_types)[len(out)]
             if kind in ("sum_f", "sumsq"):
                 dl = _logical_double(d, col.type)
@@ -1902,9 +1983,8 @@ class AggregationOperator:
                         )
                         continue
                     if kind.startswith("bi_"):
-                        perm0 = jnp.arange(batch.capacity, dtype=jnp.int64)
                         series, v = self._bivariate_series(
-                            batch, spec, kind, perm0, live
+                            batch, spec, kind, None, live
                         )
                         if kind == "bi_count":
                             states.append(

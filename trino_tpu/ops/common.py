@@ -128,8 +128,11 @@ def group_ids_from_sorted(batch: Batch, perm, key_channels):
 
 #: A segmented reduction over at most this many segments runs DENSE — one
 #: fused masked pass over the rows per segment, `reduce_n where(gid[n] == s,
-#: value[n], identity)` — and only above it as a scatter
-#: (`jax.ops.segment_*`).  The TPU has no scatter hardware: XLA serialises
+#: value[n], identity)`.  Above it the reduction runs over the RUNS of a
+#: non-decreasing group id where the caller has one (`Runs`: the
+#: range-positional aggregation, `AggregationOperator._range_step`), and as
+#: a scatter (`jax.ops.segment_*`) only for the callers that number their
+#: groups some other way.  The TPU has no scatter hardware: XLA serialises
 #: a scatter-add at 60-130 ns a row whatever the segment count (63-133 ms a
 #: 2^20-row batch on a v5e), while the dense pass costs rows x segments
 #: vector work and materialises nothing [rows, segments]-shaped.  Chosen by
@@ -170,9 +173,15 @@ def _reduce_segments(values, gid, num_segments: int, op: str):
 
 def segment_reduce(values, gid, num_segments: int, kind: str, valid=None):
     """Null-skipping segmented reduction -> [num_segments].  kind:
-    sum/min/max/count/any.  THE one place that lowers a segment op: dense
-    masked reductions up to DENSE_SEGMENT_LIMIT segments, a scatter above
-    (see `_reduce_segments`); same integers either way."""
+    sum/min/max/count/any.  THE one place that lowers a segment op: over a
+    `Runs` (a group id non-decreasing over the live rows, its run ends
+    found once) a reduction over runs, at any segment count and never a
+    scatter; over a plain [n] `gid`, dense masked reductions up to
+    DENSE_SEGMENT_LIMIT segments and a scatter above (see
+    `_reduce_segments`).  Same integers every way."""
+    if isinstance(gid, Runs):
+        assert num_segments == gid.slots, (num_segments, gid.slots)
+        return _reduce_runs(values, gid, kind, valid)
     if kind == "count":
         w = jnp.ones(gid.shape, jnp.int64)
         if valid is not None:
@@ -196,6 +205,145 @@ def segment_reduce(values, gid, num_segments: int, kind: str, valid=None):
         else:
             values = jnp.where(valid, values, _min_sentinel(values.dtype))
     return _reduce_segments(values, gid, num_segments, kind)
+
+
+# -- reductions over the runs of a non-decreasing group id ----------------------
+
+#: rows of one block of the two-level scans below: a row-wise scan of
+#: [capacity / 4096, 4096] and one over the blocks' totals compile in 1-3 s
+#: at 2^20-2^21 rows; ONE plane-wide `jnp.cumsum` of 2^19-2^20 rows
+#: compiled for 18-34 s (tools/compact_sweep.py, PERF.md section 6, PR 31),
+#: and a `lax.associative_scan` over 4096 lanes for 3-9 minutes (PR 33)
+_SCAN_BLOCK = 4096
+
+
+def _blocks(x, fill):
+    width = min(_SCAN_BLOCK, x.shape[0])
+    return jnp.pad(
+        x, (0, -x.shape[0] % width), constant_values=fill
+    ).reshape(-1, width)
+
+
+def prefix_sum(x):
+    """Inclusive running sum of a [n] plane, in its own dtype (integers
+    wrap), in two levels."""
+    b = _blocks(x, 0)
+    within = jnp.cumsum(b, axis=1, dtype=x.dtype)
+    total = within[:, -1]
+    before = jnp.cumsum(total, dtype=x.dtype) - total
+    return (within + before[:, None]).reshape(-1)[: x.shape[0]]
+
+
+def running_max(x):
+    """Inclusive running maximum of a [n] integer plane, in two levels."""
+    low = _min_sentinel(x.dtype)
+    within = jax.lax.cummax(_blocks(x, low), axis=1)
+    before = jnp.concatenate(
+        [low[None], jax.lax.cummax(within[:, -1])[:-1]]
+    )
+    return jnp.maximum(within, before[:, None]).reshape(-1)[: x.shape[0]]
+
+
+@dataclass(frozen=True)
+class Runs:
+    """A group id that is non-decreasing over the live rows, as its RUNS:
+    group k of the output is the k-th run, in id order, packed to the front.
+    Dead rows may lie anywhere: each rides in the run of the live row before
+    it and adds the identity.  Positions and ids are int32 (the chip
+    emulates int64 as two u32 planes)."""
+
+    rows: jnp.ndarray  # [n] bool: the live rows
+    gid: jnp.ndarray  # [n] int32: the id of the newest live row, -1 before one
+    last: jnp.ndarray  # [n] bool: the row closes a run
+    src: jnp.ndarray  # [slots] int32: the row that closes the k-th run
+    live: jnp.ndarray  # [slots] bool: k < number of runs
+
+    @property
+    def slots(self) -> int:
+        return self.src.shape[0]
+
+    def slot_of_rows(self):
+        """[n] int32: the output slot of each row's run."""
+        ends = prefix_sum(self.last.astype(jnp.int32)) - self.last
+        return jnp.minimum(ends, self.slots - 1)
+
+
+def run_ids(gid, live, slots: int) -> Runs:
+    """`Runs` of a [n] integer `gid` (0 <= gid < 2^31 on live rows) that is
+    non-decreasing over the rows `live` marks; `slots` is static and at
+    least the number of distinct ids.  No scatter: the newest live id is a
+    running maximum, and the run ends a compaction of a boundary mask
+    (`slot_sources`)."""
+    from trino_tpu.columnar.batch import slot_sources
+
+    n = gid.shape[0]
+    assert 0 < n < (1 << 31) and slots < (1 << 31), (n, slots)
+    filled = running_max(jnp.where(live, gid, -1).astype(jnp.int32))
+    after = jnp.concatenate([filled[1:], jnp.full(1, -2, jnp.int32)])
+    last = jnp.logical_and(filled != after, filled >= 0)
+    src, out_live = slot_sources(last, slots)
+    return Runs(live, filled, last, src, out_live)
+
+
+def _scan_runs(values, gid, op):
+    """Inclusive scan of `op` over a [n] plane that restarts at every run
+    of the non-decreasing `gid`: log2(n) shifted passes, each combining a
+    row with the row 2^k before it while both lie in one run.  A run's
+    result is a tree over its own rows only — it never depends on the rows
+    before the run, which a difference of float prefix sums would."""
+    n = values.shape[0]
+    shift = 1
+    while shift < n:
+        earlier = jnp.concatenate([values[:shift], values[:-shift]])
+        same = jnp.concatenate(
+            [jnp.zeros(shift, bool), gid[shift:] == gid[:-shift]]
+        )
+        values = jnp.where(same, op(values, earlier), values)
+        shift *= 2
+    return values
+
+
+def _reduce_runs(values, runs: Runs, kind: str, valid):
+    """`segment_reduce` over `Runs` -> [runs.slots]; a slot past the last
+    run reads the identity, as an empty segment does."""
+    rows = runs.rows if valid is None else jnp.logical_and(valid, runs.rows)
+
+    def run_totals(plane):
+        # a run's sum is a difference of the running sum at two run ends;
+        # integers wrap, so it is exact whenever the run's own sum fits
+        ends = jnp.take(prefix_sum(plane), runs.src, mode="clip")
+        before = jnp.concatenate([jnp.zeros(1, ends.dtype), ends[:-1]])
+        return jnp.where(runs.live, ends - before, 0)
+
+    def run_scan(plane, op, identity):
+        ends = jnp.take(_scan_runs(plane, runs.gid, op), runs.src, mode="clip")
+        return jnp.where(runs.live, ends, identity)
+
+    if kind == "count":
+        return run_totals(rows.astype(jnp.int32)).astype(jnp.int64)
+    if kind == "any":
+        n = runs.rows.shape[0]
+        idx = jnp.where(rows, jnp.arange(n, dtype=jnp.int32), n)
+        first = run_scan(idx, jnp.minimum, n)
+        return jnp.take(values, first, axis=0, mode="clip")
+    if kind == "sum":
+        zero = jnp.zeros((), values.dtype)
+        plane = jnp.where(rows, values, zero)
+        if jnp.issubdtype(values.dtype, jnp.integer):
+            return run_totals(plane)
+        return run_scan(plane, jnp.add, zero)
+    if kind not in ("min", "max"):
+        raise ValueError(kind)
+    identity = (_max_sentinel if kind == "min" else _min_sentinel)(values.dtype)
+    op = jnp.minimum if kind == "min" else jnp.maximum
+    return run_scan(jnp.where(rows, values, identity), op, identity)
+
+
+def segment_values_of_rows(per_segment, gid):
+    """[n]: each row's segment's entry of a `segment_reduce` result."""
+    if isinstance(gid, Runs):
+        gid = gid.slot_of_rows()
+    return jnp.take(per_segment, gid, mode="clip")
 
 
 def _max_sentinel(dtype):

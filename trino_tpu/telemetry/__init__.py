@@ -62,6 +62,11 @@ launch paths (`path=` of a `launch` span, `+`-joined; each also a label of
     formulation the step chose), dense, scatter (how its segment
     reductions lowered: masked reductions over few segments, or
     `jax.ops.segment_*` over many — `ops/common.segment_reduce`),
+    runs, sorted_runs (an `agg_range` step over more than 2 048 slots
+    reduced over the runs of its group code, never a scatter: the rows
+    arrived in code order, or one 32-bit sort put them there first —
+    `ops/common.Runs`, chosen by `_positional_try` from the order
+    `agg_key_stats` observed),
     compact_sort (the program packs live rows to the front and found
     each output slot's source row by a one-key sort in blocks, never a
     scatter — `columnar/batch.slot_sources`; on `compact` and every mesh

@@ -420,13 +420,15 @@ DECIMAL_FASTPATHS = ("proven", "runtime_check", "limb")
 #: int64 one-hot masked reduction, per-aggregate segment reductions over
 #: dense codes, the range-positional domain, or the sort-based numbering —
 #: and how its segment reductions lowered (ops/common.segment_reduce):
-#: dense masked reductions (few segments) or scatters (many); and that a
-#: program packed rows, finding its slots' source rows by a one-key sort
-#: (columnar/batch.slot_sources; prefixed, because `sort` is the
-#: aggregation's sort-based numbering)
+#: dense masked reductions (few segments) or scatters (many); over many
+#: slots the range-positional step reduces over the runs of its group
+#: code instead, the rows found in code order (`runs`) or sorted into it
+#: first (`sorted_runs`); and that a program packed rows, finding its
+#: slots' source rows by a one-key sort (columnar/batch.slot_sources;
+#: prefixed, because `sort` is the aggregation's sort-based numbering)
 AGGREGATION_PATHS = (
     "pallas", "onehot", "segmented", "positional", "sort", "dense", "scatter",
-    "compact_sort",
+    "runs", "sorted_runs", "compact_sort",
 )
 
 
@@ -757,7 +759,10 @@ def _register_engine_metrics(reg: MetricsRegistry) -> None:
         "reductions over dense codes, positional = range-positional domain, "
         "sort = sort-based numbering; and how the program's segment reductions "
         "lowered: dense = masked reductions (few segments), scatter = "
-        "jax.ops.segment_* (many); compact_sort = the program packed live "
+        "jax.ops.segment_* (many); runs / sorted_runs = the positional step "
+        "reduced many groups over the runs of its group code, the rows "
+        "arriving in code order / sorted into it first (ops/common.Runs, "
+        "never a scatter); compact_sort = the program packed live "
         "rows to the front, each output slot's source row found by a "
         "one-key sort in blocks (columnar/batch.slot_sources)",
         labelnames=("path",),

@@ -297,9 +297,9 @@ def rescale128(h, l, from_scale: int, to_scale: int):
 
 def _segment_reducer(gid, num_segments: int, kind: str):
     """plane -> its [num_segments] `kind` by `gid`, through the engine's one
-    segment lowering (ops/common.segment_reduce: dense up to
-    DENSE_SEGMENT_LIMIT segments, a scatter above).  Imported here because
-    ops/common imports this package."""
+    segment lowering (ops/common.segment_reduce: over runs where `gid` is a
+    `Runs`, else dense up to DENSE_SEGMENT_LIMIT segments and a scatter
+    above).  Imported here because ops/common imports this package."""
     from trino_tpu.ops.common import segment_reduce
 
     return lambda plane: segment_reduce(plane, gid, num_segments, kind)
@@ -352,13 +352,15 @@ def sum128_widened(d, gid, num_segments: int, valid=None):
 def segment_minmax128(h, l, gid, num_segments: int, valid, is_max: bool):
     """Segmented lexicographic min/max over i128 planes: reduce the high
     limb first, then the low limb among rows matching the winning high."""
+    from trino_tpu.ops.common import segment_values_of_rows
+
     big = jnp.int64(np.iinfo(np.int64).max)
     small = jnp.int64(np.iinfo(np.int64).min)
     lu = l ^ _SIGN  # low limb in signed-comparable (unsigned) order
     pick = _segment_reducer(gid, num_segments, "max" if is_max else "min")
     lose = small if is_max else big
     win_h = pick(jnp.where(valid, h, lose))
-    on_win = jnp.logical_and(valid, h == jnp.take(win_h, gid, mode="clip"))
+    on_win = jnp.logical_and(valid, h == segment_values_of_rows(win_h, gid))
     win_l = pick(jnp.where(on_win, lu, lose))
     return win_h, win_l ^ _SIGN
 
