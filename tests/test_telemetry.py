@@ -1053,6 +1053,27 @@ def test_compaction_form_rides_the_compact_launch(runner):
     )
 
 
+def test_repartition_forms_ride_the_exchange_launches(dist):
+    """A partitioned join's repartition says how it placed and counted its
+    rows: `fused_exchange*` launches carry `compact_sort` (one stable
+    compaction a destination), `exchange_counts` ones `dense`; no launch of
+    the statement names a scatter."""
+    dist.execute("set session join_distribution_type = 'PARTITIONED'")
+    try:
+        _, flat = _run_with_context(dist, _tpch(3))
+    finally:
+        dist.execute("set session join_distribution_type = 'AUTOMATIC'")
+    paths = [
+        (a["step"], set(a.get("path", "").split("+")))
+        for a in (_attrs(s) for s in flat if s["name"] == "launch")
+    ]
+    exchanges = [p for step, p in paths if step.startswith("fused_exchange")]
+    counts = [p for step, p in paths if step.startswith("exchange_counts")]
+    assert exchanges and all("compact_sort" in p for p in exchanges), paths
+    assert counts and all(p == {"dense"} for p in counts), paths
+    assert not any("scatter" in p for _, p in paths), paths
+
+
 def test_pull_off_the_statement_thread_is_counted_without_a_span():
     import contextvars
     import threading

@@ -300,22 +300,35 @@ def _stacked_batch(wm, cap):
     )
 
 
-def test_four_chip_repartition_compiles(topo):
-    """Hash repartition = bucketize + `all_to_all` under shard_map, on a
-    WorkerMesh of the four described chips (what `chip_smoke.py --chips 4`
-    runs for real).  Rows per worker are kept small: the stable argsort
-    inside dominates compile time (76 s at 2^20 rows) and is covered, at
-    real size, by test_q1_fragment_compiles."""
-    from trino_tpu.parallel.exchange import _exchange_kernel
+@pytest.mark.parametrize("kernel", ["exchange", "counts"])
+def test_four_chip_repartition_compiles(topo, kernel):
+    """Hash repartition = counts pass, then bucketize + `all_to_all` under
+    shard_map, on a WorkerMesh of the four described chips (what
+    `chip_smoke.py --chips 4` runs for real), at the mesh cell's shape: 2^21
+    rows a worker into 4 x 2^18 slots.  Neither program scatters: as
+    `.at[].set` into 2^20 + 1 slots and `segment_*` into five they cost
+    3 x 192 + 2 x 126 ms a statement (PERF.md section 6, PR 36), and the
+    stable int64 argsort in front of them compiled for 76 s at 2^20 rows."""
+    from trino_tpu.parallel.exchange import _counts_kernel, _exchange_kernel
     from trino_tpu.parallel.spmd import WorkerMesh, spmd_collective_step
 
     wm = WorkerMesh(devices=list(topo.devices))
     assert wm.n == 4
-    fn = spmd_collective_step(
-        wm, _exchange_kernel([0], wm.n, 1 << 10), "fused_exchange_x"
-    )
-    compiled = fn.lower(_stacked_batch(wm, 1 << 12)).compile()
-    assert "all-to-all" in compiled.as_text()
+    step, name = {
+        "exchange": (_exchange_kernel([0], wm.n, 1 << 18), "fused_exchange_x"),
+        "counts": (_counts_kernel([0], wm.n), "exchange_counts_x"),
+    }[kernel]
+    t0 = time.perf_counter()
+    compiled = spmd_collective_step(wm, step, name).lower(
+        _stacked_batch(wm, 1 << 21)
+    ).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    assert " scatter(" not in text  # the op, not a frame name
+    assert ("all-to-all" in text) == (kernel == "exchange")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (128 << 20), f"temp grew to {temp} bytes"
+    assert seconds < 40, f"compiled for {seconds:.1f} s"
 
 
 def test_four_chip_broadcast_compiles(topo):

@@ -11,11 +11,19 @@ A first jitted phase counts rows per (worker, destination); the host takes
 the max and picks the pow2 slot capacity; the second jitted phase performs
 the exchange (the reference's two-step "reserve then append" PagePartitioner
 pattern, with the host sync standing in for buffer backpressure).
+
+Neither phase scatters: the TPU serialises a scatter at 50-90 ns a SOURCE
+row whatever comes out (three int64 columns of 2^21 rows: 3 x 192 ms a
+statement, and 126 ms for a `segment_*` into five slots; PERF.md section 6,
+PR 36).  The counts are a dense compare-and-sum (`segment_reduce`); the
+bucketize is one stable compaction a destination (`slot_sources`) and one
+gather a plane (`bucketize`).  Nothing is sorted by destination.  What a
+consumer may rely on: a destination receives each sender's rows in the
+sender's row order, senders in worker order.
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional, Sequence
 
 import jax
@@ -23,8 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar import Batch, Column
-from trino_tpu.columnar.batch import host_pull
-from trino_tpu.ops.common import next_pow2
+from trino_tpu.columnar.batch import host_pull, slot_sources
+from trino_tpu.ops.common import next_pow2, segment_reduce
 from trino_tpu.parallel.spmd import WorkerMesh
 
 _MIX = np.uint64(0x9E3779B97F4A7C15)
@@ -60,16 +68,50 @@ def _hash_rows(batch: Batch, key_channels: Sequence[int]) -> jnp.ndarray:
     return h
 
 
+def _destinations(batch: Batch, key_channels: Sequence[int], n_workers: int):
+    """[cap] int32: the worker each row's key hashes to; a dead row reads
+    `n_workers`, which is nobody."""
+    h = _hash_rows(batch, key_channels)
+    dest = (h % jnp.uint64(n_workers)).astype(jnp.int32)
+    return jnp.where(batch.mask(), dest, n_workers)
+
+
+def bucketize(batch: Batch, dest, n_workers: int, slot_cap: int) -> Batch:
+    """The send buffer of one worker: a Batch whose planes are
+    [n_workers, slot_cap(, k)], piece `d` holding the rows with
+    `dest == d` packed to the front in row order — the first `slot_cap` of
+    them; its mask says which slots hold a row.  A dead slot is zero, not
+    valid and not live, so the bytes a receiver sees are deterministic.
+
+    `n_workers` stable compactions and one gather a plane, never a scatter
+    (`slot_sources`; tools/exchange_sweep.py, PERF.md section 6, PR 36).
+    Needs no mesh: a test and the sweep call it on one device."""
+    pieces = [slot_sources(dest == d, slot_cap) for d in range(n_workers)]
+    inv = jnp.stack([i for i, _ in pieces])
+    live = jnp.stack([l for _, l in pieces])
+
+    def place(plane):  # [cap(, k)] -> [n_workers, slot_cap(, k)]
+        got = jnp.take(plane, inv, axis=0, mode="clip")
+        keep = live.reshape(live.shape + (1,) * (got.ndim - live.ndim))
+        return jnp.where(keep, got, jnp.zeros((), got.dtype))
+
+    cols = [
+        Column(
+            place(c.data), c.type,
+            None if c.valid is None else place(c.valid), c.dictionary,
+        )
+        for c in batch.columns
+    ]
+    return Batch(cols, live)
+
+
 def _counts_kernel(key_channels, n_workers):
     def kernel(stacked: Batch):
         b = jax.tree.map(lambda x: x[0], stacked)
-        h = _hash_rows(b, key_channels)
-        dest = (h % jnp.uint64(n_workers)).astype(jnp.int64)
-        dest = jnp.where(b.mask(), dest, n_workers)
-        counts = jax.ops.segment_sum(
-            jnp.ones_like(dest), dest, n_workers + 1
-        )[:n_workers]
-        return counts[None]
+        dest = _destinations(b, key_channels, n_workers)
+        # dead rows count into the slot past the workers', which is dropped
+        counts = segment_reduce(None, dest, n_workers + 1, "count")
+        return counts[None, :n_workers]
 
     return kernel
 
@@ -77,58 +119,17 @@ def _counts_kernel(key_channels, n_workers):
 def _exchange_kernel(key_channels, n_workers, slot_cap):
     def kernel(stacked: Batch):
         b = jax.tree.map(lambda x: x[0], stacked)
-        cap = b.capacity
-        h = _hash_rows(b, key_channels)
-        dest = (h % jnp.uint64(n_workers)).astype(jnp.int64)
-        dest = jnp.where(b.mask(), dest, n_workers)
-        # stable sort rows by destination; dead rows last
-        order = jnp.argsort(dest, stable=True)
-        d_sorted = dest[order]
-        # slot within destination = position - first position of that dest
-        pos = jnp.arange(cap, dtype=jnp.int64)
-        first = jax.ops.segment_min(pos, d_sorted, n_workers + 1)
-        slot = pos - first[jnp.clip(d_sorted, 0, n_workers)]
-        valid_slot = jnp.logical_and(d_sorted < n_workers, slot < slot_cap)
-        flat = jnp.where(valid_slot, d_sorted * slot_cap + slot, n_workers * slot_cap)
+        dest = _destinations(b, key_channels, n_workers)
+        sent = bucketize(b, dest, n_workers, slot_cap)
 
-        def scatter(col_1d, fill):
-            if col_1d.ndim > 1:  # long-decimal limb planes [cap, k]
-                k = col_1d.shape[1]
-                out = jnp.full(
-                    (n_workers * slot_cap + 1, k), fill, dtype=col_1d.dtype
-                )
-                out = out.at[flat].set(col_1d[order], mode="drop")
-                return out[:-1].reshape(n_workers, slot_cap, k)
-            out = jnp.full((n_workers * slot_cap + 1,), fill, dtype=col_1d.dtype)
-            out = out.at[flat].set(col_1d[order], mode="drop")
-            return out[:-1].reshape(n_workers, slot_cap)
-
-        sent_mask = scatter(b.mask(), False)
-        sent_cols = [
-            (
-                scatter(c.data, jnp.asarray(0, c.data.dtype)),
-                None if c.valid is None else scatter(c.valid, False),
-            )
-            for c in b.columns
-        ]
         # the collective: piece d goes to worker d; received[w] = from worker w
-        recv_mask = jax.lax.all_to_all(
-            sent_mask, "workers", split_axis=0, concat_axis=0
-        ).reshape(-1)
-        out_cols = []
-        for (data, valid), c in zip(sent_cols, b.columns):
-            rd = jax.lax.all_to_all(data, "workers", split_axis=0, concat_axis=0)
-            rv = (
-                None
-                if valid is None
-                else jax.lax.all_to_all(valid, "workers", split_axis=0, concat_axis=0).reshape(-1)
+        def ship(plane):
+            got = jax.lax.all_to_all(
+                plane, "workers", split_axis=0, concat_axis=0
             )
-            shaped = (
-                rd.reshape(-1, rd.shape[-1]) if rd.ndim > 2 else rd.reshape(-1)
-            )
-            out_cols.append(Column(shaped, c.type, rv, c.dictionary))
-        out = Batch(out_cols, recv_mask)
-        return jax.tree.map(lambda x: x[None], out)
+            return got.reshape((1, -1) + got.shape[2:])
+
+        return jax.tree.map(ship, sent)
 
     return kernel
 

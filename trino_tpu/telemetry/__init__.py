@@ -69,8 +69,11 @@ launch paths (`path=` of a `launch` span, `+`-joined; each also a label of
     `agg_key_stats` observed),
     compact_sort (the program packs live rows to the front and found
     each output slot's source row by a one-key sort in blocks, never a
-    scatter — `columnar/batch.slot_sources`; on `compact` and every mesh
-    `*_compact` / `fused_expand` step that packs rows)
+    scatter — `columnar/batch.slot_sources`; on `compact`, every mesh
+    `*_compact` / `fused_expand` step that packs rows, and every
+    `fused_exchange*`, whose send buffer is one such compaction a
+    destination: `parallel/exchange.bucketize`; its `exchange_counts`
+    reads `dense`)
 
 host_pull why (`why=` of a `host_pull` span: what the host needed it for):
     result (rows for the client), capacity (a count that sizes the next
