@@ -317,3 +317,60 @@ def test_string_join_distinct_dictionaries_recode(dist, local):
     d = dist.execute(sql).rows
     l = local.execute(sql).rows
     assert d == l and d[0][0] > 0, (d, l)
+
+
+# -- the mesh join's locate: direct table over the build keys' domain --------
+
+
+def _join_locates(dist):
+    import json
+
+    _, flat = dist.traces[-1]
+    return [
+        json.loads(s["attributes"])["locate"]
+        for s in flat if s["name"] == "join"
+    ]
+
+
+@pytest.mark.parametrize("distribution", ["AUTOMATIC", "PARTITIONED"])
+def test_q3_locates_by_table(dist, local, distribution):
+    """Q3's two inner joins key on domains the dynamic-filter summary holds
+    and the shared rule admits: each shard indexes its build by direct
+    table, broadcast or partitioned, and answers as the local runner."""
+    dist.execute(f"set session join_distribution_type = '{distribution}'")
+    try:
+        d = dist.execute(QUERIES[3])
+        locates = _join_locates(dist)
+        placed = dist.explain_distributed(QUERIES[3])
+    finally:
+        dist.execute("set session join_distribution_type = 'AUTOMATIC'")
+    assert_rows_match(d.rows, local.execute(QUERIES[3]).rows, ordered=True)
+    assert locates == ["join_locate_table"] * 2
+    assert ("dist=partitioned" in placed) == (distribution == "PARTITIONED")
+
+
+_EDGES = (
+    "(select case when c_custkey % 7 = 0 then null else c_custkey end k "
+    "from customer) c {kind} join (select o_custkey k2, o_totalprice p "
+    "from orders where o_custkey between 100 and 200) o on c.k = o.k2"
+)
+
+
+@pytest.mark.parametrize("sql, locate", [
+    # probe keys below and above the build's domain, NULL probe keys, and
+    # build keys held by many orders (a run longer than one)
+    ("select count(*), sum(c.k), sum(o.p) from " + _EDGES.format(kind=""),
+     "join_locate_table"),
+    # a domain the rule refuses: 150 build keys spread over 1.5e10 codes
+    ("select count(*), sum(o.p) from (select o_custkey * 10000000 k2, "
+     "o_totalprice p from orders) o join (select c_custkey * 10000000 k "
+     "from customer where c_nationkey < 5) c on o.k2 = c.k",
+     "join_locate_sorted"),
+    # only an inner join has a domain summary
+    ("select count(*), sum(c.k), count(o.k2) from "
+     + _EDGES.format(kind="left"), "join_locate_sorted"),
+], ids=["table_edges", "sparse_domain", "left_join"])
+def test_join_locate_follows_the_domain(dist, local, sql, locate):
+    d = dist.execute(sql).rows
+    assert d == local.execute(sql).rows and d[0][0] > 0
+    assert _join_locates(dist) == [locate]

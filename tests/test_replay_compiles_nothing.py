@@ -13,6 +13,7 @@ key, and a statement's second execution may take another program than its
 first (a learned join capacity, a scan read from the buffer pool).
 """
 
+import json
 import os
 import sys
 
@@ -97,3 +98,63 @@ def test_replay_compiles_nothing(watch, runners, kind, cell, query):
     replay = runner.execute(st.sql).rows
     assert watch.compiles == before, st.params
     assert replay == rows
+
+
+#: a second draw of Q3's literals on the mesh: MACHINERY's customers span
+#: keys 8..1493 where SEED's BUILDING span 2..1500 at `tiny`, so its
+#: customer build has another key domain at the same table size
+SECOND_DRAW = 3000000033
+
+#: the mesh join's programs (`parallel/runner.py` `_sized_expansion`)
+JOIN_PROGRAMS = ("licensed_expand", "fused_expand", "locate", "expand")
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    """The names of the programs compiled (or loaded) since it was cleared."""
+    import jax
+
+    names: list = []
+
+    def listen(event, secs, fun_name=None, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(str(fun_name))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    return names
+
+
+def test_mesh_q3_second_draw_compiles_no_join_program(
+    watch, runners, compiled
+):
+    """The direct-table locate's key domain is an argument of the mesh
+    join's programs, not a part of their keys: after one draw of Q3's
+    literals is warm, a draw whose builds span other keys compiles its own
+    scans' predicates and no join program, and then replays compiling
+    nothing."""
+    cell = spec.Cell("tpch_sf1_mesh4.partitioned_join")
+    runner = runners("mesh4", cell.config["catalog"])
+    (first,) = traffic.Mix(cell.traffic, SEED).warmup()
+    (second,) = traffic.Mix(cell.traffic, SECOND_DRAW).warmup()
+    assert (first.params["segment"], second.params["segment"]) == (
+        "BUILDING", "MACHINERY"
+    )
+    for _ in range(WARMUP_PASSES):
+        before = watch.compiles
+        runner.execute(first.sql)
+        if watch.compiles == before:
+            break
+    compiled.clear()
+    rows = runner.execute(second.sql).rows
+    _, flat = runner.traces[-1]
+    assert [
+        json.loads(s["attributes"])["locate"]
+        for s in flat if s["name"] == "join"
+    ] == ["join_locate_table"] * 2
+    assert not [
+        n for n in compiled
+        if n.removeprefix("jit(").removesuffix(")") in JOIN_PROGRAMS
+    ], compiled
+    before = watch.compiles
+    assert runner.execute(second.sql).rows == rows
+    assert watch.compiles == before

@@ -198,6 +198,30 @@ def test_locate_sorted_compiles(one_chip, cap_b, probe):
 
 
 @pytest.mark.parametrize(
+    "cap_b,probe,tcap", [(1 << 15, 1 << 18, 1 << 18), (1 << 18, 1 << 20, 1 << 21)],
+    ids=["build32K_probe256K", "build256K_probe1M"],
+)
+def test_locate_by_table_compiles(one_chip, cap_b, probe, tcap):
+    """The mesh join's per-shard locate at Q3's SF1 shapes on four chips
+    (customer's 150 K keys, orders' 1.5 M): the start/count tables built
+    in the program, then two gathers a probe row."""
+    from trino_tpu.ops.join import _locate_by_table
+
+    S = _shapes(one_chip)
+    t0 = time.perf_counter()
+    jax.jit(
+        lambda b, nm, p, pn, mins, widths: _locate_by_table(
+            [b], nm, [p], pn, mins, widths, tcap=tcap
+        )
+    ).lower(
+        S((cap_b,), jnp.int64), S((), jnp.int64),
+        S((probe,), jnp.int64), S((probe,), jnp.bool_),
+        S((1,), jnp.int64), S((1,), jnp.int64),
+    ).compile()
+    assert time.perf_counter() - t0 < 60
+
+
+@pytest.mark.parametrize(
     "cap,outc", [(1 << 19, 1 << 10), (1 << 20, 1 << 19)],
     ids=["512K_to_1K", "1M_to_512K"],
 )

@@ -172,22 +172,11 @@ def _prepare_sorted_build(build: Batch, key_channels: Sequence[int]):
                 composite = composite * w + (d.astype(jnp.int64) - mn)
             composite = jnp.where(nomatch, total, composite)
             perm = jnp.argsort(composite, stable=True)
-            if total <= TABLE_DOMAIN_LIMIT and total <= max(
-                64 * n_match, TABLE_SPARSE_LIMIT
-            ):
-                # direct-addressed probe tables over the packed key domain:
-                # start/count per composite code, O(1) gather per probe row
-                # (the PagesHash open-addressing analog, but positional)
-                tcap = next_pow2(total, floor=16)
-                c_sorted = jnp.take(composite, perm, mode="clip")
-                pos = jnp.arange(cap, dtype=jnp.int64)
-                cs = jnp.minimum(c_sorted, tcap)
-                start_t = jax.ops.segment_min(
-                    jnp.where(c_sorted < total, pos, cap), cs, tcap + 1
-                )[:tcap].astype(jnp.int32)
-                count_t = jax.ops.segment_sum(
-                    (c_sorted < total).astype(jnp.int32), cs, tcap + 1
-                )[:tcap]
+            tcap = direct_table_cap(total, n_match)
+            if tcap is not None:
+                start_t, count_t = _direct_table(
+                    jnp.take(composite, perm, mode="clip"), total, tcap
+                )
                 table = (
                     jnp.asarray(np.asarray(mins, dtype=np.int64)),
                     jnp.asarray(np.asarray(widths, dtype=np.int64)),
@@ -233,23 +222,79 @@ TABLE_DOMAIN_LIMIT = 1 << 25
 TABLE_SPARSE_LIMIT = 1 << 21
 
 
-def _locate_table(probe_canon, probe_nomatch, mins, widths, start_t, count_t):
-    """O(1)-per-row probe: composite code -> (start, count) table gather."""
-    n = probe_canon[0].shape[0]
-    code = jnp.zeros(n, dtype=jnp.int64)
-    nomatch = probe_nomatch
-    for i, pk in enumerate(probe_canon):
-        k = pk.astype(jnp.int64) - mins[i]
+def direct_table_cap(total: int, n_match: int) -> Optional[int]:
+    """The static size of the direct start/count tables over a packed key
+    domain of `total` codes that `n_match` build rows can match, or None
+    when the domain is too large, or too sparse for its rows, to be worth
+    them: the sorted locate then.  The one rule of the local build and the
+    mesh join (which reads `total` off its dynamic-filter summary)."""
+    if 0 < total <= TABLE_DOMAIN_LIMIT and total <= max(
+        64 * n_match, TABLE_SPARSE_LIMIT
+    ):
+        return next_pow2(total, floor=16)
+    return None
+
+
+def _direct_table(c_sorted, total, tcap: int):
+    """Direct-addressed probe tables over a packed key domain (the
+    PagesHash open-addressing analog, but positional): for each of the
+    `tcap` composite codes, the first row holding it in the sorted build
+    and how many rows do.  `c_sorted` is the build's composite code in its
+    sorted row order, `total` (or more) on a row no key can match."""
+    cap = c_sorted.shape[0]
+    pos = jnp.arange(cap, dtype=jnp.int64)
+    cs = jnp.minimum(c_sorted, tcap)
+    start_t = jax.ops.segment_min(
+        jnp.where(c_sorted < total, pos, cap), cs, tcap + 1
+    )[:tcap].astype(jnp.int32)
+    count_t = jax.ops.segment_sum(
+        (c_sorted < total).astype(jnp.int32), cs, tcap + 1
+    )[:tcap]
+    return start_t, count_t
+
+
+def _pack_codes(canon, nomatch, mins, widths):
+    """(composite code, nomatch) of key rows over the packed domain of
+    `mins` / `widths`: a key outside its range matches nothing."""
+    code = jnp.zeros(canon[0].shape[0], dtype=jnp.int64)
+    for i, d in enumerate(canon):
+        k = d.astype(jnp.int64) - mins[i]
         nomatch = jnp.logical_or(
             nomatch, jnp.logical_or(k < 0, k >= widths[i])
         )
         code = code * widths[i] + jnp.clip(k, 0, jnp.maximum(widths[i] - 1, 0))
+    return code, nomatch
+
+
+def _locate_table(probe_canon, probe_nomatch, mins, widths, start_t, count_t):
+    """O(1)-per-row probe: composite code -> (start, count) table gather."""
+    code, nomatch = _pack_codes(probe_canon, probe_nomatch, mins, widths)
     idx = jnp.clip(code, 0, start_t.shape[0] - 1)
     start = jnp.take(start_t, idx, mode="clip").astype(jnp.int64)
     count = jnp.where(
         nomatch, 0, jnp.take(count_t, idx, mode="clip").astype(jnp.int64)
     )
     return jnp.where(nomatch, 0, start), count
+
+
+def _locate_by_table(build_canon, n_match, probe_canon, probe_nomatch,
+                     mins, widths, tcap: int):
+    """`_locate_sorted`'s contract by direct table, for a build already in
+    `_sort_build_device`'s order whose key domain (`mins`, `widths`: run-time
+    arrays, so a moved domain compiles nothing) `direct_table_cap` admitted
+    at `tcap` codes: the tables are built here, in the caller's program,
+    and each probe row costs two gathers, not 2 x log2(cap_b)."""
+    cap = build_canon[0].shape[0]
+    total = jnp.prod(widths)
+    code, nomatch = _pack_codes(
+        build_canon, jnp.arange(cap, dtype=jnp.int64) >= n_match, mins, widths
+    )
+    start_t, count_t = _direct_table(
+        jnp.where(nomatch, total, code), total, tcap
+    )
+    return _locate_table(
+        probe_canon, probe_nomatch, mins, widths, start_t, count_t
+    )
 
 
 def _locate_sorted(build_canon, n_match, probe_canon, probe_nomatch, cap_b: int):

@@ -45,6 +45,7 @@ bench JSON so mesh regressions are visible per fragment.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -63,8 +64,10 @@ from trino_tpu.ops.join import (
     HashJoinOperator,
     SemiJoinOperator,
     _canon_probe_device,
+    _locate_by_table,
     _locate_sorted,
     _sort_build_device,
+    direct_table_cap,
 )
 from trino_tpu.ops.sort import OrderByOperator, TopNOperator
 from trino_tpu.parallel import exchange as ex
@@ -832,17 +835,19 @@ class StageExecutor:
 
     # -- exchanges ------------------------------------------------------------
 
-    def _register_dynamic_filters(self, criteria, build: "_Dist") -> None:
+    def _register_dynamic_filters(self, criteria, build: "_Dist") -> list:
         """Record build-side key min/max under the probe symbol names.
         Dictionary-coded keys are skipped (codes are producer-local).
         ALL summaries reduce in ONE cached program and cross to the host in
         ONE transfer (batched small collectives): k criteria cost the same
-        sync as one."""
-        pairs = []  # (probe name, channel)
+        sync as one.  Returns each criterion's global (lo, hi, live rows),
+        None for a key it skipped: the join's key domain."""
+        out: list = [None] * len(criteria)
+        pairs = []  # (criterion index, probe name, channel)
         # materialize pending steps first: deferred projections may have
         # changed a key column's dictionary, which the skip check reads
         stacked = build.stacked
-        for lsym, rsym in criteria:
+        for i, (lsym, rsym) in enumerate(criteria):
             try:
                 chn = build.channel(rsym.name)
             except KeyError:
@@ -852,10 +857,10 @@ class StageExecutor:
                 col.data.dtype, jnp.floating
             ):
                 continue
-            pairs.append((lsym.name, chn))
+            pairs.append((i, lsym.name, chn))
         if not pairs:
-            return
-        chans = tuple(ch for _, ch in pairs)
+            return out
+        chans = tuple(ch for _, _, ch in pairs)
 
         def build_step():
             def step(b: Batch):
@@ -893,13 +898,15 @@ class StageExecutor:
             self._current_fid, int(summ.nbytes), "reduce", "dynamic_filter"
         )
         # [W, k, 3] -> per-criterion global (lo, hi, n)
-        for i, (name, _) in enumerate(pairs):
-            lo = int(summ[:, i, 0].min())
-            hi = int(summ[:, i, 1].max())
-            n = int(summ[:, i, 2].sum())
+        for j, (i, name, _) in enumerate(pairs):
+            lo = int(summ[:, j, 0].min())
+            hi = int(summ[:, j, 1].max())
+            n = int(summ[:, j, 2].sum())
+            out[i] = (lo, hi, n)
             if n == 0:
                 continue
             self.dynamic_filters[name] = (lo, hi)
+        return out
 
     def _raw_remote(self, node: RemoteSourceNode):
         """Child fragment result WITHOUT the exchange applied."""
@@ -1699,12 +1706,15 @@ class StageExecutor:
         # filters collected from build tasks reach probe scans before
         # splits feed)
         build = self._join_side(build_node)
-        if node.kind == "inner":
+        domain = (
             self._register_dynamic_filters(node.criteria, build)
+            if node.kind == "inner" else None
+        )
         probe = self._join_side(probe_node)
         pk = [probe.channel(l.name) for l, _ in node.criteria]
         bk = [build.channel(r.name) for _, r in node.criteria]
         probe, build = self._unify_key_dicts(probe, pk, build, bk)
+        tcap, dom = self._table_domain(domain)
         out_symbols = probe.symbols + build.symbols
         residual = None
         residual_key = None
@@ -1718,7 +1728,9 @@ class StageExecutor:
         # one `join` span per join operator (the local runner's name): from
         # both sides ready to the joined output; the sides' own work is outside
         with self.profile.tracer.span(
-            "join", kind=node.kind, strategy=node.distribution
+            "join", kind=node.kind, strategy=node.distribution,
+            locate="join_locate_sorted" if tcap is None
+            else "join_locate_table",
         ):
             did = node.decision_id
             if node.distribution == "broadcast":
@@ -1760,7 +1772,7 @@ class StageExecutor:
             )
             cap_b = _trailing_cap(build_stacked)
             jkey = (
-                node.kind, tuple(pk), tuple(bk), cap_b,
+                node.kind, tuple(pk), tuple(bk), cap_b, tcap,
                 _sig(probe.symbols), _sig(build.symbols), residual_key,
             )
             # capacity-history discriminator: two queries can share the same
@@ -1813,11 +1825,12 @@ class StageExecutor:
                 with decision_scope(wdid):
                     out = self._wave_join(
                         node, op, probe_stacked, build_stacked, pk, bk, jkey,
-                        probe_types, wave_k, ctx,
+                        probe_types, wave_k, ctx, tcap, dom,
                     )
             else:
                 locate, device_emit_total, expand = self._join_step_fns(
-                    node, op, pk, bk, _trailing_cap(build_stacked), probe_types
+                    node, op, pk, bk, _trailing_cap(build_stacked),
+                    probe_types, tcap,
                 )
                 # proof-licensed capacity (verify/capacity.py): a certificate
                 # sealed for THIS mesh width licenses a fixed expand capacity
@@ -1837,7 +1850,7 @@ class StageExecutor:
                     ("join",) + jkey, probe_stacked, build_stacked,
                     locate, device_emit_total, expand, compact_probe=True,
                     stats_key=("join",) + jkey + (probe_fp,),
-                    cert=cert,
+                    cert=cert, dom=dom,
                 )
                 ctx.close()
         return self._dist(
@@ -1848,10 +1861,40 @@ class StageExecutor:
             realigned=probe.realigned or node.distribution != "broadcast",
         )
 
-    def _join_step_fns(self, node, op, pk, bk, cap_b: int, probe_types):
+    def _table_domain(self, domain):
+        """(tcap, (mins, widths)) when the direct-table locate serves a
+        join whose build keys span `domain` (`_register_dynamic_filters`'
+        per-criterion (lo, hi, live rows); None for a join that is not
+        inner): `tcap` is the tables' static size, the only part of the
+        domain a program's key holds, and `mins` / `widths` are [W, k]
+        run-time arguments.  (None, ()) for the sorted locate: a key the
+        summary skipped, an empty build, a domain too large or too sparse
+        for tables."""
+        if not domain or any(d is None or d[2] == 0 for d in domain):
+            return None, ()
+        mins = [lo for lo, _, _ in domain]
+        widths = [hi - lo + 1 for lo, hi, _ in domain]
+        tcap = direct_table_cap(
+            math.prod(widths), min(n for _, _, n in domain)
+        )
+        if tcap is None:
+            return None, ()
+        W = self.wm.n
+        return tcap, tuple(
+            jax.device_put(
+                np.tile(np.asarray([v], dtype=np.int64), (W, 1)),
+                self.wm.sharding(),
+            )
+            for v in (mins, widths)
+        )
+
+    def _join_step_fns(self, node, op, pk, bk, cap_b: int, probe_types,
+                       tcap):
         """(locate, device_emit_total, expand) closures for one build
         capacity — shared by the direct path and the per-wave path (which
-        runs them at the wave's smaller build bucket)."""
+        runs them at the wave's smaller build bucket).  With `tcap`,
+        locate takes the domain's (mins, widths) after its two batches and
+        probes by direct table (`_table_domain`)."""
 
         def device_emit_total(pb: Batch, count):
             """Per-worker emitted-row total, ON DEVICE (what the pre-PR
@@ -1864,14 +1907,20 @@ class StageExecutor:
             )
             return jnp.sum(emit, dtype=jnp.int64)
 
-        def locate(pb: Batch, bb: Batch):
+        def locate(pb: Batch, bb: Batch, *dom):
             # per-shard PagesHash analog: sort THIS shard's build once,
-            # then binary-search the probe keys against it
+            # then index its run of each probe key by direct table over
+            # the domain, or binary-search the sorted keys for it
             sb, canon, n_match = _sort_build_device(bb, bk)
             pc, pn = _canon_probe_device(pb, pk, canon)
-            start, count = _locate_sorted(
-                canon, n_match, pc, pn, cap_b=cap_b
-            )
+            if tcap is None:
+                start, count = _locate_sorted(
+                    canon, n_match, pc, pn, cap_b=cap_b
+                )
+            else:
+                start, count = _locate_by_table(
+                    canon, n_match, pc, pn, *dom, tcap=tcap
+                )
             return sb, start, count
 
         def expand(pb: Batch, sb: Batch, start, count, total, out_cap: int):
@@ -1903,7 +1952,8 @@ class StageExecutor:
         return locate, device_emit_total, expand
 
     def _wave_join(self, node, op, probe_stacked, build_stacked, pk, bk,
-                   jkey, probe_types, n_waves: int, ctx) -> Batch:
+                   jkey, probe_types, n_waves: int, ctx, tcap,
+                   dom) -> Batch:
         """Mesh partition-wave join (SpillingJoinProcessor on the mesh):
         both stacked sides pull host-side, hash-partition per worker shard
         by the exchange row-value hash into `n_waves` partitions (spilled
@@ -2000,7 +2050,7 @@ class StageExecutor:
             p_load = store("jp", p_parts)
 
             locate, emit_total, expand = self._join_step_fns(
-                node, op, pk, bk, cap_b, probe_types
+                node, op, pk, bk, cap_b, probe_types, tcap
             )
             wkey = ("join_wave", n_waves, cap_b, cap_p) + jkey
             outs = []
@@ -2021,7 +2071,7 @@ class StageExecutor:
                 outs.append(
                     self._sized_expansion(
                         wkey, probe_w, build_w, locate, emit_total, expand,
-                        compact_probe=False, stats_key=wkey,
+                        compact_probe=False, stats_key=wkey, dom=dom,
                     )
                 )
             if not outs:
@@ -2036,7 +2086,7 @@ class StageExecutor:
                 outs.append(
                     self._sized_expansion(
                         wkey, probe_w, build_w, locate, emit_total, expand,
-                        compact_probe=False, stats_key=wkey,
+                        compact_probe=False, stats_key=wkey, dom=dom,
                     )
                 )
             out = _concat_stacked(outs)
@@ -2051,7 +2101,7 @@ class StageExecutor:
     def _sized_expansion(self, key, probe_stacked, build_stacked,
                          locate, device_total, expand,
                          compact_probe: bool = False,
-                         stats_key=None, cert=None) -> Batch:
+                         stats_key=None, cert=None, dom=()) -> Batch:
         """Run a locate+expand pair whose static output capacity depends on
         the data, under the `join_speculative_capacity` policy:
 
@@ -2080,7 +2130,10 @@ class StageExecutor:
         total by the probe batch's STATIC capacity, so the expand compiles
         at the certified fixed capacity with NO sizing gather, NO overflow
         flag, and NO retry — zero `join_overflow_check`, zero
-        `gather/capacity_sizing` bytes, cold and warm alike."""
+        `gather/capacity_sizing` bytes, cold and warm alike.
+
+        `dom`: run-time arguments every program passes to `locate` after
+        its two batches (a direct-table locate's key domain)."""
         cap_p = _trailing_cap(probe_stacked)
         fid = self._current_fid
         spec = speculation_mode(self.properties)
@@ -2143,8 +2196,8 @@ class StageExecutor:
                 )
 
                 def build_licensed(_oc=oc):
-                    def step(pb: Batch, bb: Batch):
-                        sb, start, count = locate(pb, bb)
+                    def step(pb: Batch, bb: Batch, *dom):
+                        sb, start, count = locate(pb, bb, *dom)
                         total = device_total(pb, count)
                         return expand(pb, sb, start, count, total, _oc)
 
@@ -2155,7 +2208,7 @@ class StageExecutor:
                     build_licensed,
                 )
                 with decision_scope(did):
-                    out = self._call(fn, probe_stacked, build_stacked)
+                    out = self._call(fn, probe_stacked, build_stacked, *dom)
                     self.profile.bump("join_capacity_proven")
                     join_capacity_counter().labels("proven").inc()
                     if oc > 1024:
@@ -2195,12 +2248,12 @@ class StageExecutor:
             pcap = min(pcap, cap_p)
 
             def build_fused(oc=out_cap, pc=pcap):
-                def step(pb: Batch, bb: Batch):
+                def step(pb: Batch, bb: Batch, *dom):
                     live = jnp.sum(pb.mask(), dtype=jnp.int64)
                     over = live > pc
                     if pc < cap_p:
                         pb = pb.compact_device(out_capacity=pc)
-                    sb, start, count = locate(pb, bb)
+                    sb, start, count = locate(pb, bb, *dom)
                     total = device_total(pb, count)
                     over = jnp.logical_or(over, total > oc)
                     return (
@@ -2217,7 +2270,7 @@ class StageExecutor:
             )
             with decision_scope(did):
                 out, total, live, over = self._call(
-                    fn, probe_stacked, build_stacked
+                    fn, probe_stacked, build_stacked, *dom
                 )
                 with self.profile.phase(fid, "transfer"):
                     over_h, total_h, live_h = self._host_pull(
@@ -2244,8 +2297,8 @@ class StageExecutor:
 
         # sizing pass: locate + one [W] totals read + exactly-sized expand
         def build_locate():
-            def step(pb: Batch, bb: Batch):
-                sb, start, count = locate(pb, bb)
+            def step(pb: Batch, bb: Batch, *dom):
+                sb, start, count = locate(pb, bb, *dom)
                 live = jnp.sum(pb.mask(), dtype=jnp.int64)
                 return sb, start, count, device_total(pb, count), live
 
@@ -2254,7 +2307,7 @@ class StageExecutor:
         loc = cached_spmd_step(self.wm, ("locate",) + key, build_locate)
         with decision_scope(did):
             sb, start, count, total_dev, live_dev = self._call(
-                loc, probe_stacked, build_stacked
+                loc, probe_stacked, build_stacked, *dom
             )
             with self.profile.phase(fid, "transfer"):
                 totals, lives = self._host_pull(total_dev, live_dev)

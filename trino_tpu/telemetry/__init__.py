@@ -31,7 +31,9 @@ mesh under its `fragment-N`, from both sides ready to the joined output):
     join_locate_sorted | join_nested_expand, or partition_waves for a build
     over the memory budget, which has no build_rows and no counts (its
     operators are made wave by wave); mesh: broadcast |
-    partitioned | colocated), keys (local: its equi-key columns, 0 for a
+    partitioned | colocated), locate (mesh: the locate its shards run,
+    join_locate_table when the build keys' domain gets direct tables,
+    else join_locate_sorted), keys (local: its equi-key columns, 0 for a
     cross join), build_rows and, for a hash join, what it
     reads to size its output anyway: probe_rows, out_rows (matches emitted,
     before a residual filter), null_keys (probe rows dropped for a NULL
