@@ -767,6 +767,33 @@ def test_vocabulary_parses():
     assert not _step_in_vocabulary("chain_local", steps)
 
 
+def test_feed_span_vocabulary(runner):
+    """The feed spans, their attributes and values are listed once, and
+    the engine keeps to them on every thread kind it runs."""
+    import trino_tpu.telemetry as telemetry
+
+    section = telemetry.__doc__.split("feed spans", 1)[1].split("\n\n", 1)[0]
+    listed = set(re.findall(r"[a-z][a-z_-]+", section))
+    assert {"feed", "feed_launch", "feed_pull", "feed_wait"} <= (
+        _vocabulary("span names") & listed
+    )
+    assert {"thread", "feed", "by", "statement", "eager-prefetch",
+            "scan-prefetch", "local-exchange"} <= listed
+    _, flat = _run_with_context(runner, _tpch(3))
+    seen = set()
+    for s in flat:
+        a = _attrs(s)
+        if s["name"] == "feed":
+            assert set(a) == {"thread"} and a["thread"] in listed
+            seen.add(a["thread"])
+        elif s["name"] == "feed_wait":
+            assert set(a) == {"feed", "by"}
+            assert a["feed"] in listed and a["by"] in listed
+        elif s["name"] == "feed_pull":
+            assert a["why"] in _vocabulary("host_pull why")
+    assert "eager-prefetch" in seen
+
+
 def test_join_span_vocabulary_and_counter(runner):
     """The `join` span's attributes and values are listed once, and the
     engine keeps to them; its NULL-key count is a registered counter."""
@@ -808,10 +835,21 @@ def test_local_execute_has_build_result_launch_and_pull(runner):
     assert all(_attrs(p)["why"] in whys for p in pulls)
     results = [p for p in pulls if _attrs(p)["why"] == "result"]
     assert results and all(_attrs(p)["bytes"] > 0 for p in results)
-    # the pull of the rows sits in `result` and names the launch before it
+    # the pull of the rows sits in `result`, and carries no guess at what
+    # the device ran before it: the profiler trace tells that
     last = results[-1]
     assert by_id[last["parent_id"]]["name"] == "result"
-    assert _attrs(last)["after"] in steps
+    assert set(_attrs(last)) == {"why", "bytes"}
+    # the scan's split readers ran on lanes of their own, under `build`
+    # (where the feed was made), each its door spans beneath it
+    feeds = [s for s in flat if s["name"] == "feed"]
+    assert feeds
+    for f in feeds:
+        assert _attrs(f)["thread"] in ("local-exchange", "scan-prefetch")
+        assert by_id[f["parent_id"]]["name"] == "build"
+    for s in flat:
+        if s["name"] in ("feed_launch", "feed_pull"):
+            assert by_id[s["parent_id"]]["name"] == "feed"
     assert {s["name"] for s in flat} <= _vocabulary("span names")
 
 

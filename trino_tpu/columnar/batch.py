@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from trino_tpu.columnar.column import Column
-from trino_tpu.runtime.lifecycle import current_query
+from trino_tpu.runtime.lifecycle import current_feed, current_query
 from trino_tpu.telemetry.programs import jit_program, note_path, recording_tracer
 from trino_tpu.telemetry.spans import now
 
@@ -136,10 +136,11 @@ def host_pull(tree, why: str):
     seconds this thread was blocked and the bytes of the device leaves;
     with `query_trace` on it also opens a `host_pull` span for the wait,
     carrying `why` (what the host needs the value for — the closed
-    vocabulary is in `trino_tpu.telemetry`'s docstring), `bytes` and
-    `after` (the `step` of the statement's newest launch: what the device
-    was most likely still running).  The tracer is not thread-safe: a pull
-    made off the statement's own thread is counted but records no span."""
+    vocabulary is in `trino_tpu.telemetry`'s docstring) and `bytes`.  On
+    one of the statement's feed threads the pull and its seconds go to the
+    thread's `FeedTally` instead, and the span on its lane is a
+    `feed_pull`: the statement's fields and `host_pull` spans are its own
+    thread's alone."""
     nbytes = 0
     for leaf in jax.tree_util.tree_leaves(tree):
         if hasattr(leaf, "copy_to_host_async"):
@@ -151,18 +152,24 @@ def host_pull(tree, why: str):
     ctx = current_query()
     if ctx is None:
         return jax.device_get(tree)
-    tracer = recording_tracer(ctx)
+    feed = current_feed()
+    tracer = recording_tracer(ctx) if feed is None else feed.tracer
     t0 = now()
     if tracer is None:
         out = jax.device_get(tree)
     else:
         with tracer.span(
-            "host_pull", why=why, bytes=nbytes, after=ctx.last_step
+            "host_pull" if feed is None else "feed_pull",
+            why=why, bytes=nbytes,
         ):
             out = jax.device_get(tree)
-    ctx.host_pulls += 1
-    ctx.host_pull_s += now() - t0
-    ctx.d2h_bytes += nbytes
+    if feed is None:
+        ctx.host_pulls += 1
+        ctx.host_pull_s += now() - t0
+        ctx.d2h_bytes += nbytes
+    else:
+        feed.pulls += 1
+        feed.pull_s += now() - t0
     return out
 
 

@@ -382,9 +382,12 @@ class JoinSpan:
     reads them anyway, `probe_rows`, `out_rows` (matches emitted, before a
     residual filter) and `null_keys` (probe rows dropped for a NULL key).
     Made while planning, on the statement's thread; the stream may then
-    run on a prefetch thread, where the span only moves its own ends (the
-    doors record nothing off the statement's thread, so no `launch` or
-    `host_pull` nests there)."""
+    run on a feed thread (a join below another join runs on its probe
+    prefetch), whose lane the span is pushed on instead: the operator's
+    `feed_launch` and `feed_pull` spans nest under it there.  A hash join
+    makes its span before its probe feed, so that the feed's lane hangs
+    under it, and names its strategy once the build is indexed
+    (`chose()`)."""
 
     __slots__ = ("tracer", "sp")
 
@@ -400,18 +403,26 @@ class JoinSpan:
         self.sp = self.tracer.held(
             "join", under="execute", kind=kind, keys=keys
         )
-        if self.sp is not None:
-            if op is not None:
-                self.sp.attrs["strategy"] = op.strategy
-                self.sp.attrs["build_rows"] = op.build_rows
-            else:
-                self.sp.attrs["strategy"] = strategy
+        if op is not None or strategy:
+            self.chose(op, strategy)
+
+    def chose(self, op=None, strategy: str = "") -> None:
+        """The build's choice: `op`'s locate step and build rows, or a
+        `strategy` with no operator (partition_waves)."""
+        if self.sp is None:
+            return
+        if op is not None:
+            self.sp.attrs["strategy"] = op.strategy
+            self.sp.attrs["build_rows"] = op.build_rows
+        else:
+            self.sp.attrs["strategy"] = strategy
 
     def wrap(self, process, probe_stream, op=None):
         """`process(probe_stream)` under the span: the operator's own work
-        on a batch is inside it (its launches and pulls nest there, on the
-        statement's thread), the probe side's stays outside.  `op`: the
-        operator whose counts the span takes when the stream is done."""
+        on a batch is inside it (its launches and pulls nest there, on
+        whichever recording thread runs the stream), the probe side's
+        stays outside.  `op`: the operator whose counts the span takes
+        when the stream is done."""
         tracer, sp = self.tracer, self.sp
         done = object()
         started = False

@@ -80,11 +80,18 @@ class QueryStatistics:
     profile_key: str = ""
     #: the launch/pull boundary's always-on counts (lifecycle.QueryContext):
     #: device programs dispatched, blocking device->host reads, the seconds
-    #: the statement's threads were blocked in them, bytes read back
+    #: the statement's own thread was blocked in them, bytes read back
     launches: int = 0
     host_pulls: int = 0
     host_pull_s: float = 0.0
     d2h_bytes: int = 0
+    #: the same for the statement's feed threads (runtime/prefetch.Feed:
+    #: a join's probe prefetch, a scan's prefetch and split readers), and
+    #: the seconds its threads were blocked waiting on a feed for an item
+    feed_launches: int = 0
+    feed_pulls: int = 0
+    feed_pull_s: float = 0.0
+    feed_wait_s: float = 0.0
 
 
 @dataclass

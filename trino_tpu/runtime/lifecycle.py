@@ -258,15 +258,42 @@ class QueryContext:
         # -- the launch/pull boundary's always-on counts (bumped by
         # telemetry/programs.Program and columnar/batch.host_pull, copied
         # into QueryStatistics when the statement finishes)
-        #: device programs dispatched for this statement
+        #: device programs dispatched by the statement's own thread
         self.launches = 0
-        #: blocking device->host reads, the seconds the reading thread
-        #: was blocked in them, and the bytes they brought back
+        #: blocking device->host reads of the statement's own thread, the
+        #: seconds it was blocked in them, and the bytes they brought back
         self.host_pulls = 0
         self.host_pull_s = 0.0
         self.d2h_bytes = 0
-        #: `step` of the newest launch (`after=` of the next host_pull)
-        self.last_step = ""
+        #: the FeedTally of each of the statement's feed threads, handed
+        #: over as the thread ends (`list.append` is one step under the
+        #: GIL: lanes that end at once lose nothing, and the statement's
+        #: thread takes no lock); the `feed_*` properties sum them
+        self.feed_tallies: list = []
+        #: seconds the statement's own thread was blocked on a feed
+        self.stmt_feed_wait_s = 0.0
+
+    # -- the feed threads' counts ---------------------------------------------
+
+    @property
+    def feed_launches(self) -> int:
+        """Device programs the statement's feed threads dispatched."""
+        return sum(t.launches for t in self.feed_tallies)
+
+    @property
+    def feed_pulls(self) -> int:
+        """Blocking device->host reads of the statement's feed threads."""
+        return sum(t.pulls for t in self.feed_tallies)
+
+    @property
+    def feed_pull_s(self) -> float:
+        return sum(t.pull_s for t in self.feed_tallies)
+
+    @property
+    def feed_wait_s(self) -> float:
+        """Seconds the statement's threads were blocked waiting on a feed
+        for an item, its own thread and its feed threads together."""
+        return self.stmt_feed_wait_s + sum(t.wait_s for t in self.feed_tallies)
 
     # -- state machine --------------------------------------------------------
 
@@ -484,6 +511,44 @@ _CURRENT: "contextvars.ContextVar[Optional[QueryContext]]" = (
 
 def current_query() -> Optional[QueryContext]:
     return _CURRENT.get()
+
+
+class FeedTally:
+    """What one feed thread did for its statement: launches, pulls and the
+    seconds it was blocked in pulls and in waits on a feed below it.  The
+    thread owns it and books without a lock; `QueryContext.feed_tallies`
+    takes it when the thread ends.  `tracer` is the thread's `Lane`, None
+    with tracing off."""
+
+    __slots__ = ("kind", "tracer", "launches", "pulls", "pull_s", "wait_s")
+
+    def __init__(self, kind: str, tracer=None):
+        self.kind = kind
+        self.tracer = tracer
+        self.launches = 0
+        self.pulls = 0
+        self.pull_s = 0.0
+        self.wait_s = 0.0
+
+
+#: the tally of the feed thread running now (runtime/prefetch.Feed sets it
+#: with `_CURRENT` for the thread's life); None on the statement's thread
+_FEED: "contextvars.ContextVar[Optional[FeedTally]]" = contextvars.ContextVar(
+    "trino_tpu_feed", default=None
+)
+
+
+def current_feed() -> Optional[FeedTally]:
+    return _FEED.get()
+
+
+def set_feed(tally: Optional[FeedTally]):
+    """Install `tally` as the running feed thread's; returns the token."""
+    return _FEED.set(tally)
+
+
+def reset_feed(token) -> None:
+    _FEED.reset(token)
 
 
 def set_current(ctx: Optional[QueryContext]):

@@ -16,6 +16,8 @@ import queue
 import threading
 from typing import Callable, Iterable, Iterator, Sequence
 
+from trino_tpu.runtime.prefetch import Feed
+
 
 _DONE = object()
 
@@ -32,11 +34,14 @@ def parallel_feed(
     items after a failure are dropped, not yielded).  If the CONSUMER
     abandons the generator (LIMIT, downstream error), the finally block
     stops the producers and drains the queue so no thread stays blocked on a
-    full buffer pinning device batches."""
+    full buffer pinning device batches.  The producers work for the
+    statement that called this (`prefetch.Feed`, kind local-exchange)."""
     if workers <= 1 or len(makers) <= 1:
-        for mk in makers:
-            yield from mk()
-        return
+        return (item for mk in makers for item in mk())
+    return _exchange(Feed("local-exchange"), makers, workers, buffer)
+
+
+def _exchange(feed: Feed, makers, workers: int, buffer: int) -> Iterator:
     q: "queue.Queue" = queue.Queue(maxsize=max(buffer, workers))
     pending = list(enumerate(makers))
     lock = threading.Lock()
@@ -44,14 +49,14 @@ def parallel_feed(
     n_workers = min(workers, len(makers))
     errors: list = []
 
-    def worker():
+    def produce(tally):
         while not stop.is_set():
             with lock:
                 if errors or not pending:
                     break
                 _, mk = pending.pop(0)
             try:
-                for item in mk():
+                for item in feed.items(mk(), tally):
                     if stop.is_set() or errors:
                         break
                     q.put(item)
@@ -59,6 +64,9 @@ def parallel_feed(
                 with lock:
                     errors.append(e)
                 break
+
+    def worker():
+        feed.run(produce)
         q.put(_DONE)
 
     threads = [
@@ -72,7 +80,7 @@ def parallel_feed(
     try:
         done = 0
         while done < n_workers:
-            item = q.get()
+            item = feed.take(q)
             if item is _DONE:
                 done += 1
                 continue

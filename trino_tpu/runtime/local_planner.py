@@ -490,9 +490,14 @@ class LocalExecutionPlanner:
         # pipeline parallelism (§2.7(4)): the probe feed starts decoding NOW,
         # overlapping the build side's device-side compaction/indexing.
         # Planned AFTER the build drain so dynamic filters still apply.
+        # Made under the join's span, so the feed's lane hangs under it.
         from trino_tpu.runtime.prefetch import eager_prefetch
 
-        probe = PhysicalPlan(eager_prefetch(probe.stream, depth=2), probe.symbols)
+        span = JoinSpan(node.kind, keys=len(node.criteria))
+        with span.tracer.entered(span.sp):
+            probe = PhysicalPlan(
+                eager_prefetch(probe.stream, depth=2), probe.symbols
+            )
         out_symbols = probe.symbols + build.symbols
         probe_keys = [probe.channel(l.name) for l, _ in node.criteria]
         build_keys = [build.channel(r.name) for _, r in node.criteria]
@@ -558,13 +563,11 @@ class LocalExecutionPlanner:
                     if spiller is not None:
                         spiller.close()
 
-            span = JoinSpan(
-                node.kind, strategy="partition_waves", keys=len(node.criteria)
-            )
+            span.chose(strategy="partition_waves")
             return PhysicalPlan(span.wrap(waves, probe.stream), out_symbols)
         op = make_op()
         op.set_build(build_batches)
-        span = JoinSpan(node.kind, op, keys=len(node.criteria))
+        span.chose(op)
         if node.kind == "full":
             # full outer tracks build-side matched flags across the whole
             # probe; a mid-stream revoke cannot split that state exactly,

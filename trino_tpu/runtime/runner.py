@@ -550,6 +550,10 @@ class LocalQueryRunner:
         stats.host_pulls = ctx.host_pulls
         stats.host_pull_s = round(ctx.host_pull_s, 6)
         stats.d2h_bytes = ctx.d2h_bytes
+        stats.feed_launches = ctx.feed_launches
+        stats.feed_pulls = ctx.feed_pulls
+        stats.feed_pull_s = round(ctx.feed_pull_s, 6)
+        stats.feed_wait_s = round(ctx.feed_wait_s, 6)
         adm = current_admission()
         if adm is not None:
             stats.group, stats.queued_s = adm[0], round(adm[1], 6)

@@ -11,6 +11,8 @@ served here as Prometheus text at GET /v1/metrics.
   * `programs` — the launch door: `jit_program` names every device program
     and records/counts its launches.  (The host-pull door,
     `columnar.batch.host_pull`, is its counterpart for device->host reads.)
+    Both record on every thread that works for a statement: its own, and
+    its feed threads' lanes (`runtime/prefetch.Feed`).
   * `metrics` — counters/gauges/histograms registered once and bumped
     everywhere; the single home for the engine's formerly scattered
     counters (MeshProfile, trace cache, buffer pool).
@@ -20,7 +22,21 @@ them; a new site takes a name from here or adds one here):
 
 span names:
     query, queued, analyze, optimize, fragment, execute, build, result,
-    schedule, fragment-N, transfer, launch, compile, host_pull, task, join
+    schedule, fragment-N, transfer, launch, compile, host_pull, task, join,
+    feed, feed_launch, feed_pull, feed_wait
+
+feed spans (`runtime/prefetch.Feed`; a statement's work away from its own
+thread):
+    feed (a feed thread's lane, from its first item to its last, under the
+    span innermost where the feed was made: the join's for a probe side,
+    else build or execute; attribute thread = eager-prefetch (a join's
+    probe side), scan-prefetch (a scan's split reader), local-exchange (one
+    of a scan's parallel split readers)), feed_launch and feed_pull (the
+    launch and host_pull doors on a lane: the attributes of a launch and of
+    a host_pull; the statement's own thread keeps `launch` and
+    `host_pull`), feed_wait (a consumer blocked on a feed's queue, on the
+    consuming thread: attribute feed = the producer's thread kind, by =
+    statement or the consuming lane's thread kind)
 
 join span (one per join operator per statement, under `execute`; on the
 mesh under its `fragment-N`, from both sides ready to the joined output):
@@ -38,10 +54,10 @@ mesh under its `fragment-N`, from both sides ready to the joined output):
     reads to size its output anyway: probe_rows, out_rows (matches emitted,
     before a residual filter), null_keys (probe rows dropped for a NULL
     key, counted by the locate program; summed in
-    `trino_tpu_join_null_keys_total`).  `launch` and `host_pull` spans
-    of the operator's own batches nest under it on the statement's thread;
-    a join below another join runs on a prefetch thread, where the doors
-    record nothing, and keeps its span and its counts all the same.
+    `trino_tpu_join_null_keys_total`).  The launch and pull spans of the
+    operator's own batches nest under it: `launch` and `host_pull` on the
+    statement's thread, `feed_launch` and `feed_pull` where a join below
+    another join runs, on the upper join's probe feed lane.
 
 launch steps (`step=` of a `launch` span; the XLA module is `jit_<step>`):
     local — filter_project, unnest, sample, window, sort, sort_merge,

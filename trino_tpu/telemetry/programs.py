@@ -8,28 +8,31 @@ store in place of a bare `jax.jit` wrapper.  It does three things:
     trace, the compile observatory and the benchmark's device-op breakdown
     carry the operator and step, not `jit_step` / `jit_local`;
   * every call bumps `QueryContext.launches` of the executing statement
-    (always on: one contextvar read and two attribute writes);
-  * with `query_trace` on it records a `launch` span carrying `step=` (and
-    `path=`, the kernel path the step chose while it traced, see
-    `note_path`).  The span is HOST DISPATCH time — the jit call to its
-    return, tracing and compiling included when the call traced; the
-    device runs the program afterwards, and its time is the profiler
-    trace's, under the same name.
+    when it is made on the statement's own thread, and the thread's
+    `FeedTally.launches` when it is made on one of its feed threads
+    (always on: two contextvar reads and one attribute write);
+  * with `query_trace` on it records a `launch` span (`feed_launch` on a
+    feed thread's lane) carrying `step=` (and `path=`, the kernel path
+    the step chose while it traced, see `note_path`).  The span is HOST
+    DISPATCH time — the jit call to its return, tracing and compiling
+    included when the call traced; the device runs the program
+    afterwards, and its time is the profiler trace's, under the same
+    name.
 
-The tracer keeps one open-span stack and is not thread-safe: a launch made
-off the statement's own thread is counted but records no span.
+A launch on a thread that is neither the statement's nor a feed's (one
+that copied the statement's context) is counted as the statement's and
+records no span: only the statement's thread and the lanes keep a stack.
 """
 
 from __future__ import annotations
 
 import contextvars
 import functools
-from threading import get_ident
 from typing import Callable, Optional
 
 import jax
 
-from trino_tpu.runtime.lifecycle import current_query
+from trino_tpu.runtime.lifecycle import current_feed, current_query
 from trino_tpu.telemetry.spans import now
 
 #: the Program whose step function jax is tracing right now (set by the
@@ -40,12 +43,13 @@ _TRACING: "contextvars.ContextVar[Optional[Program]]" = contextvars.ContextVar(
 
 
 def recording_tracer(ctx):
-    """The tracer a door may record on for the statement `ctx`, or None:
-    tracing off, or the caller is not the statement's own thread."""
+    """The tracer a door may record on for the statement `ctx`: the
+    statement's tracer on its own thread, the lane on a feed thread that
+    opened one; None with tracing off or on any other thread."""
     tracer = ctx.tracer
-    if tracer is None or not tracer.enabled or tracer.thread_id != get_ident():
+    if tracer is None or not tracer.enabled:
         return None
-    return tracer
+    return tracer.here()
 
 
 class Program:
@@ -73,9 +77,13 @@ class Program:
         ctx = current_query()
         tracer = None
         if ctx is not None:
-            ctx.launches += 1
-            ctx.last_step = self.step
-            tracer = recording_tracer(ctx)
+            feed = current_feed()
+            if feed is None:
+                ctx.launches += 1
+                tracer = recording_tracer(ctx)
+            else:
+                feed.launches += 1
+                tracer = feed.tracer
         if tracer is None:
             out = self.jitted(*args, **kwargs)
         else:
@@ -86,7 +94,9 @@ class Program:
                 attrs["path"] = self.path
             if self.div128_rows:
                 attrs["div128_rows"] = self.div128_rows
-            tracer.last_launch = tracer.record("launch", t0, now(), attrs)
+            tracer.last_launch = tracer.record(
+                "launch" if feed is None else "feed_launch", t0, now(), attrs
+            )
         if self.path:
             _count_paths(self.path)
         return out

@@ -18,12 +18,20 @@ Design constraints:
     `span()` pushes/pops, `record()` appends an already-closed child to the
     innermost open span (the shape `parallel/runner.py::_call` needs — it
     knows the duration only after the launch returned);
+  * one open-span stack a thread: the statement's own thread records on
+    the tracer, and each feed thread that works for the statement (a
+    join's probe prefetch, a scan's prefetch, a split reader) on a `Lane`
+    of it (`lane()`, from `runtime/prefetch.Feed`).  A lane's spans hang
+    under its `feed` span, which hangs under the span that was innermost
+    on the thread that made the feed;
   * one clock with the device: `span()` also enters a
     `jax.profiler.TraceAnnotation("tt:<name>")` for the span's lifetime, so
     in any profiler session (the benchmark's traced run, the `profile_dir`
     session property) the engine's spans lie in the host plane of the same
-    `.xplane.pb` as the device ops.  `record()` writes none: a launch
-    already has jax's own host event under the program's name
+    `.xplane.pb` as the device ops, on the line of the thread that
+    opened them.  A span with a `why` carries it as the annotation's
+    metadata, formatted only inside a session.  `record()` writes none: a
+    launch already has jax's own `PjitFunction(<step>)` host event
     (telemetry/programs.py).  Outside a session an annotation costs a
     flag test.
 """
@@ -40,6 +48,13 @@ from jax.profiler import TraceAnnotation
 
 #: prefix of the engine's spans in a profiler trace's host plane
 ANNOTATION_PREFIX = "tt:"
+
+#: true inside a profiler session (bound once: a test may stand a fake in
+#: for TraceAnnotation)
+_profiling = TraceAnnotation.is_enabled
+
+#: a feed thread's annotation while it makes an item (Lane.producing)
+_FEED_ANNOTATION = ANNOTATION_PREFIX + "feed"
 
 #: THE phase-timing clock.  Every engine-side wall measurement (spans,
 #: MeshProfile phases, stage self-time) reads this one callable so span and
@@ -88,7 +103,13 @@ class _OpenSpan:
     def __init__(self, tracer: "SpanTracer", sp: Span):
         self.tracer = tracer
         self.sp = sp
-        self.annotation = TraceAnnotation(ANNOTATION_PREFIX + sp.name)
+        why = sp.attrs.get("why")
+        if why is not None and _profiling():
+            self.annotation = TraceAnnotation(
+                ANNOTATION_PREFIX + sp.name, why=why
+            )
+        else:
+            self.annotation = TraceAnnotation(ANNOTATION_PREFIX + sp.name)
 
     def __enter__(self) -> Span:
         self.annotation.__enter__()
@@ -147,10 +168,18 @@ _NULL_CTX = _NullCtx()
 
 
 class SpanTracer:
-    """Per-query span tree.  Not thread-safe: one open-span stack, owned
-    by the thread that created the tracer (the statement's own, matching
-    the reference's per-query trace context).  The launch and host-pull
-    doors compare `thread_id` and record nothing from another thread."""
+    """Per-query span tree, recorded by the thread that created the tracer
+    (the statement's own, matching the reference's per-query trace
+    context) and by the lanes of the statement's feed threads.
+
+    The lane rule: each recording thread has its own stack of open spans
+    and its own thread id -- the tracer's for the statement's thread, a
+    `Lane`'s for a feed thread -- and `here()` finds the calling thread's.
+    A thread that is neither records nothing.  What the threads share is
+    the span ids and the child lists, and both are safe without a lock:
+    `next()` of an `itertools.count` and `list.append` are each one step
+    under the GIL, so two lanes never take one id and never lose a child.
+    """
 
     enabled = True
 
@@ -161,10 +190,36 @@ class SpanTracer:
         self._ids = itertools.count(1)
         self.t0 = now()
         self.thread_id = get_ident()
+        #: the open lanes of the statement's feed threads, by thread id
+        self._lanes: dict = {}
         #: the newest `launch` span the launch door recorded, so that
         #: StageExecutor._call can add its phase booking and compile
         #: children to it instead of recording the launch a second time
         self.last_launch: Optional[Span] = None
+
+    # -- threads --------------------------------------------------------------
+
+    def here(self) -> Optional["SpanTracer"]:
+        """The tracer the calling thread records on: this one on the
+        statement's thread, its lane on a feed thread that opened one,
+        else None."""
+        ident = get_ident()
+        if ident == self.thread_id:
+            return self
+        return self._lanes.get(ident)
+
+    def innermost(self) -> Optional[Span]:
+        """The span a child recorded now would hang under."""
+        return self._stack[-1] if self._stack else self.root
+
+    def lane(self, parent: Optional[Span], kind: str) -> "Lane":
+        """Open a lane for the calling (feed) thread: a `feed` span with
+        `thread=kind` under `parent` (else the root), the bottom of the
+        lane's own stack.  The thread closes it with `Lane.close()` when
+        its feed ends."""
+        lane = Lane(self, parent if parent is not None else self.root, kind)
+        self._lanes[lane.thread_id] = lane
+        return lane
 
     # -- recording ------------------------------------------------------------
 
@@ -202,23 +257,22 @@ class SpanTracer:
         return self.record(name, t, t, attrs)
 
     def left(self, sp: Optional[Span]):
-        """The inverse of `entered()`: for the body `sp` is off the stack
-        if it is the innermost open span (an operator pulling its input,
-        whose work is not its own)."""
-        if (
-            sp is None or self.thread_id != get_ident()
-            or not self._stack or self._stack[-1] is not sp
-        ):
+        """The inverse of `entered()`: for the body `sp` is off the calling
+        thread's stack if it is the innermost open span there (an operator
+        pulling its input, whose work is not its own)."""
+        here = self.here() if sp is not None else None
+        if here is None or not here._stack or here._stack[-1] is not sp:
             return _NULL_CTX
-        return _Restacked(self._stack, sp, push=False)
+        return _Restacked(here._stack, sp, push=False)
 
     def entered(self, sp: Optional[Span]):
         """Context manager: `sp` (from `held()`) is the innermost open
-        span for the body.  Off the tracer's own thread it is a no-op, as
-        the doors record nothing there."""
-        if sp is None or self.thread_id != get_ident():
+        span of the calling thread's stack for the body.  On a thread that
+        records nothing it is a no-op."""
+        here = self.here() if sp is not None else None
+        if here is None:
             return _NULL_CTX
-        return _Restacked(self._stack, sp, push=True)
+        return _Restacked(here._stack, sp, push=True)
 
     def record(self, name: str, start_s: float, end_s: float,
                attrs: Optional[dict] = None) -> Optional[Span]:
@@ -348,6 +402,56 @@ class SpanTracer:
         if self.root is not None:
             rec(self.root, 0)
         return "\n".join(lines)
+
+
+class Lane(SpanTracer):
+    """The recording state of one feed thread of a statement: its own
+    stack of open spans, whose bottom is the lane's `feed` span, over the
+    statement tracer's ids and tree (see the lane rule on SpanTracer).
+    The `feed` span runs from the thread's first item to its last; the
+    producer wraps the making of each item in `producing()`, so that the
+    thread's `tt:feed` annotation is open while it works and closed while
+    it waits to hand an item over."""
+
+    def __init__(self, owner: SpanTracer, parent: Span, kind: str):
+        self.owner = owner
+        self.query_id = owner.query_id
+        self.root = owner.root
+        self._ids = owner._ids
+        self._lanes = owner._lanes
+        self.t0 = owner.t0
+        self.thread_id = get_ident()
+        self.last_launch = None
+        t = now()
+        self.feed = owner.attach(parent, "feed", t, t, {"thread": kind})
+        self._stack = [self.feed]
+
+    def here(self) -> Optional[SpanTracer]:
+        return self.owner.here()
+
+    def producing(self) -> "_Producing":
+        """Context manager around the making of one item."""
+        return _Producing(self)
+
+    def close(self) -> None:
+        self.feed.end_s = now()
+        self._lanes.pop(self.thread_id, None)
+
+
+class _Producing:
+    __slots__ = ("lane", "annotation")
+
+    def __init__(self, lane: Lane):
+        self.lane = lane
+        self.annotation = TraceAnnotation(_FEED_ANNOTATION)
+
+    def __enter__(self) -> None:
+        self.annotation.__enter__()
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.annotation.__exit__(et, ev, tb)
+        self.lane.feed.end_s = now()
+        return False
 
 
 class NullTracer:
